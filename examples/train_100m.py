@@ -2,8 +2,8 @@
 """End-to-end training driver: a ~100M-param granite-family model trained on
 the synthetic pipeline with checkpointing and fault-tolerance hooks.
 
-    PYTHONPATH=src python examples/train_100m.py --steps 300   # full run
-    PYTHONPATH=src python examples/train_100m.py --steps 20    # quick look
+    PYTHONPATH=src python examples/train_100m.py --steps 300 --ckpt DIR  # full run
+    PYTHONPATH=src python examples/train_100m.py --steps 20 --ckpt DIR   # quick look
 
 (On the CPU container a step takes seconds; on a real pod the identical step
 function runs under the dry-run's production mesh shardings.)
@@ -27,7 +27,7 @@ def main():
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--ckpt", default="/tmp/repro_100m")
+    ap.add_argument("--ckpt", required=True, help="checkpoint directory")
     args = ap.parse_args()
 
     # ~100M params: granite family, scaled

@@ -43,7 +43,7 @@ def main():
     w = out.append
 
     w("# EXPERIMENTS — ProTuner on TPU (generated by scripts/render_experiments.py)\n")
-    w("Container: 1-core CPU, 35 GB RAM; jax 0.8.2 CPU backend with "
+    w("Container: 1-core CPU, 35 GB RAM; jax 0.9.0 CPU backend with "
       "`--xla_force_host_platform_device_count=512` for dry-runs only. "
       "Hardware model: TPU v5e — 197 TF/s bf16, 819 GB/s HBM, 50 GB/s/link "
       "ICI, 16 GiB HBM/chip. Meshes: single pod 16×16 (`data`,`model`) = 256 "

@@ -141,9 +141,8 @@ def _jax_mods():
     if _JAX_MODS is None:
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
-        _JAX_MODS = (jax, jnp, enable_x64)
+        _JAX_MODS = (jax, jnp)
     return _JAX_MODS
 
 
@@ -1152,12 +1151,12 @@ class AnalyticCostModel:
         next power of two (bounded compile cache) and sliced back to
         ``n``.  Agreement with ``_terms_columnar``: within ``JIT_RTOL``
         (see module notes on the tolerance contract and pricing tag)."""
-        jax, _, enable_x64 = _jax_mods()
+        jax, _ = _jax_mods()
         fn = self._jit_fn
         if fn is None:
             fn = self._jit_fn = _build_jit_kernel(self, ctx)
         inp = self._jit_inputs(cols, ctx, _pad_pow2(cols.n))
-        with enable_x64():
+        with jax.enable_x64(True):
             out = fn(**inp)
         return np.asarray(out)[: cols.n]
 
@@ -1299,7 +1298,7 @@ def _build_jit_kernel(model: AnalyticCostModel, ctx: _EvalContext):
     (traced and executed under ``enable_x64``).  Only ``step_s`` is
     computed — the jitted path prices searches; full term breakdowns stay
     on the exact kernels."""
-    jax, jnp, enable_x64 = _jax_mods()
+    jax, jnp = _jax_mods()
     cfg, shape, hw, mesh = model.cfg, model.shape, model.hw, model.mesh
     train = shape.kind == "train"
     decode = shape.kind == "decode"

@@ -269,6 +269,9 @@ def measure_request(req: dict) -> dict:
     env["PYTHONPATH"] = os.pathsep.join(
         [p for p in [env.get("PYTHONPATH"), _src_path()] if p]
     )
+    # the child compiles for host devices and must not claim the chip, which
+    # belongs to one process (the dryrun module pins this too)
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         try:
             proc = subprocess.run(

@@ -22,6 +22,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -145,21 +146,12 @@ def flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         scratch_shapes=[
-            _vmem((block_q, 1), jnp.float32),
-            _vmem((block_q, 1), jnp.float32),
-            _vmem((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover - CPU interpret fallback
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore[attr-defined]
 
 
 # re-exported from the jax-free geometry module (the cost model and the

@@ -16,15 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore[attr-defined]
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref):
@@ -77,6 +69,6 @@ def moe_gemm(
             (1, block_c, block_f), lambda e, c, fo, di: (e, c, fo)
         ),
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
-        scratch_shapes=[_vmem((block_c, block_f), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
         interpret=interpret,
     )(x, w)

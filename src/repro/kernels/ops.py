@@ -11,11 +11,17 @@ Three modes (``set_kernel_mode`` / ``kernel_mode`` context manager):
 * ``ref``       — force the jnp oracles.
 
 Kernel block shapes are threaded from the schedule plan (``KernelTiles``).
+
+The Pallas forwards of ``attention``, ``selective_scan``, ``rmsnorm`` and
+``moe_gemm`` are differentiable: each is a ``jax.custom_vjp`` whose backward
+pass is the VJP of the matching ``ref.py`` oracle, traced under the named
+scope ``kernel_bwd_<name>`` so a profile shows it.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 
 import jax
@@ -76,21 +82,59 @@ def _use_pallas() -> bool:
 
 
 def _interpret() -> bool:
-    return get_kernel_mode() == "interpret" or jax.default_backend() != "tpu"
+    return get_kernel_mode() == "interpret"
+
+
+def _per_device(name: str, kernel, shard, args):
+    """``kernel`` as one call per device when ``shard`` carries a mesh
+    (``sharding.rules.ShardFn``): XLA cannot partition a Mosaic kernel, so
+    it runs under ``shard_map`` on the splits ``ShardingRules.kernel_specs``
+    names."""
+    rules = getattr(shard, "rules", None)
+    if rules is None:
+        return kernel
+    in_specs, out_specs = rules.kernel_specs(name, *(a.shape for a in args))
+    return jax.shard_map(kernel, mesh=shard.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _pallas_with_ref_vjp(name: str, kernel, oracle, *args, shard=None):
+    """``kernel(*args)`` forward; backward is ``jax.vjp(oracle, *args)``.
+
+    The residuals are the inputs: the backward recomputes the oracle's
+    forward and differentiates it, partitioned by XLA like any jnp code."""
+
+    kernel = _per_device(name, kernel, shard, args)
+
+    @jax.custom_vjp
+    def f(*a):
+        return kernel(*a)
+
+    def f_fwd(*a):
+        return kernel(*a), a
+
+    def f_bwd(a, g):
+        with jax.named_scope(f"kernel_bwd_{name}"):
+            return jax.vjp(oracle, *a)[1](g)
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(*args)
 
 
 # -- attention ---------------------------------------------------------------
-def attention(q, k, v, *, causal=True, tiles: KernelTiles = DEFAULT_TILES):
+def attention(q, k, v, *, causal=True, tiles: KernelTiles = DEFAULT_TILES,
+              shard=None):
     if _use_pallas():
-        return _fa.flash_attention(
-            q,
-            k,
-            v,
+        kernel = functools.partial(
+            _fa.flash_attention,
             causal=causal,
             block_q=tiles.attn_block_q,
             block_kv=tiles.attn_block_kv,
             interpret=_interpret(),
         )
+        oracle = functools.partial(_ref.attention, causal=causal)
+        return _pallas_with_ref_vjp("attention", kernel, oracle, q, k, v,
+                                    shard=shard)
     # kernel_streamed: on the TPU target this region is the flash-attention
     # Pallas kernel — its interior (S² scores chain) never touches HBM, so
     # the HLO byte analysis (core/hlo_analysis.py) excludes ops under this
@@ -100,18 +144,18 @@ def attention(q, k, v, *, causal=True, tiles: KernelTiles = DEFAULT_TILES):
 
 
 # -- mamba scan ----------------------------------------------------------------
-def selective_scan(u, dt, A, Bm, Cm, D, *, tiles: KernelTiles = DEFAULT_TILES):
+def selective_scan(u, dt, A, Bm, Cm, D, *, tiles: KernelTiles = DEFAULT_TILES,
+                   shard=None):
     if _use_pallas():
-        return _ss.selective_scan(
-            u,
-            dt,
-            A,
-            Bm,
-            Cm,
-            D,
+        kernel = functools.partial(
+            _ss.selective_scan,
             chunk=tiles.scan_chunk,
             d_block=tiles.scan_d_block,
             interpret=_interpret(),
+        )
+        return _pallas_with_ref_vjp(
+            "selective_scan", kernel, _ref.selective_scan, u, dt, A, Bm, Cm, D,
+            shard=shard,
         )
     # kernel_streamed: the Pallas scan kernel carries the SSM state in VMEM
     with jax.named_scope("kernel_streamed_scan"):
@@ -122,23 +166,26 @@ selective_scan_step = _ref.selective_scan_step  # decode step: pure jnp
 
 
 # -- rmsnorm -------------------------------------------------------------------
-def rmsnorm(x, w, *, eps: float = 1e-6):
+def rmsnorm(x, w, *, eps: float = 1e-6, shard=None):
     if _use_pallas():
-        return _rn.rmsnorm(x, w, eps=eps, interpret=_interpret())
+        kernel = functools.partial(_rn.rmsnorm, eps=eps, interpret=_interpret())
+        oracle = functools.partial(_ref.rmsnorm, eps=eps)
+        return _pallas_with_ref_vjp("rmsnorm", kernel, oracle, x, w, shard=shard)
     return _ref.rmsnorm(x, w, eps=eps)
 
 
 # -- moe grouped gemm -----------------------------------------------------------
-def moe_gemm(x, w, *, tiles: KernelTiles = DEFAULT_TILES):
+def moe_gemm(x, w, *, tiles: KernelTiles = DEFAULT_TILES, shard=None):
     if _use_pallas():
-        return _mg.moe_gemm(
-            x,
-            w,
+        kernel = functools.partial(
+            _mg.moe_gemm,
             block_c=tiles.moe_block_c,
             block_f=tiles.moe_block_f,
             block_d=tiles.moe_block_d,
             interpret=_interpret(),
         )
+        return _pallas_with_ref_vjp("moe_gemm", kernel, _ref.moe_gemm, x, w,
+                                    shard=shard)
     return _ref.moe_gemm(x, w)
 
 

@@ -1,9 +1,12 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines, before any other import: jax locks the host
+# ^ MUST come before any other import: jax locks the platform and the host
 # device count at first init, and the production meshes (16×16 single-pod,
-# 2×16×16 multi-pod) need 512 placeholder devices.  Never set this globally —
-# smoke tests and benches must see 1 device.
+# 2×16×16 multi-pod) need 512 placeholder CPU devices.  The dry run never
+# takes the accelerator: a chip belongs to one process, which may be the
+# tuner that started this one.  Never set these globally — smoke tests and
+# benches must see 1 device.
 """Multi-pod dry-run driver.
 
 Usage:

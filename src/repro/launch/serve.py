@@ -1,10 +1,15 @@
-"""Serving driver: batched decode with the continuous-batching engine.
+"""Serving driver: batched decode with the continuous-batching engine, on
+the devices present.
 
     python -m repro.launch.serve --arch granite-3-2b --smoke --requests 6
+
+Exits non-zero for an architecture the engine cannot serve (embedding-input
+configs) and when a request does not complete.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 
 import numpy as np
 
@@ -22,6 +27,7 @@ def main(argv=None) -> int:
     import jax
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import transformer
     from repro.serving.engine import ServingEngine
 
@@ -29,9 +35,10 @@ def main(argv=None) -> int:
     if args.smoke:
         cfg = cfg.reduced()
     if cfg.input_kind != "tokens":
-        print(f"[serve] {args.arch} uses a stub modality frontend; serving "
-              "demo drives token-input archs — pick granite/deepseek/etc.")
-        return 0
+        print(f"[serve] {args.arch} takes embeddings, not tokens; the engine "
+              "serves token-input archs only", file=sys.stderr)
+        return 2
+    enable_compile_cache()
     params = transformer.init_params(cfg, jax.random.PRNGKey(args.seed))
     eng = ServingEngine(cfg, params, batch_slots=args.slots, max_len=64)
     rng = np.random.default_rng(args.seed)
@@ -42,7 +49,7 @@ def main(argv=None) -> int:
     for r in sorted(done, key=lambda r: r.uid):
         print(f"[serve] req {r.uid}: prompt {r.prompt.tolist()} -> {r.generated}")
     print(f"[serve] completed {len(done)}/{args.requests} requests")
-    return 0
+    return 0 if len(done) == args.requests else 1
 
 
 if __name__ == "__main__":

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import socket
 
 
@@ -120,6 +121,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.cmd == "serve":
+        # the daemon's JAX users (learned-cost MLP, jit pricing) price plans
+        # on the host; they and the workers it spawns stay off the chip,
+        # which belongs to the one process that runs the tuned program
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         from repro.service.daemon import TunerService, serve_forever
 
         service = TunerService(

@@ -51,7 +51,7 @@ def forward(
     k = shard(k, "act_bkvsd")
     v = shard(v, "act_bkvsd")
     q, k = layers.apply_positions(q, k, cfg, positions)
-    o = ops.attention(q, k, v, causal=True, tiles=tiles)  # (B,H,S,hd)
+    o = ops.attention(q, k, v, causal=True, tiles=tiles, shard=shard)  # (B,H,S,hd)
     o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
     out = shard(o @ p["wo"], "act_btd")
     if return_kv:

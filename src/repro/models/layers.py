@@ -13,9 +13,10 @@ from repro.kernels import ops
 # ---------------------------------------------------------------------------
 # Norms
 # ---------------------------------------------------------------------------
-def norm(x: jax.Array, w: jax.Array, kind: str, eps: float = 1e-6) -> jax.Array:
+def norm(x: jax.Array, w: jax.Array, kind: str, eps: float = 1e-6,
+         shard=None) -> jax.Array:
     if kind == "rmsnorm":
-        return ops.rmsnorm(x, w, eps=eps)
+        return ops.rmsnorm(x, w, eps=eps, shard=shard)
     # layernorm (no bias, like most modern stacks)
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)

@@ -69,7 +69,7 @@ def forward(
     xc = jax.nn.silu(_conv_causal(xi, p["conv_w"], p["conv_b"]))
     dt, A, Bm, Cm = _ssm_inputs(p, xc, cfg)
     y = ops.selective_scan(
-        xc, dt.astype(xc.dtype), A, Bm, Cm, p["Dp"], tiles=tiles
+        xc, dt.astype(xc.dtype), A, Bm, Cm, p["Dp"], tiles=tiles, shard=shard
     )
     y = y * jax.nn.silu(z)
     return shard(y @ p["out_proj"], "act_btd")

@@ -108,14 +108,14 @@ def forward(
     grouped = shard(grouped, "moe_ecd")
 
     # --- expert FFN (grouped GEMMs) ---
-    up = ops.moe_gemm(grouped, p["w_up"], tiles=tiles)
+    up = ops.moe_gemm(grouped, p["w_up"], tiles=tiles, shard=shard)
     if cfg.act == "swiglu":
-        gate = ops.moe_gemm(grouped, p["w_gate"], tiles=tiles)
+        gate = ops.moe_gemm(grouped, p["w_gate"], tiles=tiles, shard=shard)
         hidden = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
     else:
         hidden = layers.activate(up.astype(jnp.float32), cfg.act)
     hidden = shard(hidden.astype(x.dtype), "moe_ecf")
-    out = ops.moe_gemm(hidden, p["w_down"], tiles=tiles)  # (E, C, d)
+    out = ops.moe_gemm(hidden, p["w_down"], tiles=tiles, shard=shard)  # (E, C, d)
 
     # --- combine ---
     gathered = out[se, pos] * sw[:, None].astype(out.dtype)

@@ -109,7 +109,7 @@ def _embed(params: dict, cfg: ModelConfig, inputs: jax.Array, positions) -> jax.
 
 
 def _logits(params: dict, cfg: ModelConfig, h: jax.Array, shard: ShardFn) -> jax.Array:
-    h = layers.norm(h, params["final_norm"], cfg.norm)
+    h = layers.norm(h, params["final_norm"], cfg.norm, shard=shard)
     if cfg.tie_embeddings:
         logits = jnp.einsum("...d,vd->...v", h, params["embed"])
     else:
@@ -127,7 +127,7 @@ def _block_forward(
     shard: ShardFn,
     moe_dist=None,
 ) -> jax.Array:
-    hn = layers.norm(h, bp["norm1"], cfg.norm)
+    hn = layers.norm(h, bp["norm1"], cfg.norm, shard=shard)
     if spec.mixer == "attn":
         mixed = attention.forward(
             bp["attn"], cfg, hn, positions, tiles=tiles, shard=shard
@@ -136,7 +136,7 @@ def _block_forward(
         mixed = mamba.forward(bp["mamba"], cfg, hn, tiles=tiles, shard=shard)
     h = h + mixed
     if spec.mlp != "none":
-        hn = layers.norm(h, bp["norm2"], cfg.norm)
+        hn = layers.norm(h, bp["norm2"], cfg.norm, shard=shard)
         if spec.mlp == "moe":
             out = moe.forward(bp["mlp"], cfg, hn, tiles=tiles, shard=shard,
                               dist=moe_dist)
@@ -241,7 +241,7 @@ def decode_step(
         new_cache = {}
         for i, spec in enumerate(plan):
             bp = period_params[f"b{i}"]
-            hn = layers.norm(h, bp["norm1"], cfg.norm)
+            hn = layers.norm(h, bp["norm1"], cfg.norm, shard=shard)
             if spec.mixer == "attn":
                 mixed, new_cache[f"b{i}"] = attention.decode_step(
                     bp["attn"], cfg, period_cache[f"b{i}"], hn, cur, shard=shard
@@ -252,7 +252,7 @@ def decode_step(
                 )
             h = h + mixed
             if spec.mlp != "none":
-                hn = layers.norm(h, bp["norm2"], cfg.norm)
+                hn = layers.norm(h, bp["norm2"], cfg.norm, shard=shard)
                 if spec.mlp == "moe":
                     out = moe.forward(bp["mlp"], cfg, hn, tiles=tiles,
                                       shard=shard, dist=moe_dist)

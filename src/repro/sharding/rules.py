@@ -233,15 +233,52 @@ class ShardingRules:
         return jax.tree_util.tree_map_with_path(f, cache)
 
 
+    # -- Pallas kernels -------------------------------------------------------------
+    def kernel_specs(self, kernel: str, *shapes) -> Tuple[Tuple[P, ...], P]:
+        """(in_specs, out_spec) that split one Pallas call over the mesh
+        along the dims the kernel is parallel in; every other dim is whole
+        on each device."""
+        if kernel == "rmsnorm":  # rows: (..., d), (d,)
+            x = P(self._b(shapes[0][0]), *([None] * (len(shapes[0]) - 1)))
+            return (x, P(None)), x
+        if kernel == "attention":  # q (B,H,S,D), k/v (B,Hkv,S,D): batch, heads
+            H, Hkv = shapes[0][1], shapes[1][1]
+            h = "model" if (
+                self.tp_mixer and self._fit("model", H) and self._fit("model", Hkv)
+            ) else None
+            qkv = P(self._b(shapes[0][0]), h, None, None)
+            return (qkv, qkv, qkv), qkv
+        if kernel == "selective_scan":  # u, dt, A, Bm, Cm, D: batch, d_inner
+            b = self._b(shapes[0][0])
+            i = self._fit("model", shapes[0][2]) if self.tp_mixer else None
+            ui, bn = P(b, None, i), P(b, None, None)
+            return (ui, ui, P(i, None), bn, bn, P(i)), ui
+        if kernel == "moe_gemm":  # x (E,C,d), w (E,d,f): experts
+            e = self._fit("model", shapes[0][0]) if self.moe_mode == "ep" else None
+            ex = P(e, None, None)
+            return (ex, ex), ex
+        raise KeyError(kernel)
+
+
+class ShardFn:
+    """The ``shard(x, name)`` callback threaded through the models: it
+    constrains activation ``name`` to its rule.  Its ``mesh`` and ``rules``
+    let the kernel wrappers in ``kernels/ops.py`` run a Pallas call per
+    device, since Mosaic kernels are not partitioned by XLA."""
+
+    def __init__(self, mesh: Mesh, rules: ShardingRules):
+        self.mesh = mesh
+        self.rules = rules
+
+    def __call__(self, x: jax.Array, name: str) -> jax.Array:
+        spec = self.rules.act_spec(name, x.ndim, x.shape)
+        if spec is None:
+            return x
+        return jax.lax.with_sharding_constraint(x, NamedSharding(self.mesh, spec))
+
+
 def make_shard_fn(mesh: Mesh, rules: Optional[ShardingRules]):
     """Returns the `shard(x, name)` callback threaded through the models."""
     if rules is None or mesh is None:
         return lambda x, name: x
-
-    def shard(x, name):
-        spec = rules.act_spec(name, x.ndim, x.shape)
-        if spec is None:
-            return x
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-    return shard
+    return ShardFn(mesh, rules)

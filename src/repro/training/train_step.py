@@ -74,6 +74,38 @@ def make_positions(cfg: ModelConfig, batch: int, seq: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Train step
 # ---------------------------------------------------------------------------
+def make_loss_fn(
+    cfg: ModelConfig,
+    shape: InputShape,
+    plan: SchedulePlan,
+    mesh: Optional[Mesh] = None,
+    mesh_spec: Optional[MeshSpec] = None,
+    unroll: bool = False,
+) -> Callable:
+    """(params, inputs, labels, positions) -> next-token loss: the function
+    ``make_train_step`` differentiates."""
+    tiles = tiles_from_plan(plan)
+    rules = ShardingRules(cfg, shape, plan, mesh_spec) if mesh_spec else None
+    shard = make_shard_fn(mesh, rules)
+    moe_dist = moe_dist_for(cfg, shape, plan, mesh, mesh_spec)
+
+    def loss_fn(params, inputs, labels, positions):
+        logits = transformer.forward(
+            params,
+            cfg,
+            inputs,
+            positions,
+            tiles=tiles,
+            shard=shard,
+            remat=plan.remat,
+            unroll=unroll,
+            moe_dist=moe_dist,
+        )
+        return cross_entropy(logits[:, :-1, :], labels[:, 1:])
+
+    return loss_fn
+
+
 def make_train_step(
     cfg: ModelConfig,
     shape: InputShape,
@@ -92,27 +124,10 @@ def make_train_step(
     opt_cfg = opt_cfg or optim.OptimizerConfig(
         moment_dtype=plan.opt_dtype if plan.opt_dtype != "float32" else "float32"
     )
-    tiles = tiles_from_plan(plan)
-    rules = ShardingRules(cfg, shape, plan, mesh_spec) if mesh_spec else None
-    shard = make_shard_fn(mesh, rules)
-    moe_dist = moe_dist_for(cfg, shape, plan, mesh, mesh_spec)
     n_mb = plan.microbatches
-
-    def loss_fn(params, inputs, labels, positions):
-        logits = transformer.forward(
-            params,
-            cfg,
-            inputs,
-            positions,
-            tiles=tiles,
-            shard=shard,
-            remat=plan.remat,
-            unroll=unroll,
-            moe_dist=moe_dist,
-        )
-        return cross_entropy(logits[:, :-1, :], labels[:, 1:])
-
-    grad_fn = jax.value_and_grad(loss_fn)
+    grad_fn = jax.value_and_grad(
+        make_loss_fn(cfg, shape, plan, mesh, mesh_spec, unroll)
+    )
 
     def train_step(params, opt_state, batch):
         inputs, labels = batch["inputs"], batch["labels"]

@@ -31,9 +31,9 @@ from repro.training.train_step import make_train_step
 
 @dataclass
 class TrainerConfig:
+    ckpt_dir: str  # a run resumes from the checkpoints it finds here
     total_steps: int = 100
     ckpt_every: int = 50
-    ckpt_dir: str = "/tmp/repro_ckpt"
     ckpt_async: bool = True
     log_every: int = 10
     seed: int = 0
@@ -45,7 +45,7 @@ class Trainer:
         cfg: ModelConfig,
         shape: InputShape,
         plan: SchedulePlan,
-        tc: TrainerConfig = TrainerConfig(),
+        tc: TrainerConfig,
         opt_cfg: Optional[optim.OptimizerConfig] = None,
         data_cfg: DataConfig = DataConfig(),
         mesh=None,

@@ -18,6 +18,15 @@ def rng_key():
     return jax.random.PRNGKey(0)
 
 
+@pytest.fixture
+def no_compile_cache(monkeypatch):
+    """For tests that call the launchers: their compile-cache helper would
+    turn JAX's persistent cache on for the rest of the worker process."""
+    from repro.launch import compile_cache
+
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
+
+
 # ---------------------------------------------------------------------------
 # Table-1 cell construction — ONE definition shared by the differential /
 # engine / serving / service suites (each used to carry its own copy).

@@ -110,10 +110,12 @@ def test_rebalanced_pipeline_is_exact():
 
 
 def _shm_segments():
-    """Live repro shm cache segments (Linux: files in /dev/shm)."""
+    """Live repro shm cache segments of pools this process created (Linux:
+    files in /dev/shm).  Segment names carry the creating pid; other test
+    processes running at the same time create and unlink their own."""
     try:
         return {f for f in os.listdir("/dev/shm")
-                if f.startswith("repro-cache-")}
+                if f.startswith(f"repro-cache-{os.getpid()}-")}
     except FileNotFoundError:  # pragma: no cover - non-Linux shm
         return set()
 

@@ -137,3 +137,96 @@ def test_quantize_roundtrip(R, C):
     err = np.abs(np.asarray(xd) - np.asarray(x))
     bound = np.asarray(s) * 0.5 + 1e-7
     assert (err <= bound).all()
+
+
+# ---------------------------------------------------------------------------
+# row-group scan kernel at chunk / d_block values the sweep above does not use
+@pytest.mark.parametrize(
+    "B,L,Di,N,chunk,dblk,dtype",
+    [
+        (1, 48, 40, 8, 24, 40, jnp.float32),    # chunk of 3 row groups, odd Di
+        (2, 80, 64, 16, 40, 16, jnp.float32),   # 2 chunks of 5 groups, N=16
+        (1, 64, 96, 16, 64, 32, jnp.bfloat16),  # bf16 blocks, as the models run
+        (1, 8, 24, 4, 8, 8, jnp.float32),       # one group per chunk
+    ],
+)
+def test_selective_scan_row_groups_match_ref(B, L, Di, N, chunk, dblk, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    u = jax.random.normal(ks[0], (B, L, Di)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, L, Di))).astype(dtype)
+    A = -jnp.exp(jax.random.normal(ks[2], (Di, N)) * 0.5)
+    Bm = jax.random.normal(ks[3], (B, L, N)).astype(dtype)
+    Cm = jax.random.normal(ks[4], (B, L, N)).astype(dtype)
+    D = jnp.linspace(0.1, 1.0, Di)
+    out = selective_scan(u, dt, A, Bm, Cm, D, chunk=chunk, d_block=dblk, interpret=True)
+    exp = ref.selective_scan(u, dt, A, Bm, Cm, D)
+    tol = _tol(dtype) if dtype == jnp.bfloat16 else dict(atol=1e-4, rtol=1e-3)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(exp, np.float32), **tol
+    )
+
+
+def test_selective_scan_rejects_unaligned_chunk():
+    u = jnp.zeros((1, 12, 8))
+    with pytest.raises(AssertionError):
+        selective_scan(u, u, jnp.zeros((8, 4)), jnp.zeros((1, 12, 4)),
+                       jnp.zeros((1, 12, 4)), jnp.zeros(8), chunk=12,
+                       d_block=8, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# custom_vjp: Pallas forward, oracle backward
+def _grads(fn, args, cot):
+    return jax.vjp(fn, *args)[1](cot)
+
+
+def _kernel_cases():
+    from repro.kernels.ops import KernelTiles
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 8)
+    tiles = KernelTiles(attn_block_q=64, attn_block_kv=64, scan_chunk=16,
+                        scan_d_block=16, moe_block_c=16, moe_block_f=16,
+                        moe_block_d=16)
+    q = jax.random.normal(ks[0], (1, 4, 128, 32))
+    k = jax.random.normal(ks[1], (1, 2, 128, 32))
+    v = jax.random.normal(ks[2], (1, 2, 128, 32))
+    u = jax.random.normal(ks[3], (1, 32, 32))
+    dt = jax.nn.softplus(jax.random.normal(ks[4], (1, 32, 32)))
+    A = -jnp.exp(jax.random.normal(ks[5], (32, 8)) * 0.5)
+    Bm = jax.random.normal(ks[6], (1, 32, 8))
+    Cm = jax.random.normal(ks[7], (1, 32, 8))
+    D = jnp.linspace(0.1, 1.0, 32)
+    x = jax.random.normal(ks[0], (4, 16, 32))
+    w = jax.random.normal(ks[1], (4, 32, 48))
+    h = jax.random.normal(ks[2], (3, 5, 64))
+    g = jax.random.normal(ks[3], (64,))
+    return {
+        "attention": (lambda o: lambda *a: o.attention(*a, tiles=tiles),
+                      ref.attention, (q, k, v)),
+        "selective_scan": (lambda o: lambda *a: o.selective_scan(*a, tiles=tiles),
+                           ref.selective_scan, (u, dt, A, Bm, Cm, D)),
+        "rmsnorm": (lambda o: o.rmsnorm, ref.rmsnorm, (h, g)),
+        "moe_gemm": (lambda o: lambda *a: o.moe_gemm(*a, tiles=tiles),
+                     ref.moe_gemm, (x, w)),
+    }
+
+
+@pytest.mark.parametrize("name", ["attention", "selective_scan", "rmsnorm", "moe_gemm"])
+def test_custom_vjp_matches_oracle_grad(name):
+    from repro.kernels import ops
+
+    make, oracle, args = _kernel_cases()[name]
+    fn = make(ops)
+    cot = jax.random.normal(jax.random.PRNGKey(11), oracle(*args).shape)
+    with ops.kernel_mode("interpret"):
+        out = jax.jit(fn)(*args)
+        got = jax.jit(lambda *a: _grads(fn, a, cot))(*args)
+        jaxpr = str(jax.make_jaxpr(lambda *a: _grads(fn, a, cot))(*args))
+    assert "pallas_call" in jaxpr  # the forward really ran the kernel
+    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle(*args)),
+                               atol=2e-4, rtol=2e-4)
+    want = _grads(oracle, args, cot)
+    assert len(got) == len(want) == len(args)
+    for a, gk, gr in zip(args, got, want):
+        assert gk.shape == a.shape and gk.dtype == a.dtype
+        np.testing.assert_allclose(np.asarray(gk), np.asarray(gr), atol=2e-4, rtol=2e-4)

@@ -61,7 +61,7 @@ def test_learned_cost_model_trains_and_ranks():
     assert rc > 0.5, rc
 
 
-def test_cli_entrypoints_smoke(capsys, tmp_path):
+def test_cli_entrypoints_smoke(capsys, tmp_path, no_compile_cache):
     from repro.launch.serve import main as serve_main
     from repro.launch.train import main as train_main
 

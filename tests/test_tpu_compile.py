@@ -1,0 +1,109 @@
+"""Main-path Pallas kernels compiled for a described TPU v5e chip.
+
+Nothing runs: each test lowers a kernel at the widths of a config the repo
+serves and compiles it with the TPU compiler for one chip of a ``v5e:2x2``
+topology that is described, not attached.  This catches what interpret mode
+cannot — unaligned slices, VMEM overuse, lowering failures — at no chip
+time.  The topology is described inside a module fixture, never at import,
+because only one process may load the TPU library at a time.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops, ref
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.moe_gemm import moe_gemm
+from repro.kernels.quantize import dequantize_int8, quantize_int8
+from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.selective_scan import selective_scan
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *specs):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# granite-3-2b: 32 query / 8 kv heads, head_dim 64, 4096 tokens
+ATTN = [((1, 32, 4096, 64), BF16), ((1, 8, 4096, 64), BF16), ((1, 8, 4096, 64), BF16)]
+
+
+@pytest.mark.parametrize("block", [256, 512])
+def test_flash_attention_compiles(one_chip, block):
+    fn = functools.partial(flash_attention, block_q=block, block_kv=block)
+    _compile(fn, one_chip, *ATTN)
+
+
+def test_selective_scan_compiles_falcon_mamba(one_chip):
+    # falcon-mamba-7b: d_inner 8192, N 16, 4096 tokens, chunk 128, d_block 256
+    L, Di, N = 4096, 8192, 16
+    fn = functools.partial(selective_scan, chunk=128, d_block=256)
+    _compile(fn, one_chip, ((1, L, Di), BF16), ((1, L, Di), BF16),
+             ((Di, N), jnp.float32), ((1, L, N), BF16), ((1, L, N), BF16),
+             ((Di,), jnp.float32))
+
+
+@pytest.mark.parametrize("d,f", [(1024, 512), (512, 1024)])
+def test_moe_gemm_compiles_granite_moe(one_chip, d, f):
+    # granite-moe-1b-a400m: 32 experts, capacity 1280 for 4096 tokens top-8
+    fn = functools.partial(moe_gemm, block_c=128, block_f=256, block_d=256)
+    _compile(fn, one_chip, ((32, 1280, d), BF16), ((32, d, f), BF16))
+
+
+def test_rmsnorm_compiles(one_chip):
+    _compile(rmsnorm, one_chip, ((1, 4096, 2048), BF16), ((2048,), BF16))
+
+
+def test_quantize_compiles(one_chip):
+    # a 2048 x 8192 gradient in the compressed all-reduce's (rows, 128) layout
+    rows = 2048 * 8192 // 128
+    _compile(quantize_int8, one_chip, ((rows, 128), jnp.float32))
+    _compile(dequantize_int8, one_chip, ((rows, 128), jnp.int8),
+             ((rows, 1), jnp.float32))
+
+
+def _vjp_of(kernel, oracle, name):
+    def f(*args):
+        out, vjp = jax.vjp(
+            lambda *a: ops._pallas_with_ref_vjp(name, kernel, oracle, *a), *args
+        )
+        return out, vjp(jnp.ones_like(out))
+
+    return f
+
+
+def test_rmsnorm_custom_vjp_backward_compiles(one_chip):
+    f = _vjp_of(rmsnorm, ref.rmsnorm, "rmsnorm")
+    c = _compile(f, one_chip, ((1, 4096, 2048), BF16), ((2048,), BF16))
+    assert "kernel_bwd_rmsnorm" in c.as_text()
+
+
+def test_flash_attention_custom_vjp_backward_compiles(one_chip):
+    kernel = functools.partial(flash_attention, block_q=256, block_kv=256)
+    f = _vjp_of(kernel, ref.attention, "attention")
+    c = _compile(f, one_chip, *ATTN)
+    assert "kernel_bwd_attention" in c.as_text()

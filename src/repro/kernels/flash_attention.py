@@ -151,6 +151,7 @@ def flash_attention(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 

@@ -71,4 +71,5 @@ def moe_gemm(
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
         interpret=interpret,
+        name="moe_gemm",
     )(x, w)

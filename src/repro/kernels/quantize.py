@@ -46,6 +46,7 @@ def quantize_int8(x: jax.Array, *, block_rows: int = 256, interpret: bool = Fals
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_int8",
     )(x)
 
 
@@ -72,4 +73,5 @@ def dequantize_int8(
         out_specs=pl.BlockSpec((block_rows, C), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), dtype),
         interpret=interpret,
+        name="dequantize_int8",
     )(q, scale)

@@ -52,6 +52,7 @@ def rmsnorm(
         out_specs=pl.BlockSpec((block_rows, d), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, w.reshape(1, d))
     if pad:
         out = out[:rows]

@@ -119,6 +119,7 @@ def selective_scan(
         out_shape=jax.ShapeDtypeStruct((B, L, Di), u.dtype),
         scratch_shapes=[pltpu.VMEM((N, d_block), jnp.float32)],
         interpret=interpret,
+        name="selective_scan",
     )(u, dt, A.T, Bm, Cm, D.reshape(1, Di))
 
 

@@ -10,6 +10,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.kernels.ops import KernelTiles
 from repro.models import layers
+from repro.runtime import tracing
 
 
 def init(cfg: ModelConfig, key) -> dict:
@@ -35,6 +36,7 @@ def _project(p, x, cfg):
     return q, k, v
 
 
+@tracing.scope(tracing.ATTENTION)
 def forward(
     p: dict,
     cfg: ModelConfig,
@@ -75,6 +77,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype, kv_dtype: str 
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@tracing.scope(tracing.KV_WRITE)
 def _quant_kv(x: jax.Array):
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
@@ -82,6 +85,7 @@ def _quant_kv(x: jax.Array):
     return q, scale
 
 
+@tracing.scope(tracing.ATTENTION)
 def decode_step(
     p: dict,
     cfg: ModelConfig,
@@ -96,6 +100,7 @@ def decode_step(
     cur = jnp.asarray(cur, jnp.int32)
     per_row = cur.ndim == 1  # continuous batching: each row at its own length
 
+    @tracing.scope(tracing.KV_WRITE)
     def _write_at_cur(c, new):
         # KV write at the token position — per-row positions need a
         # per-row dynamic_update_slice (vmapped over the batch axis)

@@ -8,11 +8,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.runtime import tracing
 
 
 # ---------------------------------------------------------------------------
 # Norms
 # ---------------------------------------------------------------------------
+@tracing.scope(tracing.NORM)
 def norm(x: jax.Array, w: jax.Array, kind: str, eps: float = 1e-6,
          shard=None) -> jax.Array:
     if kind == "rmsnorm":

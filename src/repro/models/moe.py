@@ -31,6 +31,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.kernels.ops import KernelTiles
 from repro.models import layers
+from repro.runtime import tracing
 
 CAPACITY_FACTOR = 1.25
 
@@ -85,12 +86,44 @@ def forward(
     C = capacity(T, cfg, block=tiles.moe_block_c if T >= tiles.moe_block_c else 8)
 
     xt = x.reshape(T, d)
-    router_logits = (xt.astype(jnp.float32) @ p["router"])  # (T, E)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, k)  # (T, k)
-    topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
+    topw, topi = _route(xt, p["router"], k)  # (T, k)
 
     # --- sort-based dispatch ---
+    grouped, (se, st, sw, keep, pos) = _dispatch(xt, topw, topi, E, C, x.dtype)
+    with tracing.scope(tracing.MOE_DISPATCH):
+        grouped = shard(grouped, "moe_ecd")
+
+    # --- expert FFN (grouped GEMMs) ---
+    with tracing.scope(tracing.MOE_EXPERTS):
+        up = ops.moe_gemm(grouped, p["w_up"], tiles=tiles, shard=shard)
+        if cfg.act == "swiglu":
+            gate = ops.moe_gemm(grouped, p["w_gate"], tiles=tiles, shard=shard)
+            hidden = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+        else:
+            hidden = layers.activate(up.astype(jnp.float32), cfg.act)
+        hidden = shard(hidden.astype(x.dtype), "moe_ecf")
+        out = ops.moe_gemm(hidden, p["w_down"], tiles=tiles, shard=shard)  # (E, C, d)
+
+    # --- combine ---
+    y = _combine(out, se, st, sw, keep, pos, T)
+    with tracing.scope(tracing.MOE_COMBINE):
+        return shard(y.astype(x.dtype).reshape(B, S, d), "act_btd")
+
+
+@tracing.scope(tracing.MOE_ROUTE)
+def _route(xt, router, k: int):
+    """Top-k routing: (T, d) -> renormalised weights and expert ids, (T, k)."""
+    logits = xt.astype(jnp.float32) @ router  # (T, E)
+    probs = jax.nn.softmax(logits, axis=-1)
+    topw, topi = jax.lax.top_k(probs, k)
+    return topw / jnp.sum(topw, axis=-1, keepdims=True), topi
+
+
+@tracing.scope(tracing.MOE_DISPATCH)
+def _dispatch(xt, topw, topi, E: int, C: int, dtype):
+    """Sort-based grouping: (T, d) -> (E, C, d) plus the bookkeeping to
+    combine: (sorted_expert, sorted_token, sorted_weight, keep, pos)."""
+    T, k = topi.shape
     flat_e = topi.reshape(-1)  # (T*k,)
     flat_t = jnp.repeat(jnp.arange(T), k)
     flat_w = topw.reshape(-1)
@@ -101,57 +134,24 @@ def forward(
     pos = jnp.arange(T * k) - seg_start[se]  # rank within expert
     keep = pos < C
     pos = jnp.where(keep, pos, 0)
-
-    grouped = jnp.zeros((E, C, d), x.dtype)
-    src = jnp.where(keep[:, None], xt[st], 0).astype(x.dtype)
+    grouped = jnp.zeros((E, C, xt.shape[1]), dtype)
+    src = jnp.where(keep[:, None], xt[st], 0).astype(dtype)
     grouped = grouped.at[se, pos].add(src)  # dropped tokens add 0
-    grouped = shard(grouped, "moe_ecd")
+    return grouped, (se, st, sw, keep, pos)
 
-    # --- expert FFN (grouped GEMMs) ---
-    up = ops.moe_gemm(grouped, p["w_up"], tiles=tiles, shard=shard)
-    if cfg.act == "swiglu":
-        gate = ops.moe_gemm(grouped, p["w_gate"], tiles=tiles, shard=shard)
-        hidden = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-    else:
-        hidden = layers.activate(up.astype(jnp.float32), cfg.act)
-    hidden = shard(hidden.astype(x.dtype), "moe_ecf")
-    out = ops.moe_gemm(hidden, p["w_down"], tiles=tiles, shard=shard)  # (E, C, d)
 
-    # --- combine ---
+@tracing.scope(tracing.MOE_COMBINE)
+def _combine(out, se, st, sw, keep, pos, T: int):
+    """Each token's expert outputs, weighted and summed: (E, C, d) -> (T, d) f32."""
     gathered = out[se, pos] * sw[:, None].astype(out.dtype)
     gathered = jnp.where(keep[:, None], gathered, 0)
-    y = jnp.zeros((T, d), jnp.float32).at[st].add(gathered.astype(jnp.float32))
-    return shard(y.astype(x.dtype).reshape(B, S, d), "act_btd")
+    return jnp.zeros((T, out.shape[-1]), jnp.float32).at[st].add(
+        gathered.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
 # shard_map expert-parallel path
 # ---------------------------------------------------------------------------
-def _local_route_group(xt, router, k: int, E: int, C: int, dtype):
-    """Local top-k routing + sort-based grouping: (T,d) -> (E, C, d) plus the
-    bookkeeping to combine: (sorted_expert, sorted_token, sorted_weight, keep,
-    pos)."""
-    T = xt.shape[0]
-    logits = xt.astype(jnp.float32) @ router
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, k)
-    topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
-    flat_e = topi.reshape(-1)
-    flat_t = jnp.repeat(jnp.arange(T), k)
-    flat_w = topw.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)
-    se, st, sw = flat_e[order], flat_t[order], flat_w[order]
-    counts = jnp.bincount(flat_e, length=E)
-    seg_start = jnp.cumsum(counts) - counts
-    pos = jnp.arange(T * k) - seg_start[se]
-    keep = pos < C
-    pos = jnp.where(keep, pos, 0)
-    grouped = jnp.zeros((E, C, xt.shape[1]), dtype)
-    src = jnp.where(keep[:, None], xt[st], 0).astype(dtype)
-    grouped = grouped.at[se, pos].add(src)
-    return grouped, (se, st, sw, keep, pos)
-
-
 def _forward_ep_shard_map(
     p: dict, cfg: ModelConfig, x: jax.Array, tiles: KernelTiles, dist: MoEDist
 ) -> jax.Array:
@@ -177,41 +177,42 @@ def _forward_ep_shard_map(
         # x_loc: (B/dp, S, d) — replicated over the model axis
         # up/gate: (E_loc, d, f[/dp]), down: (E_loc, f, d[/dp])
         if dist.fsdp:
-            up = jax.lax.all_gather(up, dist.data_axes, axis=2, tiled=True)
-            down = jax.lax.all_gather(down, dist.data_axes, axis=1, tiled=True)
-            if gate is not None:
-                gate = jax.lax.all_gather(gate, dist.data_axes, axis=2, tiled=True)
+            with tracing.scope(tracing.MOE_EXPERTS):
+                up = jax.lax.all_gather(up, dist.data_axes, axis=2, tiled=True)
+                down = jax.lax.all_gather(down, dist.data_axes, axis=1, tiled=True)
+                if gate is not None:
+                    gate = jax.lax.all_gather(gate, dist.data_axes, axis=2, tiled=True)
         Bl, Sl, dl = x_loc.shape
         T = Bl * Sl
         C = capacity(T, cfg, block=8)
         xt = x_loc.reshape(T, dl)
-        grouped, (se, st, sw, keep, pos) = _local_route_group(
-            xt, router_w, k, E, C, x_loc.dtype
-        )
+        topw, topi = _route(xt, router_w, k)
+        grouped, (se, st, sw, keep, pos) = _dispatch(xt, topw, topi, E, C, x_loc.dtype)
         # each model rank owns experts [r*E_loc, (r+1)*E_loc): slice locally —
         # no dispatch collective (tokens replicated over the expert axis)
         r = jax.lax.axis_index(dist.model_axis)
-        mine = jax.lax.dynamic_slice_in_dim(grouped, r * E_loc, E_loc, axis=0)
+        with tracing.scope(tracing.MOE_DISPATCH):
+            mine = jax.lax.dynamic_slice_in_dim(grouped, r * E_loc, E_loc, axis=0)
 
-        up_o = ops.moe_gemm(mine, up, tiles=tiles)
-        if gate is not None:
-            g_o = ops.moe_gemm(mine, gate, tiles=tiles)
-            hidden = jax.nn.silu(g_o.astype(jnp.float32)) * up_o.astype(jnp.float32)
-        else:
-            hidden = layers.activate(up_o.astype(jnp.float32), cfg.act)
-        out = ops.moe_gemm(hidden.astype(x_loc.dtype), down, tiles=tiles)
-        # scatter back into the FULL (E, C, d) slot layout, zero elsewhere,
-        # so the combine below can index it uniformly; psum merges ranks.
-        full = jnp.zeros((E, C, dl), out.dtype)
-        full = jax.lax.dynamic_update_slice_in_dim(full, out, r * E_loc, axis=0)
-        gathered = full[se, pos] * sw[:, None].astype(out.dtype)
-        gathered = jnp.where(keep[:, None], gathered, 0)
-        y = jnp.zeros((T, dl), jnp.float32).at[st].add(gathered.astype(jnp.float32))
-        # combine-AR in bf16: halves the wire bytes of the only EP collective
-        # (each token's k experts live on ≤k ranks, so the sum has ≤k terms —
-        # bf16 is ample; §Perf iteration 3)
-        y = jax.lax.psum(y.astype(jnp.bfloat16), dist.model_axis)
-        return y.astype(x_loc.dtype).reshape(Bl, Sl, dl)
+        with tracing.scope(tracing.MOE_EXPERTS):
+            up_o = ops.moe_gemm(mine, up, tiles=tiles)
+            if gate is not None:
+                g_o = ops.moe_gemm(mine, gate, tiles=tiles)
+                hidden = jax.nn.silu(g_o.astype(jnp.float32)) * up_o.astype(jnp.float32)
+            else:
+                hidden = layers.activate(up_o.astype(jnp.float32), cfg.act)
+            out = ops.moe_gemm(hidden.astype(x_loc.dtype), down, tiles=tiles)
+        with tracing.scope(tracing.MOE_COMBINE):
+            # scatter back into the FULL (E, C, d) slot layout, zero elsewhere,
+            # so the combine below can index it uniformly; psum merges ranks.
+            full = jnp.zeros((E, C, dl), out.dtype)
+            full = jax.lax.dynamic_update_slice_in_dim(full, out, r * E_loc, axis=0)
+            y = _combine(full, se, st, sw, keep, pos, T)
+            # combine-AR in bf16: halves the wire bytes of the only EP collective
+            # (each token's k experts live on ≤k ranks, so the sum has ≤k terms —
+            # bf16 is ample; §Perf iteration 3)
+            y = jax.lax.psum(y.astype(jnp.bfloat16), dist.model_axis)
+            return y.astype(x_loc.dtype).reshape(Bl, Sl, dl)
 
     if w_gate is not None:
         fn = jax.shard_map(

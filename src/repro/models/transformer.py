@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.kernels.ops import KernelTiles, DEFAULT_TILES
 from repro.models import attention, layers, mamba, moe
+from repro.runtime import tracing
 
 ShardFn = Callable[[jax.Array, str], jax.Array]
 
@@ -86,6 +87,7 @@ def init_params(cfg: ModelConfig, key) -> dict:
 # ---------------------------------------------------------------------------
 # Shared pieces
 # ---------------------------------------------------------------------------
+@tracing.scope(tracing.MLP)
 def _mlp_forward(p: dict, cfg: ModelConfig, x: jax.Array, shard: ShardFn) -> jax.Array:
     up = x @ p["w_up"]
     up = shard(up, "act_btf")
@@ -97,6 +99,7 @@ def _mlp_forward(p: dict, cfg: ModelConfig, x: jax.Array, shard: ShardFn) -> jax
     return shard(h.astype(x.dtype) @ p["w_down"], "act_btd")
 
 
+@tracing.scope(tracing.EMBED)
 def _embed(params: dict, cfg: ModelConfig, inputs: jax.Array, positions) -> jax.Array:
     if cfg.input_kind == "tokens":
         h = params["embed"][inputs]  # (B, S, d)
@@ -108,6 +111,7 @@ def _embed(params: dict, cfg: ModelConfig, inputs: jax.Array, positions) -> jax.
     return h
 
 
+@tracing.scope(tracing.LOGITS)
 def _logits(params: dict, cfg: ModelConfig, h: jax.Array, shard: ShardFn) -> jax.Array:
     h = layers.norm(h, params["final_norm"], cfg.norm, shard=shard)
     if cfg.tie_embeddings:

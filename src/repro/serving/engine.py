@@ -5,10 +5,16 @@ The engine keeps a fixed batch of decode slots (static shapes → one compiled
 request is prefix-filled into it.  Mamba/hybrid archs carry conv+SSM state
 instead of (or alongside) KV cache — the cache pytree comes from
 ``transformer.init_cache`` and is opaque here.
+
+What the engine did is readable three ways: ``stats()`` (its call and
+admission counts beside ``pending()``), each ``Request``'s host times, and
+host spans on a profiler trace (``serve.admit``, ``serve.feed``,
+``serve.decode``, ``serve.sync``, ``serve.bookkeep``; ``runtime/tracing.py``).
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional
 
@@ -19,6 +25,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.space import SchedulePlan
 from repro.models import transformer
+from repro.runtime import tracing
 from repro.training.train_step import make_serve_step, tiles_from_plan
 
 
@@ -29,6 +36,11 @@ class Request:
     max_new_tokens: int
     generated: List[int] = field(default_factory=list)
     done: bool = False
+    # host clock (time.perf_counter) when submitted, given a slot, and when
+    # its first token reached the host
+    submitted_s: Optional[float] = None
+    admitted_s: Optional[float] = None
+    first_token_s: Optional[float] = None
 
 
 class ServingEngine:
@@ -58,6 +70,10 @@ class ServingEngine:
         self.queue: List[Request] = []
         self.finished: List[Request] = []
         self._uid = 0
+        # decode calls that fed one prompt token / produced a token for every
+        # active slot; requests given a slot; slot caches zeroed
+        self.counts = {"feed_calls": 0, "decode_calls": 0, "admissions": 0,
+                       "slot_resets": 0}
 
         tiles = tiles_from_plan(self.plan)
         step = make_serve_step(cfg, None, self.plan)
@@ -71,14 +87,16 @@ class ServingEngine:
             # so a prefill feed for one slot can never clobber its
             # neighbours' caches.
             logits, new_cache = step(params, cache, tokens[:, None], cur)
-            new_cache = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(
-                    mask.reshape((1, -1) + (1,) * (new.ndim - 2)), new, old
-                ),
-                new_cache,
-                cache,
-            )
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with tracing.scope(tracing.CACHE_COMMIT):
+                new_cache = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(
+                        mask.reshape((1, -1) + (1,) * (new.ndim - 2)), new, old
+                    ),
+                    new_cache,
+                    cache,
+                )
+            with tracing.scope(tracing.SAMPLE):
+                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return next_tok, new_cache
 
         @jax.jit
@@ -97,7 +115,8 @@ class ServingEngine:
     # -- public API -----------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new_tokens: int = 16) -> int:
         self._uid += 1
-        self.queue.append(Request(self._uid, np.asarray(prompt, np.int32), max_new_tokens))
+        self.queue.append(Request(self._uid, np.asarray(prompt, np.int32), max_new_tokens,
+                                  submitted_s=time.perf_counter()))
         return self._uid
 
     def run(self, max_steps: int = 1000) -> List[Request]:
@@ -122,20 +141,31 @@ class ServingEngine:
             "queued": len(self.queue),
         }
 
+    def stats(self) -> dict:
+        """The engine's counts since it was built, with ``pending()``."""
+        return {**self.counts, **self.pending()}
+
     # -- internals -----------------------------------------------------------------
     def _fill_slots(self):
         for i in range(self.slots):
             if self.active[i] is None and self.queue:
-                req = self.queue.pop(0)
-                self.active[i] = req
-                self.cache = self._reset_slot(self.cache, i)
-                # sequential prompt feed (prefill via decode steps keeps the
-                # engine single-kernel; bulk prefill uses make_prefill_step)
-                self.lengths[i] = 0
-                for t in req.prompt[:-1]:
-                    self.tokens[i] = t
-                    self._single_feed(i)
-                self.tokens[i] = req.prompt[-1]
+                with tracing.span("serve.admit"):
+                    self._admit(i, self.queue.pop(0))
+
+    def _admit(self, i: int, req: Request):
+        req.admitted_s = time.perf_counter()
+        self.active[i] = req
+        self.counts["admissions"] += 1
+        self.cache = self._reset_slot(self.cache, i)
+        self.counts["slot_resets"] += 1
+        # sequential prompt feed (prefill via decode steps keeps the
+        # engine single-kernel; bulk prefill uses make_prefill_step)
+        self.lengths[i] = 0
+        for t in req.prompt[:-1]:
+            self.tokens[i] = t
+            with tracing.span("serve.feed"):
+                self._single_feed(i)
+        self.tokens[i] = req.prompt[-1]
 
     def _single_feed(self, slot: int):
         # prefill one token for ONE slot: per-slot positions plus a one-hot
@@ -148,6 +178,7 @@ class ServingEngine:
             self.params, self.cache, jnp.asarray(self.tokens),
             jnp.asarray(self.lengths), jnp.asarray(mask),
         )
+        self.counts["feed_calls"] += 1
         self.lengths[slot] += 1
 
     def _step(self):
@@ -155,14 +186,24 @@ class ServingEngine:
         # (pre-fix: one shared cur = lengths.max() wrote every slot's KV at
         # the longest slot's position)
         mask = np.array([r is not None for r in self.active], bool)
-        next_tok, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(self.tokens),
-            jnp.asarray(self.lengths), jnp.asarray(mask),
-        )
-        next_np = np.asarray(next_tok)
+        with tracing.span("serve.decode"):
+            next_tok, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(self.tokens),
+                jnp.asarray(self.lengths), jnp.asarray(mask),
+            )
+        self.counts["decode_calls"] += 1
+        with tracing.span("serve.sync"):
+            next_np = np.asarray(next_tok)
+        with tracing.span("serve.bookkeep"):
+            self._bookkeep(next_np)
+
+    def _bookkeep(self, next_np: np.ndarray):
+        now = time.perf_counter()
         for i, req in enumerate(self.active):
             if req is None:
                 continue
+            if not req.generated:
+                req.first_token_s = now
             req.generated.append(int(next_np[i]))
             self.tokens[i] = next_np[i]
             self.lengths[i] += 1

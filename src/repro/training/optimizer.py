@@ -13,6 +13,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import tracing
+
 
 @dataclass(frozen=True)
 class OptimizerConfig:
@@ -79,6 +81,7 @@ def global_norm(tree) -> jax.Array:
     return jnp.sqrt(sq)
 
 
+@tracing.scope(tracing.OPTIMIZER)
 def apply_updates(
     params, grads, state, oc: OptimizerConfig
 ) -> Tuple[Any, Dict[str, Any]]:

@@ -19,6 +19,7 @@ from repro.kernels.ops import KernelTiles
 from repro.models import transformer
 from repro.models.losses import cross_entropy
 from repro.models.moe import MoEDist
+from repro.runtime import tracing
 from repro.sharding.rules import ShardingRules, make_shard_fn
 from repro.training import optimizer as optim
 
@@ -101,7 +102,8 @@ def make_loss_fn(
             unroll=unroll,
             moe_dist=moe_dist,
         )
-        return cross_entropy(logits[:, :-1, :], labels[:, 1:])
+        with tracing.scope(tracing.LOSS):
+            return cross_entropy(logits[:, :-1, :], labels[:, 1:])
 
     return loss_fn
 

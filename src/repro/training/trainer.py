@@ -3,7 +3,9 @@
 Single-process reference loop (the multi-host deployment wires the same
 object to per-host pipelines and the pod coordinator's heartbeat stream —
 all decisions below are host-side control-plane logic, identical at fleet
-scale).
+scale).  Each step's host work is a span on a profiler trace (``train.batch``,
+``train.dispatch``, ``train.sync``, ``train.log``, ``train.ckpt``;
+``runtime/tracing.py``).
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ from repro.configs.base import InputShape, ModelConfig
 from repro.core.space import SchedulePlan
 from repro.data.pipeline import DataConfig, Pipeline
 from repro.models import transformer
+from repro.runtime import tracing
 from repro.runtime.fault_tolerance import (
     HeartbeatMonitor,
     StragglerPolicy,
@@ -84,31 +87,36 @@ class Trainer:
         host = f"host{self.pipe.dc.host_index}"
         while step < self.tc.total_steps:
             t0 = time.perf_counter()
-            batch = {
-                k: jnp.asarray(v) for k, v in self.pipe.batch_at(step).items()
-            }
-            params, opt_state, m = self.step_fn(params, opt_state, batch)
-            jax.block_until_ready(m)  # honest step timing (async dispatch)
+            with tracing.span("train.batch"):
+                batch = {
+                    k: jnp.asarray(v) for k, v in self.pipe.batch_at(step).items()
+                }
+            with tracing.span("train.dispatch"):
+                params, opt_state, m = self.step_fn(params, opt_state, batch)
+            with tracing.span("train.sync"):
+                jax.block_until_ready(m)  # honest step timing (async dispatch)
             dt = time.perf_counter() - t0
             self.stragglers.observe(host, dt)
             if self.monitor is not None:
                 self.monitor.beat(host)
             step += 1
             if step % self.tc.log_every == 0 or step == 1:
-                rec = {
-                    "step": step,
-                    "loss": float(m["loss"]),
-                    "grad_norm": float(m["grad_norm"]),
-                    "lr": float(m["lr"]),
-                    "step_time_s": dt,
-                }
-                self.metrics_log.append(rec)
+                with tracing.span("train.log"):
+                    rec = {
+                        "step": step,
+                        "loss": float(m["loss"]),
+                        "grad_norm": float(m["grad_norm"]),
+                        "lr": float(m["lr"]),
+                        "step_time_s": dt,
+                    }
+                    self.metrics_log.append(rec)
             if step % self.tc.ckpt_every == 0:
-                self.ckpt.save(
-                    step, params, opt_state,
-                    extra={"data_step": step},
-                    blocking=not self.tc.ckpt_async,
-                )
+                with tracing.span("train.ckpt"):
+                    self.ckpt.save(
+                        step, params, opt_state,
+                        extra={"data_step": step},
+                        blocking=not self.tc.ckpt_async,
+                    )
         self.ckpt.wait()
         return params, opt_state, step
 

@@ -9,6 +9,7 @@ because only one process may load the TPU library at a time.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,3 +108,31 @@ def test_flash_attention_custom_vjp_backward_compiles(one_chip):
     f = _vjp_of(kernel, ref.attention, "attention")
     c = _compile(f, one_chip, *ATTN)
     assert "kernel_bwd_attention" in c.as_text()
+
+
+# each kernel's instruction is named by its ``pallas_call(name=...)``: the
+# device trace and the roofline metrics find it by that name, whatever the
+# Python function or the scope around it is called
+@pytest.mark.parametrize("kernel,shapes", [
+    (functools.partial(flash_attention.__wrapped__, block_q=128, block_kv=128),
+     [((1, 4, 256, 64), BF16), ((1, 2, 256, 64), BF16), ((1, 2, 256, 64), BF16)]),
+    (functools.partial(moe_gemm.__wrapped__, block_c=128, block_f=256, block_d=256),
+     [((4, 128, 256), BF16), ((4, 256, 256), BF16)]),
+    (rmsnorm.__wrapped__, [((1, 256, 256), BF16), ((256,), BF16)]),
+    (functools.partial(selective_scan.__wrapped__, chunk=128, d_block=256),
+     [((1, 256, 256), BF16), ((1, 256, 256), BF16), ((256, 16), jnp.float32),
+      ((1, 256, 16), BF16), ((1, 256, 16), BF16), ((256,), jnp.float32)]),
+    (quantize_int8.__wrapped__, [((256, 128), jnp.float32)]),
+    (dequantize_int8.__wrapped__, [((256, 128), jnp.int8), ((256, 1), jnp.float32)]),
+], ids=["flash_attention", "moe_gemm", "rmsnorm", "selective_scan", "quantize_int8",
+        "dequantize_int8"])
+def test_kernel_instruction_carries_its_name(one_chip, request, kernel, shapes):
+    name = request.node.callspec.id
+
+    def elsewhere(*args):
+        with jax.named_scope("elsewhere"):
+            return kernel(*args)
+
+    text = _compile(elsewhere, one_chip, *shapes).as_text()
+    calls = [l for l in text.splitlines() if "custom_call_target=\"tpu_custom_call\"" in l]
+    assert calls and all(re.search(rf"%{name}(\.\d+)? = ", l) for l in calls), calls

@@ -93,52 +93,48 @@ def decode_step(
     x: jax.Array,  # (B, 1, d)
     cur: jax.Array,  # int32 position of the new token: scalar, or (B,) per-row
     *,
+    layer: jax.Array,
     shard: Callable[[jax.Array, str], jax.Array],
 ) -> Tuple[jax.Array, dict]:
+    """One token of attention for every slot against period ``layer`` of the
+    stacked cache, which it only reads.
+
+    ``cache`` holds this block's K/V for every period, ``(P, B, Hkv, L, hd)``
+    (int8 K/V add per-row scales ``k_s``/``v_s``).  The new token's K/V row
+    takes position ``cur`` in what the attention reads; the cache itself
+    takes the rows later, all periods at once (``write_rows``).  Returns the
+    block output and the new rows, ``(B, Hkv, 1, w)`` in the cache's dtypes.
+    """
     B = x.shape[0]
     hd = cfg.resolved_head_dim
     cur = jnp.asarray(cur, jnp.int32)
     per_row = cur.ndim == 1  # continuous batching: each row at its own length
-
-    @tracing.scope(tracing.KV_WRITE)
-    def _write_at_cur(c, new):
-        # KV write at the token position — per-row positions need a
-        # per-row dynamic_update_slice (vmapped over the batch axis)
-        if per_row:
-            return jax.vmap(
-                lambda cb, nb, pb: jax.lax.dynamic_update_slice(cb, nb, (0, pb, 0))
-            )(c, new, cur)
-        return jax.lax.dynamic_update_slice(c, new, (0, 0, cur, 0))
-
     q, k_new, v_new = _project(p, x, cfg)  # (B,H,1,hd), (B,Hkv,1,hd)
     pos = cur[:, None] if per_row else jnp.full((B, 1), cur, jnp.int32)
     if cfg.pos_kind == "mrope":
         pos = jnp.broadcast_to(pos[:, None, :], (B, 3, 1))
     q, k_new = layers.apply_positions(q, k_new, cfg, pos)
-    int8_kv = "k_s" in cache
-    new_cache = {}
-    if int8_kv:
+    if "k_s" in cache:
         kq, ks = _quant_kv(k_new)
         vq, vs = _quant_kv(v_new)
-        kc = _write_at_cur(cache["k"], kq)
-        vc = _write_at_cur(cache["v"], vq)
-        kss = _write_at_cur(cache["k_s"], ks)
-        vss = _write_at_cur(cache["v_s"], vs)
-        kc = shard(kc, "kv_cache")
-        vc = shard(vc, "kv_cache")
-        new_cache = {"k": kc, "v": vc, "k_s": kss, "v_s": vss}
-        # scales fold into the logits / probs (per b,h,t) — the int8 cache is
-        # never dequantized to a full-width tensor
-        k, v = kc, vc
-        k_scale = kss[..., 0]  # (B, Hkv, S)
-        v_scale = vss[..., 0]
+        rows = {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
     else:
-        k = _write_at_cur(cache["k"], k_new.astype(cache["k"].dtype))
-        v = _write_at_cur(cache["v"], v_new.astype(cache["v"].dtype))
-        k = shard(k, "kv_cache")
-        v = shard(v, "kv_cache")
-        new_cache = {"k": k, "v": v}
-        k_scale = v_scale = None
+        rows = {"k": k_new, "v": v_new}
+    rows = {n: r.astype(cache[n].dtype) for n, r in rows.items()}
+    with tracing.scope(tracing.KV_WRITE):
+        # the slab as the cache will hold it: XLA fuses this select into the
+        # attention's read of the slab
+        t = jnp.arange(cache["k"].shape[3])
+        at_cur = t[None, None, :, None] == (cur[:, None, None, None] if per_row else cur)
+        kv = {n: jnp.where(at_cur, rows[n],
+                           jax.lax.dynamic_index_in_dim(c, layer, keepdims=False))
+              for n, c in cache.items()}
+    k = shard(kv["k"], "kv_cache")
+    v = shard(kv["v"], "kv_cache")
+    # int8: the scales fold into the logits / probs (per b,h,t), so the int8
+    # cache is never dequantized to a full-width tensor
+    k_scale = kv["k_s"][..., 0] if "k_s" in kv else None  # (B, Hkv, S)
+    v_scale = kv["v_s"][..., 0] if "v_s" in kv else None
     # GQA-grouped masked attention over the full cache: query heads reshape
     # to (Hkv, groups) so the cache is NEVER repeated (a materialized
     # jnp.repeat was measured at 4e11 HBM bytes/device on deepseek decode —
@@ -164,4 +160,37 @@ def decode_step(
     ).astype(x.dtype)
     o = o.reshape(B, cfg.n_heads, 1, hd).transpose(0, 2, 1, 3).reshape(B, 1, -1)
     out = shard(o @ p["wo"], "act_btd")
-    return out, new_cache
+    return out, rows
+
+
+def write_rows(
+    cache: dict,
+    rows: dict,  # per leaf (P, B, Hkv, 1, w): every period's new rows
+    cur: jax.Array,
+    commit: Optional[jax.Array],
+) -> dict:
+    """Write each slot's new rows, all periods at once, in place at
+    ``(:, b, :, cur[b], :)`` of the stacked cache; a slot whose ``commit``
+    entry is False keeps its old rows (``commit=None`` writes every slot).
+
+    The rows go in as ``(P, n, Hkv, 1, w)`` blocks of n slots: one block of
+    all slots when they share a position, else one per slot.  XLA then keeps
+    the cache's own layout and updates it in place, where a scatter, or rows
+    gathered into one array, makes it re-lay the whole cache out on TPU.
+    """
+    cur = jnp.asarray(cur, jnp.int32)
+    P, B = rows["k"].shape[:2]
+    blocks = ([(b, 1, (0, b, 0, cur[b], 0)) for b in range(B)] if cur.ndim == 1
+              else [(0, B, (0, 0, 0, cur, 0))])
+    out = {}
+    for n, c in cache.items():
+        for b, m, at in blocks:
+            part = rows[n][:, b:b + m]
+            if commit is not None:
+                with tracing.scope(tracing.CACHE_COMMIT):
+                    was = jax.lax.dynamic_slice(c, at, part.shape)
+                    part = jnp.where(commit[b:b + m].reshape(1, m, 1, 1, 1), part, was)
+            with tracing.scope(tracing.KV_WRITE):
+                c = jax.lax.dynamic_update_slice(c, part, at)
+        out[n] = c
+    return out

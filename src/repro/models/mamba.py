@@ -1,7 +1,7 @@
 """Mamba-1 block: causal conv + selective scan; O(1)-state decode step."""
 from __future__ import annotations
 
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +10,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.kernels.ops import KernelTiles
 from repro.models import layers
+from repro.runtime import tracing
 
 
 def init(cfg: ModelConfig, key) -> dict:
@@ -88,13 +89,20 @@ def decode_step(
     cache: dict,
     x: jax.Array,  # (B, 1, d)
     *,
+    layer: jax.Array,
+    commit: Optional[jax.Array],
     shard: Callable[[jax.Array, str], jax.Array],
 ) -> Tuple[jax.Array, dict]:
-    B = x.shape[0]
+    """One token for every slot, from period ``layer`` of the stacked conv and
+    SSM state (leading period axis).  A slot whose ``commit`` entry is False
+    keeps its old state (``commit=None`` updates every slot).  Returns the
+    block output and the updated stacked state."""
+    state = {n: jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+             for n, c in cache.items()}
     xz = x[:, 0] @ p["in_proj"]  # (B, 2*Di)
     xi, z = jnp.split(xz, 2, axis=-1)
     # conv over (cached K-1 inputs, new input)
-    window = jnp.concatenate([cache["conv"], xi[:, None, :]], axis=1)  # (B,K,Di)
+    window = jnp.concatenate([state["conv"], xi[:, None, :]], axis=1)  # (B,K,Di)
     w = p["conv_w"].astype(jnp.float32)
     xc = jnp.sum(window.astype(jnp.float32) * w[None], axis=1) + p["conv_b"].astype(
         jnp.float32
@@ -102,8 +110,14 @@ def decode_step(
     xc = jax.nn.silu(xc).astype(x.dtype)  # (B, Di)
     dt, A, Bm, Cm = _ssm_inputs(p, xc, cfg)
     new_state, y = ops.selective_scan_step(
-        cache["ssm"], xc, dt.astype(xc.dtype), A, Bm, Cm, p["Dp"]
+        state["ssm"], xc, dt.astype(xc.dtype), A, Bm, Cm, p["Dp"]
     )
     y = y * jax.nn.silu(z)
     out = shard((y @ p["out_proj"])[:, None, :], "act_btd")
-    return out, {"conv": window[:, 1:, :], "ssm": new_state}
+    new = {"conv": window[:, 1:, :], "ssm": new_state}
+    if commit is not None:
+        with tracing.scope(tracing.CACHE_COMMIT):
+            new = {n: jnp.where(commit.reshape((-1,) + (1,) * (v.ndim - 1)), v, state[n])
+                   for n, v in new.items()}
+    return out, {n: jax.lax.dynamic_update_index_in_dim(cache[n], v, layer, 0)
+                 for n, v in new.items()}

@@ -227,11 +227,22 @@ def decode_step(
     inputs: jax.Array,  # (B,1) token or (B,1,d) embedding
     cur: jax.Array,  # int32 position of the new token: scalar, or (B,) per-row
     *,
+    commit: Optional[jax.Array] = None,
     tiles: KernelTiles = DEFAULT_TILES,
     shard: ShardFn = _identity_shard,
     unroll: bool = False,
     moe_dist=None,
 ) -> Tuple[jax.Array, dict]:
+    """One token for every slot.  ``commit`` (``(B,)`` bool) names the slots
+    whose cache takes the token; the others keep theirs.  The default commits
+    every slot.
+
+    The period scan only reads the attention caches (by index, not as the
+    scan's ``xs``) and returns each period's new K/V rows, which
+    ``attention.write_rows`` then writes for every period at once; the Mamba
+    state is the scan's carry, each period's written back in place.  So a
+    cache the caller donates is updated in place.
+    """
     plan = cfg.layer_plan()
     cur = jnp.asarray(cur, jnp.int32)
     pos = (
@@ -239,20 +250,25 @@ def decode_step(
         else jnp.broadcast_to(cur, (inputs.shape[0], 1)).astype(jnp.int32)
     )
     h = shard(_embed(params, cfg, inputs, pos), "act_btd")
+    kv = {f"b{i}": cache[f"b{i}"] for i, spec in enumerate(plan) if spec.mixer == "attn"}
+    state = {n: c for n, c in cache.items() if n not in kv}
 
-    def period_body(h, xs):
-        period_params, period_cache = xs
-        new_cache = {}
+    def period_body(carry, xs):
+        h, state = carry
+        period_params, layer = xs
+        state, rows = dict(state), {}
         for i, spec in enumerate(plan):
-            bp = period_params[f"b{i}"]
+            n = f"b{i}"
+            bp = period_params[n]
             hn = layers.norm(h, bp["norm1"], cfg.norm, shard=shard)
             if spec.mixer == "attn":
-                mixed, new_cache[f"b{i}"] = attention.decode_step(
-                    bp["attn"], cfg, period_cache[f"b{i}"], hn, cur, shard=shard
+                mixed, rows[n] = attention.decode_step(
+                    bp["attn"], cfg, kv[n], hn, cur, layer=layer, shard=shard
                 )
             else:
-                mixed, new_cache[f"b{i}"] = mamba.decode_step(
-                    bp["mamba"], cfg, period_cache[f"b{i}"], hn, shard=shard
+                mixed, state[n] = mamba.decode_step(
+                    bp["mamba"], cfg, state[n], hn, layer=layer, commit=commit,
+                    shard=shard,
                 )
             h = h + mixed
             if spec.mlp != "none":
@@ -263,11 +279,13 @@ def decode_step(
                 else:
                     out = _mlp_forward(bp["mlp"], cfg, hn, shard)
                 h = h + out
-        return h, new_cache
+        return (h, state), rows
 
-    h, new_cache = jax.lax.scan(
-        period_body, h, (params["blocks"], cache),
+    (h, state), rows = jax.lax.scan(
+        period_body, (h, state), (params["blocks"], jnp.arange(cfg.n_periods)),
         unroll=cfg.n_periods if unroll else 1,
     )
+    cache = {**state, **{n: attention.write_rows(kv[n], rows[n], cur, commit)
+                         for n in kv}}
     logits = _logits(params, cfg, h[:, -1, :], shard)  # (B, V)
-    return logits, new_cache
+    return logits, cache

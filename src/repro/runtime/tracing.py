@@ -31,7 +31,7 @@ SPAN_PREFIX = "repro."
 EMBED = "embed"
 NORM = "norm"
 ATTENTION = "attention"  # projections, the attention kernel, output projection
-KV_WRITE = "kv_write"  # decode: the new token's K/V written into the cache
+KV_WRITE = "kv_write"  # decode: the new token's K/V rows written in place
 MLP = "mlp"
 MOE_ROUTE = "moe_route"  # router logits, softmax, top-k
 MOE_DISPATCH = "moe_dispatch"  # sort by expert through the grouped scatter-add
@@ -40,7 +40,7 @@ MOE_COMBINE = "moe_combine"  # weighted gather back to token order
 LOGITS = "logits"
 LOSS = "loss"
 OPTIMIZER = "optimizer"  # gradient clip and AdamW
-CACHE_COMMIT = "cache_commit"  # the engine's masked write of the new cache
+CACHE_COMMIT = "cache_commit"  # decode: a slot's new rows or state kept or dropped
 SAMPLE = "sample"
 
 LAYER_SCOPES = (EMBED, NORM, ATTENTION, KV_WRITE, MLP, MOE_ROUTE, MOE_DISPATCH,

@@ -13,10 +13,10 @@ host spans on a profiler trace (``serve.admit``, ``serve.feed``,
 """
 from __future__ import annotations
 
-import dataclasses
+import functools
 import time
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +26,7 @@ from repro.configs.base import ModelConfig
 from repro.core.space import SchedulePlan
 from repro.models import transformer
 from repro.runtime import tracing
-from repro.training.train_step import make_serve_step, tiles_from_plan
+from repro.training.train_step import make_serve_step
 
 
 @dataclass
@@ -75,10 +75,11 @@ class ServingEngine:
         self.counts = {"feed_calls": 0, "decode_calls": 0, "admissions": 0,
                        "slot_resets": 0}
 
-        tiles = tiles_from_plan(self.plan)
         step = make_serve_step(cfg, None, self.plan)
 
-        @jax.jit
+        # the cache is donated to both programs: each call updates it in place
+        # and the engine keeps only the cache a call returns
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def _decode(params, cache, tokens, cur, mask):
             # cur: (B,) per-slot positions — every slot reads/writes its OWN
             # length, so requests of different lengths can share the batch.
@@ -86,20 +87,12 @@ class ServingEngine:
             # conv/SSM state) are committed; the rest keep their old state,
             # so a prefill feed for one slot can never clobber its
             # neighbours' caches.
-            logits, new_cache = step(params, cache, tokens[:, None], cur)
-            with tracing.scope(tracing.CACHE_COMMIT):
-                new_cache = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(
-                        mask.reshape((1, -1) + (1,) * (new.ndim - 2)), new, old
-                    ),
-                    new_cache,
-                    cache,
-                )
+            logits, cache = step(params, cache, tokens[:, None], cur, commit=mask)
             with tracing.scope(tracing.SAMPLE):
                 next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return next_tok, new_cache
+            return next_tok, cache
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(0,))
         def _reset_slot(cache, slot):
             # zero one slot's cache state on (re)assignment: stale KV past
             # the new request's length is masked by position anyway, but

@@ -230,17 +230,18 @@ def make_serve_step(
     mesh_spec: Optional[MeshSpec] = None,
     unroll: bool = False,
 ) -> Callable:
-    """(params, cache, inputs, cur) -> (logits, cache): one decode token."""
+    """(params, cache, inputs, cur, commit=None) -> (logits, cache): one
+    decode token (``transformer.decode_step``)."""
     tiles = tiles_from_plan(plan)
     rules = ShardingRules(cfg, shape, plan, mesh_spec) if mesh_spec else None
     shard = make_shard_fn(mesh, rules)
 
     moe_dist = moe_dist_for(cfg, shape, plan, mesh, mesh_spec)
 
-    def serve_step(params, cache, inputs, cur):
+    def serve_step(params, cache, inputs, cur, commit=None):
         return transformer.decode_step(
-            params, cfg, cache, inputs, cur, tiles=tiles, shard=shard,
-            unroll=unroll, moe_dist=moe_dist,
+            params, cfg, cache, inputs, cur, commit=commit, tiles=tiles,
+            shard=shard, unroll=unroll, moe_dist=moe_dist,
         )
 
     return serve_step

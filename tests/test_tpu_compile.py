@@ -136,3 +136,49 @@ def test_kernel_instruction_carries_its_name(one_chip, request, kernel, shapes):
     text = _compile(elsewhere, one_chip, *shapes).as_text()
     calls = [l for l in text.splitlines() if "custom_call_target=\"tpu_custom_call\"" in l]
     assert calls and all(re.search(rf"%{name}(\.\d+)? = ", l) for l in calls), calls
+
+
+def _unfused_instructions(hlo: str) -> list:
+    """The instructions of a compiled module that run as ops of their own:
+    those of every computation but the bodies of fusions."""
+    comps = re.split(r"\n(?=%[\w.\-]+ \(|ENTRY )", hlo)
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo))
+    return [line for comp in comps
+            if not (m := re.match(r"%([\w.\-]+) ", comp)) or m.group(1) not in fused
+            for line in comp.splitlines()]
+
+
+def test_serve_decode_updates_the_cache_in_place(one_chip, monkeypatch):
+    """The engine's decode program at the serving cell's size (granite-3-2b,
+    16 slots x 2048) writes the 2,684,354,560 B cache in the buffer it was
+    given, and holds no second copy of it or of a layer's 67 MB K/V slab.
+
+    Readings of the compiled program: the engine that committed through a
+    whole-cache ``where`` into a fresh cache read ``alias_size_in_bytes`` 0
+    and ``temp_size_in_bytes`` 135,251,456 (each layer's slab copied
+    between layouts); the in-place one reads the cache's size and
+    1,613,824."""
+    from repro.configs import get_config
+    from repro.models import transformer
+    from repro.serving.engine import ServingEngine
+
+    cfg, B, L = get_config("granite-3-2b"), 16, 2048
+    make = transformer.init_cache
+    monkeypatch.setattr(transformer, "init_cache",
+                        lambda *a, **k: jax.eval_shape(lambda: make(*a, **k)))
+    params = jax.eval_shape(lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = ServingEngine(cfg, params, batch_slots=B, max_len=L)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    vec = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)
+    compiled = eng._decode.lower(on_chip(params), on_chip(eng.cache), vec(jnp.int32),
+                                 vec(jnp.int32), vec(jnp.bool_)).compile()
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(eng.cache))
+    assert cache_bytes == 2_684_354_560
+    assert ma.alias_size_in_bytes == cache_bytes
+    assert ma.temp_size_in_bytes < 16 * 2**20
+    # no op of its own copies, transposes or selects a cache or a slab
+    shaped = rf"= bf16\[(40,)?(1,)?{B},8,{L},64\]\S* (copy|copy-start|transpose|select)\("
+    whole = [l for l in _unfused_instructions(compiled.as_text()) if re.search(shaped, l)]
+    assert not whole, whole[:3]

@@ -20,6 +20,7 @@ _MODULES = {
     "phi3.5-moe-42b-a6.6b": "phi3_5_moe_42b_a6_6b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "falcon-mamba-7b": "falcon_mamba_7b",
+    "jamba2-3b": "jamba2_3b",
 }
 
 ARCH_IDS: List[str] = list(_MODULES)

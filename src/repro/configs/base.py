@@ -78,6 +78,8 @@ class ModelConfig:
     dt_rank: int = 0  # 0 -> ceil(d_model / 16)
     conv_width: int = 4
     attn_every: int = 0  # hybrid: one attention layer per `attn_every` layers
+    attn_offset: int = 0  # hybrid: the attention layer's slot in its period
+    ssm_input_norms: bool = False  # RMSNorm on the dt slice, B and C (Jamba)
     # --- misc ---
     dtype: str = "bfloat16"
     source: str = ""
@@ -117,6 +119,7 @@ class ModelConfig:
         period = 1
         if self.attn_every:
             period = self.attn_every
+            assert 0 <= self.attn_offset < period, (self.name, self.attn_offset)
         if self.is_moe:
             period = _lcm(period, self.moe_every)
         plan = []
@@ -124,7 +127,7 @@ class ModelConfig:
             if self.is_attention_free:
                 mixer = "mamba"
             elif self.attn_every:
-                mixer = "attn" if i == 0 else "mamba"
+                mixer = "attn" if i == self.attn_offset else "mamba"
             else:
                 mixer = "attn"
             if self.d_ff == 0:
@@ -161,7 +164,8 @@ class ModelConfig:
         dt_proj = dtr * di + di
         a_d = di * ds + di
         out_proj = di * d
-        return in_proj + conv + x_proj + dt_proj + a_d + out_proj
+        norms = dtr + 2 * ds if self.ssm_input_norms else 0
+        return in_proj + conv + x_proj + dt_proj + a_d + out_proj + norms
 
     def _mlp_params(self, spec: LayerSpec) -> Tuple[int, int]:
         """(total, active) parameters of the MLP slot."""

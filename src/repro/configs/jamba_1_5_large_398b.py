@@ -1,7 +1,9 @@
 """Jamba-1.5-Large (398B total) [arXiv:2403.19887; hf].
 
-Hybrid: 1 attention layer per 8 (1:7 attn:mamba interleave), MoE (16 experts,
-top-2) on every other layer.  72 layers = 9 periods of 8.
+Hybrid: 1 attention layer per 8 (1:7 attn:mamba interleave) at slot 4
+(``attn_layer_offset``), MoE (16 experts, top-2) on every other layer.  72
+layers = 9 periods of 8.  The Mamba mixers pass the dt slice, B and C
+through RMSNorms.
 """
 from repro.configs.base import ModelConfig
 
@@ -25,5 +27,7 @@ CONFIG = ModelConfig(
     d_inner=16384,  # expand=2
     conv_width=4,
     attn_every=8,
+    attn_offset=4,
+    ssm_input_norms=True,
     source="arXiv:2403.19887; hf",
 )

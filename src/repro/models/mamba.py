@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.kernels.ops import KernelTiles
 from repro.models import layers
 from repro.runtime import tracing
@@ -21,7 +21,7 @@ def init(cfg: ModelConfig, key) -> dict:
     o_scale = 0.02 / max(1.0, (2 * cfg.n_layers) ** 0.5)
     # S4D-real initialization for A: A[d, n] = -(n + 1)
     a = jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32), (Di, N))
-    return {
+    p = {
         "in_proj": layers.dense_init(ks[0], (d, 2 * Di), dt),
         "conv_w": layers.dense_init(ks[1], (K, Di), dt, scale=0.1),
         "conv_b": jnp.zeros((Di,), dt),
@@ -32,6 +32,10 @@ def init(cfg: ModelConfig, key) -> dict:
         "Dp": jnp.ones((Di,), jnp.float32),
         "out_proj": layers.dense_init(ks[4], (Di, d), dt, scale=o_scale),
     }
+    if cfg.ssm_input_norms:
+        p.update(dt_norm=jnp.ones((dtr,), dt), b_norm=jnp.ones((N,), dt),
+                 c_norm=jnp.ones((N,), dt))
+    return p
 
 
 def _conv_causal(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
@@ -48,6 +52,9 @@ def _ssm_inputs(p: dict, xc: jax.Array, cfg: ModelConfig):
     dtr, N = cfg.resolved_dt_rank, cfg.ssm_state
     proj = xc @ p["x_proj"]  # (..., dtr + 2N)
     dt_raw, Bm, Cm = jnp.split(proj, [dtr, dtr + N], axis=-1)
+    if cfg.ssm_input_norms:  # Jamba: each through its own RMSNorm, fused by XLA
+        dt_raw, Bm, Cm = (ref.rmsnorm(v, p[n]) for v, n in
+                          ((dt_raw, "dt_norm"), (Bm, "b_norm"), (Cm, "c_norm")))
     dt = jax.nn.softplus(
         dt_raw.astype(jnp.float32) @ p["dt_w"].astype(jnp.float32)
         + p["dt_b"].astype(jnp.float32)

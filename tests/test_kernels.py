@@ -166,6 +166,30 @@ def test_selective_scan_row_groups_match_ref(B, L, Di, N, chunk, dblk, dtype):
     )
 
 
+def test_selective_scan_at_the_hybrid_cell_block_shapes():
+    """The hybrid prefill cell's kernel call (jamba2-3b: d_inner 5,120, N 16)
+    in the default 256-wide d_inner blocks and the scan_chunk of the cell's
+    tuned plan, over two chunks so that the state carries between them."""
+    from repro.core.autotuner import autotune
+    from repro.kernels.ops import DEFAULT_TILES
+
+    chunk = autotune("jamba2-3b", "prefill_32k", algo="mcts_1s", seed=0).plan.scan_chunk
+    B, L, Di, N = 1, 2 * chunk, 5120, 16
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    u = jax.random.normal(ks[0], (B, L, Di)).astype(jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, L, Di)) - 3.0).astype(jnp.bfloat16)
+    A = -jnp.broadcast_to(jnp.arange(1.0, N + 1), (Di, N))
+    Bm = jax.random.normal(ks[3], (B, L, N)).astype(jnp.bfloat16)
+    Cm = jax.random.normal(ks[4], (B, L, N)).astype(jnp.bfloat16)
+    D = jnp.ones((Di,))
+    out = selective_scan(u, dt, A, Bm, Cm, D, chunk=chunk, d_block=DEFAULT_TILES.scan_d_block,
+                         interpret=True)
+    exp = ref.selective_scan(u, dt, A, Bm, Cm, D)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(exp, np.float32), **_tol(jnp.bfloat16)
+    )
+
+
 def test_selective_scan_rejects_unaligned_chunk():
     u = jnp.zeros((1, 12, 8))
     with pytest.raises(AssertionError):

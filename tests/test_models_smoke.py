@@ -71,6 +71,27 @@ def test_train_step_no_nans(arch, rng_key):
     assert delta > 0
 
 
+@pytest.mark.parametrize("arch,offset", [("jamba2-3b", 7), ("jamba-1.5-large-398b", 4),
+                                         ("granite-3-2b", 0)])
+def test_attention_sits_at_its_published_slot(arch, offset):
+    """Jamba's configs put the period's attention layer at
+    ``attn_layer_offset``; the other configs keep it at slot 0."""
+    cfg = get_config(arch)
+    plan = cfg.layer_plan()
+    assert [i for i, s in enumerate(plan) if s.mixer == "attn"] == [offset]
+    assert cfg.reduced().layer_plan() == plan
+
+
+def test_jamba2_3b_parameter_count():
+    # embedding 65536x2560; 26 Mamba mixers of 41,241,792 (in 2560x10240,
+    # conv 4x5120 + 5120, x 5120x192, dt 160x5120 + 5120, A and D 5120x17,
+    # out 5120x2560, dt/B/C norms 192); 2 attention layers of 13,762,560;
+    # 28 MLPs of 62,914,560 and 28 x 2 norms of 2560; final norm 2560
+    cfg = get_config("jamba2-3b")
+    assert cfg.param_count() == (65536 * 2560 + 26 * 41_241_792 + 2 * 13_762_560
+                                 + 28 * (62_914_560 + 2 * 2560) + 2560) == 3_029_337_472
+
+
 @pytest.mark.slow  # token-by-token decode compiles T distinct step programs
 @pytest.mark.parametrize(
     "arch", ["granite-3-2b", "falcon-mamba-7b", "jamba-1.5-large-398b"]

@@ -68,6 +68,16 @@ def test_selective_scan_compiles_falcon_mamba(one_chip):
              ((Di,), jnp.float32))
 
 
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_selective_scan_compiles_jamba2(one_chip, chunk):
+    # jamba2-3b's prefill cell: d_inner 5120, N 16, 8192 tokens, d_block 256
+    L, Di, N = 8192, 5120, 16
+    fn = functools.partial(selective_scan, chunk=chunk, d_block=256)
+    _compile(fn, one_chip, ((1, L, Di), BF16), ((1, L, Di), BF16),
+             ((Di, N), jnp.float32), ((1, L, N), BF16), ((1, L, N), BF16),
+             ((Di,), jnp.float32))
+
+
 @pytest.mark.parametrize("d,f", [(1024, 512), (512, 1024)])
 def test_moe_gemm_compiles_granite_moe(one_chip, d, f):
     # granite-moe-1b-a400m: 32 experts, capacity 1280 for 4096 tokens top-8

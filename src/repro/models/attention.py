@@ -85,6 +85,56 @@ def _quant_kv(x: jax.Array):
     return q, scale
 
 
+def _cache_rows(cache: dict, k_new: jax.Array, v_new: jax.Array) -> dict:
+    """New K/V rows as the cache holds them: int8 with per-row scales where
+    it has ``k_s``/``v_s``."""
+    if "k_s" in cache:
+        kq, ks = _quant_kv(k_new)
+        vq, vs = _quant_kv(v_new)
+        rows = {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
+    else:
+        rows = {"k": k_new, "v": v_new}
+    return {n: r.astype(cache[n].dtype) for n, r in rows.items()}
+
+
+def _attend(p, cfg, q, kv, lim, shard, dtype) -> jax.Array:
+    """Attention of ``q`` ``(B, H, Q, hd)`` over the K/V slab ``kv``
+    ``(B, Hkv, L, w)``, key position ``t`` visible where ``t <= lim``
+    (broadcast against ``(B, Hkv, groups, Q, L)``), and the output
+    projection: ``(B, Q, d)``."""
+    B, _, Q, hd = q.shape
+    k = shard(kv["k"], "kv_cache")
+    v = shard(kv["v"], "kv_cache")
+    # int8: the scales fold into the logits / probs (per b,h,t), so the int8
+    # cache is never dequantized to a full-width tensor
+    k_scale = kv["k_s"][..., 0] if "k_s" in kv else None  # (B, Hkv, S)
+    v_scale = kv["v_s"][..., 0] if "v_s" in kv else None
+    # GQA-grouped masked attention over the full cache: query heads reshape
+    # to (Hkv, groups) so the cache is NEVER repeated (a materialized
+    # jnp.repeat was measured at 4e11 HBM bytes/device on deepseek decode —
+    # §Perf). bf16 cache reads, f32 accumulation on the (tiny) logits.
+    groups = cfg.n_heads // cfg.n_kv_heads
+    qg = q.reshape(B, cfg.n_kv_heads, groups, Q, hd)
+    kk = k.astype(jnp.bfloat16) if k.dtype == jnp.int8 else k
+    vv = v.astype(jnp.bfloat16) if v.dtype == jnp.int8 else v
+    logits = jnp.einsum(
+        "bkgqd,bktd->bkgqt", qg.astype(jnp.float32), kk.astype(jnp.float32)
+    ) * (hd ** -0.5)
+    if k_scale is not None:
+        logits = logits * k_scale[:, :, None, None, :]
+    t = jnp.arange(k.shape[2])
+    mask = t[None, None, None, None, :] <= lim
+    logits = jnp.where(mask, logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    if v_scale is not None:
+        probs = probs * v_scale[:, :, None, None, :]
+    o = jnp.einsum(
+        "bkgqt,bktd->bkgqd", probs, vv.astype(jnp.float32)
+    ).astype(dtype)
+    o = o.reshape(B, cfg.n_heads, Q, hd).transpose(0, 2, 1, 3).reshape(B, Q, -1)
+    return shard(o @ p["wo"], "act_btd")
+
+
 @tracing.scope(tracing.ATTENTION)
 def decode_step(
     p: dict,
@@ -106,7 +156,6 @@ def decode_step(
     block output and the new rows, ``(B, Hkv, 1, w)`` in the cache's dtypes.
     """
     B = x.shape[0]
-    hd = cfg.resolved_head_dim
     cur = jnp.asarray(cur, jnp.int32)
     per_row = cur.ndim == 1  # continuous batching: each row at its own length
     q, k_new, v_new = _project(p, x, cfg)  # (B,H,1,hd), (B,Hkv,1,hd)
@@ -114,13 +163,7 @@ def decode_step(
     if cfg.pos_kind == "mrope":
         pos = jnp.broadcast_to(pos[:, None, :], (B, 3, 1))
     q, k_new = layers.apply_positions(q, k_new, cfg, pos)
-    if "k_s" in cache:
-        kq, ks = _quant_kv(k_new)
-        vq, vs = _quant_kv(v_new)
-        rows = {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
-    else:
-        rows = {"k": k_new, "v": v_new}
-    rows = {n: r.astype(cache[n].dtype) for n, r in rows.items()}
+    rows = _cache_rows(cache, k_new, v_new)
     with tracing.scope(tracing.KV_WRITE):
         # the slab as the cache will hold it: XLA fuses this select into the
         # attention's read of the slab
@@ -129,38 +172,47 @@ def decode_step(
         kv = {n: jnp.where(at_cur, rows[n],
                            jax.lax.dynamic_index_in_dim(c, layer, keepdims=False))
               for n, c in cache.items()}
-    k = shard(kv["k"], "kv_cache")
-    v = shard(kv["v"], "kv_cache")
-    # int8: the scales fold into the logits / probs (per b,h,t), so the int8
-    # cache is never dequantized to a full-width tensor
-    k_scale = kv["k_s"][..., 0] if "k_s" in kv else None  # (B, Hkv, S)
-    v_scale = kv["v_s"][..., 0] if "v_s" in kv else None
-    # GQA-grouped masked attention over the full cache: query heads reshape
-    # to (Hkv, groups) so the cache is NEVER repeated (a materialized
-    # jnp.repeat was measured at 4e11 HBM bytes/device on deepseek decode —
-    # §Perf). bf16 cache reads, f32 accumulation on the (tiny) logits.
-    groups = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(B, cfg.n_kv_heads, groups, 1, hd)
-    kk = k.astype(jnp.bfloat16) if k.dtype == jnp.int8 else k
-    vv = v.astype(jnp.bfloat16) if v.dtype == jnp.int8 else v
-    logits = jnp.einsum(
-        "bkgqd,bktd->bkgqt", qg.astype(jnp.float32), kk.astype(jnp.float32)
-    ) * (hd ** -0.5)
-    if k_scale is not None:
-        logits = logits * k_scale[:, :, None, None, :]
-    t = jnp.arange(k.shape[2])
     lim = cur[:, None, None, None, None] if per_row else cur
-    mask = t[None, None, None, None, :] <= lim
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    if v_scale is not None:
-        probs = probs * v_scale[:, :, None, None, :]
-    o = jnp.einsum(
-        "bkgqt,bktd->bkgqd", probs, vv.astype(jnp.float32)
-    ).astype(x.dtype)
-    o = o.reshape(B, cfg.n_heads, 1, hd).transpose(0, 2, 1, 3).reshape(B, 1, -1)
-    out = shard(o @ p["wo"], "act_btd")
-    return out, rows
+    return _attend(p, cfg, q, kv, lim, shard, x.dtype), rows
+
+
+@tracing.scope(tracing.ATTENTION)
+def prefill_chunk(
+    p: dict,
+    cfg: ModelConfig,
+    cache: dict,
+    x: jax.Array,  # (1, C, d): C consecutive tokens of one slot's prompt
+    start: jax.Array,  # int32 position of the chunk's first token
+    slot: jax.Array,  # int32 slot whose cache the chunk extends
+    *,
+    layer: jax.Array,
+    shard: Callable[[jax.Array, str], jax.Array],
+) -> Tuple[jax.Array, dict]:
+    """``decode_step`` for C query rows of one slot at positions
+    ``start .. start+C-1``, against period ``layer`` of the stacked cache,
+    which it only reads.
+
+    The chunk's K/V rows take positions ``[start, start+C)`` of the slot's
+    slab in what the attention reads, under the causal mask ``t <= start+j``;
+    the caller keeps ``start + C`` within the cache and writes the rows.
+    Returns the block output and the rows, ``(1, Hkv, C, w)`` in the cache's
+    dtypes.
+    """
+    C = x.shape[1]
+    start = jnp.asarray(start, jnp.int32)
+    q, k_new, v_new = _project(p, x, cfg)  # (1,H,C,hd), (1,Hkv,C,hd)
+    at = start + jnp.arange(C, dtype=jnp.int32)
+    pos = at[None, :]
+    if cfg.pos_kind == "mrope":
+        pos = jnp.broadcast_to(pos[:, None, :], (1, 3, C))
+    q, k_new = layers.apply_positions(q, k_new, cfg, pos)
+    rows = _cache_rows(cache, k_new, v_new)
+    with tracing.scope(tracing.KV_WRITE):
+        kv = {}
+        for n, c in cache.items():
+            slab = jax.lax.dynamic_slice(c, (layer, slot, 0, 0, 0), (1, 1) + c.shape[2:])[0]
+            kv[n] = jax.lax.dynamic_update_slice(slab, rows[n], (0, 0, start, 0))
+    return _attend(p, cfg, q, kv, at.reshape(1, 1, 1, C, 1), shard, x.dtype), rows
 
 
 def write_rows(

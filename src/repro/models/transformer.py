@@ -289,3 +289,61 @@ def decode_step(
                          for n in kv}}
     logits = _logits(params, cfg, h[:, -1, :], shard)  # (B, V)
     return logits, cache
+
+
+def prefills_in_chunks(cfg: ModelConfig) -> bool:
+    """Whether ``prefill_chunk`` runs ``cfg``: every mixer is attention and
+    every MLP dense.  A Mamba mixer would need the chunk's final conv/SSM
+    state, which ``mamba.forward`` does not return; an MoE layer would route
+    a chunk's padding into the experts' capacity."""
+    return all(s.mixer == "attn" and s.mlp != "moe" for s in cfg.layer_plan())
+
+
+def prefill_chunk(
+    params: dict,
+    cfg: ModelConfig,
+    cache: dict,
+    tokens: jax.Array,  # (1, C): one slot's prompt tokens
+    start: jax.Array,  # int32 position of tokens[0, 0]
+    slot: jax.Array,  # int32
+) -> dict:
+    """Put C prompt tokens of one slot into its cache at positions
+    ``start .. start+C-1`` and return the cache; no logits.
+
+    ``decode_step``'s arithmetic for C query rows (``attention.prefill_chunk``):
+    the period scan only reads the cache, and the rows of every period go in
+    after it with one in-place write per leaf, so a donated cache is updated
+    in place.  ``start + C`` must not pass the cache's length: the write's
+    start index would be clamped and the rows shifted.  Only for configs
+    ``prefills_in_chunks`` admits, on one device.
+    """
+    if not prefills_in_chunks(cfg):
+        raise ValueError(f"{cfg.name}: prefill_chunk needs attention mixers and dense MLPs")
+    plan = cfg.layer_plan()
+    shard = _identity_shard
+    start = jnp.asarray(start, jnp.int32)
+    slot = jnp.asarray(slot, jnp.int32)
+    pos = start + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+    h = _embed(params, cfg, tokens, pos)
+
+    def period_body(h, xs):
+        period_params, layer = xs
+        rows = {}
+        for i, spec in enumerate(plan):
+            n = f"b{i}"
+            bp = period_params[n]
+            hn = layers.norm(h, bp["norm1"], cfg.norm, shard=shard)
+            mixed, rows[n] = attention.prefill_chunk(
+                bp["attn"], cfg, cache[n], hn, start, slot, layer=layer, shard=shard
+            )
+            h = h + mixed
+            if spec.mlp != "none":
+                hn = layers.norm(h, bp["norm2"], cfg.norm, shard=shard)
+                h = h + _mlp_forward(bp["mlp"], cfg, hn, shard)
+        return h, rows
+
+    _, rows = jax.lax.scan(period_body, h, (params["blocks"], jnp.arange(cfg.n_periods)))
+    with tracing.scope(tracing.KV_WRITE):
+        return {n: {leaf: jax.lax.dynamic_update_slice(c, rows[n][leaf], (0, slot, 0, start, 0))
+                    for leaf, c in cache[n].items()}
+                for n in cache}

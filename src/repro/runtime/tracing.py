@@ -5,9 +5,9 @@ Two kinds, both on the profiler's own clock:
 * **Host spans** (``span``): ``jax.profiler.TraceAnnotation("repro.<name>")``
   around host work in ``Trainer.run`` and ``ServingEngine`` (``train.batch``,
   ``train.dispatch``, ``train.sync``, ``train.log``, ``train.ckpt``;
-  ``serve.admit``, ``serve.feed``, ``serve.decode``, ``serve.sync``,
-  ``serve.bookkeep``).  With no profiler session running a span records
-  nothing.
+  ``serve.admit``, ``serve.prefill``, ``serve.feed``, ``serve.decode``,
+  ``serve.sync``, ``serve.bookkeep``).  With no profiler session running a
+  span records nothing.
 * **Layer scopes** (``scope``): ``jax.named_scope`` over the device work of
   one layer kind.  A scope lives only in the compiled program's metadata
   (each instruction's ``op_name`` path), so it changes no instruction; the

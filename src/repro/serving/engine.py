@@ -6,10 +6,17 @@ request is prefix-filled into it.  Mamba/hybrid archs carry conv+SSM state
 instead of (or alongside) KV cache — the cache pytree comes from
 ``transformer.init_cache`` and is opaque here.
 
+A prompt's tokens but the last go into its slot's cache in chunks of
+``PREFILL_CHUNK`` (one ``transformer.prefill_chunk`` call each) where the
+model has attention mixers and dense MLPs only
+(``transformer.prefills_in_chunks``), else one decode call per token; the
+last rides the next decode call with the other slots.
+
 What the engine did is readable three ways: ``stats()`` (its call and
 admission counts beside ``pending()``), each ``Request``'s host times, and
-host spans on a profiler trace (``serve.admit``, ``serve.feed``,
-``serve.decode``, ``serve.sync``, ``serve.bookkeep``; ``runtime/tracing.py``).
+host spans on a profiler trace (``serve.admit``, ``serve.prefill``,
+``serve.feed``, ``serve.decode``, ``serve.sync``, ``serve.bookkeep``;
+``runtime/tracing.py``).
 """
 from __future__ import annotations
 
@@ -27,6 +34,11 @@ from repro.core.space import SchedulePlan
 from repro.models import transformer
 from repro.runtime import tracing
 from repro.training.train_step import make_serve_step
+
+# prompt tokens a chunk call puts into a slot's cache: a call then reads the
+# weights about once (on a v5e at granite-3-2b's widths its GEMMs take less
+# time than the weight read), and one width is one compiled program
+PREFILL_CHUNK = 128
 
 
 @dataclass
@@ -70,15 +82,19 @@ class ServingEngine:
         self.queue: List[Request] = []
         self.finished: List[Request] = []
         self._uid = 0
-        # decode calls that fed one prompt token / produced a token for every
-        # active slot; requests given a slot; slot caches zeroed
-        self.counts = {"feed_calls": 0, "decode_calls": 0, "admissions": 0,
-                       "slot_resets": 0}
+        # chunk calls and the prompt tokens they put into caches; decode calls
+        # that fed one prompt token / produced a token for every active slot;
+        # requests given a slot; slot caches zeroed
+        self.counts = {"prefill_calls": 0, "prefill_tokens": 0, "feed_calls": 0,
+                       "decode_calls": 0, "admissions": 0, "slot_resets": 0}
+        # chunk width, 0 where prompts are fed one token per decode call; a
+        # chunk never runs past the cache (see _admit)
+        self.chunk = min(PREFILL_CHUNK, max_len) if transformer.prefills_in_chunks(cfg) else 0
 
         step = make_serve_step(cfg, None, self.plan)
 
-        # the cache is donated to both programs: each call updates it in place
-        # and the engine keeps only the cache a call returns
+        # the cache is donated to every program: each call updates it in
+        # place and the engine keeps only the cache a call returns
         @functools.partial(jax.jit, donate_argnums=(1,))
         def _decode(params, cache, tokens, cur, mask):
             # cur: (B,) per-slot positions — every slot reads/writes its OWN
@@ -102,13 +118,22 @@ class ServingEngine:
                 lambda c: c.at[:, slot].set(jnp.zeros_like(c[:, slot])), cache
             )
 
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def _prefill(params, cache, tokens, start, slot):
+            return transformer.prefill_chunk(params, cfg, cache, tokens, start, slot)
+
         self._decode = _decode
         self._reset_slot = _reset_slot
+        self._prefill = _prefill
 
     # -- public API -----------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new_tokens: int = 16) -> int:
+        prompt = np.asarray(prompt, np.int32)
+        if not 1 <= len(prompt) <= self.max_len:
+            raise ValueError(f"a prompt of {len(prompt)} tokens does not fit a slot of "
+                             f"{self.max_len} positions")
         self._uid += 1
-        self.queue.append(Request(self._uid, np.asarray(prompt, np.int32), max_new_tokens,
+        self.queue.append(Request(self._uid, prompt, max_new_tokens,
                                   submitted_s=time.perf_counter()))
         return self._uid
 
@@ -151,13 +176,30 @@ class ServingEngine:
         self.counts["admissions"] += 1
         self.cache = self._reset_slot(self.cache, i)
         self.counts["slot_resets"] += 1
-        # sequential prompt feed (prefill via decode steps keeps the
-        # engine single-kernel; bulk prefill uses make_prefill_step)
-        self.lengths[i] = 0
-        for t in req.prompt[:-1]:
-            self.tokens[i] = t
-            with tracing.span("serve.feed"):
-                self._single_feed(i)
+        fed = req.prompt[:-1]
+        if self.chunk:
+            C = self.chunk
+            for s in range(0, len(fed), C):
+                # a last chunk that would run past the cache starts early
+                # enough to end at its end, and puts a few rows in again
+                start = min(s, self.max_len - C)
+                chunk = np.zeros((1, C), np.int32)
+                part = fed[start:start + C]
+                chunk[0, :len(part)] = part
+                # rows past the prompt hold the padding's K/V: decode masks
+                # them (t <= cur) and overwrites them as it advances
+                with tracing.span("serve.prefill"):
+                    self.cache = self._prefill(self.params, self.cache, jnp.asarray(chunk),
+                                               jnp.int32(start), jnp.int32(i))
+                self.counts["prefill_calls"] += 1
+                self.counts["prefill_tokens"] += min(C, len(fed) - s)
+            self.lengths[i] = len(fed)
+        else:
+            self.lengths[i] = 0
+            for t in fed:
+                self.tokens[i] = t
+                with tracing.span("serve.feed"):
+                    self._single_feed(i)
         self.tokens[i] = req.prompt[-1]
 
     def _single_feed(self, slot: int):
@@ -167,9 +209,12 @@ class ServingEngine:
         # every active neighbour's cache)
         mask = np.zeros((self.slots,), bool)
         mask[slot] = True
+        # copies: nothing waits for this call, and on the CPU an array made
+        # from a NumPy buffer may share it, so the call would read the
+        # lengths and tokens the next feeds write
         _, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(self.tokens),
-            jnp.asarray(self.lengths), jnp.asarray(mask),
+            self.params, self.cache, jnp.asarray(self.tokens.copy()),
+            jnp.asarray(self.lengths.copy()), jnp.asarray(mask),
         )
         self.counts["feed_calls"] += 1
         self.lengths[slot] += 1

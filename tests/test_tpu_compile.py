@@ -158,16 +158,9 @@ def _unfused_instructions(hlo: str) -> list:
             for line in comp.splitlines()]
 
 
-def test_serve_decode_updates_the_cache_in_place(one_chip, monkeypatch):
-    """The engine's decode program at the serving cell's size (granite-3-2b,
-    16 slots x 2048) writes the 2,684,354,560 B cache in the buffer it was
-    given, and holds no second copy of it or of a layer's 67 MB K/V slab.
-
-    Readings of the compiled program: the engine that committed through a
-    whole-cache ``where`` into a fresh cache read ``alias_size_in_bytes`` 0
-    and ``temp_size_in_bytes`` 135,251,456 (each layer's slab copied
-    between layouts); the in-place one reads the cache's size and
-    1,613,824."""
+def _serving_engine(one_chip, monkeypatch):
+    """The engine at the serving cell's size (granite-3-2b, 16 slots x
+    2048), its params and cache as shapes on the described chip."""
     from repro.configs import get_config
     from repro.models import transformer
     from repro.serving.engine import ServingEngine
@@ -180,15 +173,50 @@ def test_serve_decode_updates_the_cache_in_place(one_chip, monkeypatch):
     eng = ServingEngine(cfg, params, batch_slots=B, max_len=L)
     on_chip = lambda tree: jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
-    vec = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)
-    compiled = eng._decode.lower(on_chip(params), on_chip(eng.cache), vec(jnp.int32),
-                                 vec(jnp.int32), vec(jnp.bool_)).compile()
-    ma = compiled.memory_analysis()
     cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(eng.cache))
     assert cache_bytes == 2_684_354_560
+    return eng, on_chip(params), on_chip(eng.cache), cache_bytes
+
+
+def _cache_copies(hlo: str, B: int = 16, L: int = 2048) -> list:
+    """Ops of their own that copy, transpose or select a cache, a layer's
+    slab or one slot's slab."""
+    shaped = rf"= bf16\[(40,)?(1,)?({B}|1),8,{L},64\]\S* (copy|copy-start|transpose|select)\("
+    return [l for l in _unfused_instructions(hlo) if re.search(shaped, l)]
+
+
+def test_serve_decode_updates_the_cache_in_place(one_chip, monkeypatch):
+    """The engine's decode program at the serving cell's size (granite-3-2b,
+    16 slots x 2048) writes the 2,684,354,560 B cache in the buffer it was
+    given, and holds no second copy of it or of a layer's 67 MB K/V slab.
+
+    Readings of the compiled program: the engine that committed through a
+    whole-cache ``where`` into a fresh cache read ``alias_size_in_bytes`` 0
+    and ``temp_size_in_bytes`` 135,251,456 (each layer's slab copied
+    between layouts); the in-place one reads the cache's size and
+    1,613,824."""
+    eng, params, cache, cache_bytes = _serving_engine(one_chip, monkeypatch)
+    vec = lambda dt: jax.ShapeDtypeStruct((16,), dt, sharding=one_chip)
+    compiled = eng._decode.lower(params, cache, vec(jnp.int32), vec(jnp.int32),
+                                 vec(jnp.bool_)).compile()
+    ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes == cache_bytes
     assert ma.temp_size_in_bytes < 16 * 2**20
-    # no op of its own copies, transposes or selects a cache or a slab
-    shaped = rf"= bf16\[(40,)?(1,)?{B},8,{L},64\]\S* (copy|copy-start|transpose|select)\("
-    whole = [l for l in _unfused_instructions(compiled.as_text()) if re.search(shaped, l)]
+    whole = _cache_copies(compiled.as_text())
+    assert not whole, whole[:3]
+
+
+def test_serve_prefill_updates_the_cache_in_place(one_chip, monkeypatch):
+    """The engine's chunk program at the same size writes its 128 rows of
+    every layer into the cache it was given, with no copy of the cache or
+    of a slab.  Reading: alias the cache's size, temp 355,328 B."""
+    eng, params, cache, cache_bytes = _serving_engine(one_chip, monkeypatch)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    chunk = jax.ShapeDtypeStruct((1, eng.chunk), jnp.int32, sharding=one_chip)
+    compiled = eng._prefill.lower(params, cache, chunk, scalar, scalar).compile()
+    ma = compiled.memory_analysis()
+    assert eng.chunk == 128
+    assert ma.alias_size_in_bytes == cache_bytes
+    assert ma.temp_size_in_bytes < 16 * 2**20
+    whole = _cache_copies(compiled.as_text())
     assert not whole, whole[:3]

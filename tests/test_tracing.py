@@ -39,8 +39,8 @@ def _inside(inner, outer) -> bool:
     return outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
 
 
-def _serve(tmp_path, slots=2):
-    cfg = get_config("granite-3-2b").reduced()
+def _serve(tmp_path, arch, slots=2):
+    cfg = get_config(arch).reduced()
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, params, batch_slots=slots, max_len=32)
     for p in PROMPTS:
@@ -71,27 +71,45 @@ def test_trainer_spans_one_sync_per_step(tmp_path):
         "batch", "dispatch", "sync", "log"]
 
 
-def test_engine_feed_spans_nest_in_admissions(tmp_path):
-    eng, done, spans = _serve(tmp_path)
+# the dense arch puts its prompts into the cache in chunk calls; the Mamba
+# arch feeds them one token per decode call
+ADMISSION_PATHS = pytest.mark.parametrize("arch,path", [
+    ("granite-3-2b", "prefill"), ("falcon-mamba-7b", "feed")], ids=["chunk", "per_token"])
+
+
+@ADMISSION_PATHS
+def test_engine_feed_spans_nest_in_admissions(tmp_path, arch, path):
+    eng, done, spans = _serve(tmp_path, arch)
     assert len(done) == len(PROMPTS)
     by = {k: [s for s in spans if s[0] == f"repro.serve.{k}"]
-          for k in ("admit", "feed", "decode", "sync", "bookkeep")}
-    assert len(by["feed"]) == sum(len(p) - 1 for p in PROMPTS)
+          for k in ("admit", "prefill", "feed", "decode", "sync", "bookkeep")}
+    fed = by[path]
+    assert len(fed) == (sum(len(p) > 1 for p in PROMPTS) if path == "prefill"
+                        else sum(len(p) - 1 for p in PROMPTS))
+    assert not by["feed" if path == "prefill" else "prefill"]
     assert len(by["admit"]) == len(PROMPTS)
-    assert all(any(_inside(f, a) for a in by["admit"]) for f in by["feed"])
+    assert all(any(_inside(f, a) for a in by["admit"]) for f in fed)
     assert len(by["decode"]) == len(by["sync"]) == len(by["bookkeep"]) == \
         eng.stats()["decode_calls"]
     # no engine step runs inside an admission
     assert not any(_inside(d, a) for d in by["decode"] for a in by["admit"])
 
 
-def test_engine_counts_match_the_harness_feed_count(tmp_path):
-    """``feed_calls`` is the count the benchmark derives from the prompt
-    lengths it admitted (``feed_steps``: each prompt token but the last)."""
-    eng, done, _ = _serve(tmp_path)
+@ADMISSION_PATHS
+def test_engine_counts_match_the_harness_feed_count(tmp_path, arch, path):
+    """The prompt tokens the engine put into caches, by chunk calls
+    (``prefill_tokens``) or one-token feeds (``feed_calls``), are the count
+    the benchmark derives from the prompt lengths it admitted
+    (``feed_steps``: each prompt token but the last)."""
+    eng, done, _ = _serve(tmp_path, arch)
     feed_steps = sum(len(q.prompt) - 1 for q in done)
     st = eng.stats()
-    assert st["feed_calls"] == feed_steps
+    if path == "prefill":
+        assert st["prefill_tokens"] == feed_steps and st["feed_calls"] == 0
+        assert st["prefill_calls"] == sum(len(p) > 1 for p in PROMPTS)
+    else:
+        assert st["feed_calls"] == feed_steps
+        assert st["prefill_calls"] == st["prefill_tokens"] == 0
     assert st["admissions"] == st["slot_resets"] == len(PROMPTS)
     assert st["decode_calls"] >= 3 and st["active"] == st["queued"] == 0
     for q in done:
@@ -111,8 +129,8 @@ def _instructions(text: str) -> list:
 
 
 def _compiled_programs() -> dict:
-    """Train, prefill and decode of a tiny MoE config, and prefill of a tiny
-    dense one, compiled for the CPU."""
+    """Train, prefill and decode of a tiny MoE config, and prefill and the
+    engine's chunk program of a tiny dense one, compiled for the CPU."""
     moe = get_config("granite-moe-1b-a400m").reduced()
     dense = get_config("granite-3-2b").reduced()
     plan = SchedulePlan(microbatches=1, remat="full")
@@ -138,6 +156,11 @@ def _compiled_programs() -> dict:
                         batch_slots=2, max_len=S)
     out["decode"] = eng._decode.lower(eng.params, eng.cache, jnp.zeros(2, jnp.int32),
                                       jnp.zeros(2, jnp.int32), jnp.ones(2, bool))
+    eng = ServingEngine(dense, transformer.init_params(dense, jax.random.PRNGKey(0)),
+                        batch_slots=2, max_len=S)
+    out["prefill_chunk"] = eng._prefill.lower(eng.params, eng.cache,
+                                              jnp.zeros((1, eng.chunk), jnp.int32),
+                                              jnp.int32(0), jnp.int32(1))
     return {k: v.compile().as_text() for k, v in out.items()}
 
 

@@ -82,7 +82,6 @@ def run_algo(
     n_standard: int = 15,
     n_greedy: int = 1,
     engine: str = "array",
-    cost: str = "analytic",
     pricing: Optional[str] = None,
 ):
     """One search run under the paper protocol (scaled budgets).
@@ -93,10 +92,7 @@ def run_algo(
     engine (batched leaf evaluation + shared transposition cache) by
     default — search results are certified identical to the reference
     engine by ``tests/test_differential.py``; pass ``engine="reference"``
-    for the paper-faithful Node trees.  ``cost`` selects the serving layer
-    of the cost stack (``"analytic"`` exact — the default for every
-    published figure — or ``"learned"``/``"hybrid"`` online learned-cost
-    serving; see ``repro.core.engine.serving``).  ``pricing`` selects the
+    for the paper-faithful Node trees.  ``pricing`` selects the
     analytic kernel (None exact columnar, ``"jit"`` the jax-jitted path
     with its versioned tag; see ``cost_model.py``)."""
     mdp = make_mdp(arch, shape, noise_sigma=noise_sigma, noise_seed=noise_seed,
@@ -113,14 +109,13 @@ def run_algo(
             measure_fn=measure_fn if "real" in algo else None,
             seed=seed,
             engine=engine,
-            cost=cost,
         )
         res = tuner.run(time_budget_s=time_budget_s)
         res.algo = algo
         return res, mdp
     res = autotune(arch, shape, algo=algo, seed=seed, mdp=mdp,
                    measure_fn=measure_fn, time_budget_s=time_budget_s,
-                   engine=engine, cost=cost)
+                   engine=engine)
     return res, mdp
 
 

@@ -17,28 +17,6 @@ comparison:
   miss batches priced by the COLUMNAR roofline kernel
   (``PlanColumns`` + ``_terms_columnar``, one vectorized pass per batch).
 
-A ``parallel`` leg rides along per cell: the default array engine run
-through the persistent pinned process pool (``engine/workers.py``) at
-TWO workers — the few-core parity configuration — with the shared-memory
-cache transport and in-worker lockstep batching on (the pool defaults),
-plus an ``export``-transport pool leg (``shm=False, worker_batch=False``,
-the PR-5 configuration) as the deterministic baseline.  Reported against
-the batched-sequential leg: wall clock, the payload-byte counters —
-submit/return bytes per round, the one-time init snapshot, the
-steady-state forward-delta size vs the export baseline's — and the
-CROSS-WORKER DUPLICATE EVAL counters (states priced by two or more
-workers in the same round; all of these are deterministic for fixed
-seeds).  On the warm-cache decode cell the steady-round dup count must
-be exactly zero in shm mode, and the steady shm submit payload must not
-exceed the export baseline's.
-
-The ``--parity`` mode runs ONLY this 2-worker comparison (for the CI
-few-core step, pinned to 2 CPUs via ``taskset``): deterministic gates
-are hard, and the pool>=batched-sequential wall gate engages only when
-the process actually has 2+ CPUs to run on
-(``len(os.sched_getaffinity(0))``) — on a 1-core box the pool cannot
-win and only the catastrophic floor applies.
-
 A cost-kernel microbenchmark rides along per cell (``kernel_*`` columns):
 one deduplicated batch of random unique plans priced scalar-batched vs
 columnar, isolating the kernel win from engine bookkeeping — at Table-1
@@ -51,9 +29,11 @@ overhead is out of the picture) at batch sizes 1/16/256: batch 1 shows the
 jax dispatch floor that keeps ``JIT_MIN_BATCH`` above 1, batch 256 is the
 generation-sized burst where the jitted kernel must beat the columnar one.
 
+All legs run in one process; the timings are host-CPU wall clock.
+
 Gate policy (``--check``): gates split into DETERMINISTIC ones (identical
-results across legs, byte counters, restart counts — exactly reproducible
-for fixed seeds, so any miss is a real regression and fails immediately)
+results across legs — exactly reproducible for fixed seeds, so any miss
+is a real regression and fails immediately)
 and WALL-CLOCK ratio ones (speedups, kernel crossovers — subject to CI
 cgroup throttling bursts that can halve a leg).  A wall-clock miss
 triggers ONE full re-run of the benchmark: the check fails only if a
@@ -80,7 +60,6 @@ kernel regression cannot hide).
 from __future__ import annotations
 
 import argparse
-import os
 import random
 import sys
 import time
@@ -88,7 +67,6 @@ import time
 from benchmarks.common import ENGINE_STAMP, csv_line, emit
 from repro.core.autotuner import make_mdp
 from repro.core.cost_model import AnalyticCostModel
-from repro.core.engine.shm_cache import HAVE_SHM
 from repro.core.ensemble import ProTuner
 from repro.core.mcts import MCTSConfig
 
@@ -115,44 +93,14 @@ KERNEL_BATCH = 256  # microbench batch: a Table-1 first-round miss burst
 # batch the jit-vs-columnar gate runs at
 KERNEL_JIT_BATCHES = (1, 16, 256)
 
-# parallel-leg gates.  The pool legs run at exactly PARITY_WORKERS
-# workers — the few-core configuration the shm transport and in-worker
-# lockstep batching are built to win at.  The BYTE and COUNTER gates are
-# deterministic (pickled sizes and eval counts for fixed seeds):
-# consecutive steady-state rounds within a constant factor, no round's
-# forward delta anywhere near the init snapshot, ZERO cross-worker
-# duplicate evals in steady (warm-cache) rounds under shm — round 0 pays
-# an unavoidable cold-cache overlap; every later round's frontier is
-# deduplicated through the folded shm log — and the shm submit payload
-# strictly below the export-transport baseline measured in the same run.
-# The WALL gate depends on the box: with PARITY_WORKERS+ CPUs actually
-# schedulable the pool must match or beat the batched-sequential leg
-# (soft, retry-once); on fewer CPUs the pool cannot win by construction
-# and only a catastrophic floor applies — this box's timings swing
-# ±10-20%, so the floor is generous.
-PARITY_WORKERS = 2
-PARALLEL_ROUND_RATIO = 4.0      # consecutive steady-state submit rounds
-PARALLEL_WALL_RATIO = 4.0       # parallel may not be > 4x slower ...
-PARALLEL_WALL_FLOOR_S = 5.0     # ... unless both legs are under 5s anyway
-
-
-def _n_cpus() -> int:
-    """CPUs this process can actually schedule on (taskset/cgroup aware)."""
-    try:
-        return len(os.sched_getaffinity(0))
-    except AttributeError:  # pragma: no cover - non-Linux
-        return os.cpu_count() or 1
-
 
 def run_ensemble(cell, engine: str, *, iters: int, n_standard: int,
-                 n_greedy: int, seed: int = 0, cache=None,
-                 parallel: bool = False, batch=None, columnar: bool = True,
-                 n_workers=None, shm=None, worker_batch=None):
+                 n_greedy: int, seed: int = 0, cache=None, batch=None,
+                 columnar: bool = True):
     """One full tuning run; returns (TuneResult, iterations, wall_s).
     ``columnar=False`` flips the cell's cost model to the pre-columnar
     scalar replay (values bit-identical; only the pricing path changes).
-    ``n_workers``/``shm``/``worker_batch`` configure the pinned pool for
-    parallel legs (None = the pool's own defaults).  Repetition/noise
+    Repetition/noise
     handling lives in ``bench_cell`` (rotating best-of-reps), not here."""
     arch, shape = cell
     mdp = make_mdp(arch, shape)
@@ -160,8 +108,7 @@ def run_ensemble(cell, engine: str, *, iters: int, n_standard: int,
     cfg = MCTSConfig(iters_per_decision=iters, seed=seed)
     tuner = ProTuner(mdp, n_standard=n_standard, n_greedy=n_greedy,
                      mcts_config=cfg, seed=seed, engine=engine,
-                     cache=cache, parallel=parallel, batch=batch,
-                     n_workers=n_workers, shm=shm, worker_batch=worker_batch)
+                     cache=cache, batch=batch)
     t0 = time.perf_counter()
     res = tuner.run()
     wall = time.perf_counter() - t0
@@ -258,104 +205,6 @@ def _timed(fn) -> float:
     return time.perf_counter() - t0
 
 
-def _same_result(a, b) -> bool:
-    return (a.plan == b.plan and a.cost == b.cost
-            and [d["action"] for d in a.decisions]
-            == [d["action"] for d in b.decisions])
-
-
-def _steady(rounds) -> int:
-    """The steady-state (cache-warm) per-round payload: worst of the last
-    two rounds — round 0 carries the cold-cache burst."""
-    return max(rounds[-2:]) if rounds else 0
-
-
-def bench_parallel(cell, *, iters: int, n_standard: int, n_greedy: int,
-                   reps: int = 2) -> dict:
-    """The few-core parity comparison: batched-sequential vs the pinned
-    pool at ``PARITY_WORKERS`` workers, both pool transports (leg order
-    rotates across reps; best-of-reps per leg).
-
-    Three legs ride every rep:
-
-    * ``seq``        — the default batched array engine, no pool;
-    * ``par``        — the pool with its defaults: shm cache transport +
-      in-worker lockstep batching (auto-on for pure-analytic runs);
-    * ``par_export`` — the pool forced onto the watermark/export delta
-      transport with per-tree worker loops (``shm=False,
-      worker_batch=False``) — the pre-shm configuration, measured in the
-      SAME run so the submit-payload and dup-eval gates compare like
-      against like deterministically.
-
-    Byte counters, eval counts and cross-worker duplicate counts are
-    exact functions of the seed; only the wall columns carry noise."""
-    legs0 = [
-        ("seq", dict(parallel=False)),
-        ("par", dict(parallel=True, n_workers=PARITY_WORKERS)),
-        ("par_export", dict(parallel=True, n_workers=PARITY_WORKERS,
-                            shm=False, worker_batch=False)),
-    ]
-    best = {}
-    for rep in range(max(reps, 1)):
-        k = rep % len(legs0)
-        for name, kw in legs0[k:] + legs0[:k]:
-            got = run_ensemble(cell, "array", iters=iters,
-                               n_standard=n_standard, n_greedy=n_greedy,
-                               **kw)
-            if name not in best or got[2] < best[name][2]:
-                best[name] = got
-    res_s, _, wall_s = best["seq"]
-    res_p, it_p, wall_p = best["par"]
-    res_e, _, wall_e = best["par_export"]
-    b = res_p.submit_bytes_rounds
-    be = res_e.submit_bytes_rounds
-    steady = b[-2:] if len(b) >= 2 else b  # cache-warm rounds
-    stats = res_p.stats
-    dup_rounds = stats.get("dup_evals_rounds", [])
-    out = {
-        "parallel_workers_n": PARITY_WORKERS,
-        "parallel_shm": bool(stats.get("shm")),
-        "parallel_worker_batch": bool(stats.get("worker_batch")),
-        "parallel_wall_s": wall_p,
-        "parallel_iters_per_sec": it_p / wall_p,
-        "speedup_parallel_vs_sequential": wall_s / wall_p,
-        "parallel_submit_bytes": res_p.submit_bytes,
-        "parallel_return_bytes": res_p.return_bytes,
-        "parallel_snapshot_bytes": res_p.snapshot_bytes,
-        "parallel_submit_bytes_rounds": b,
-        "parallel_return_bytes_rounds": res_p.return_bytes_rounds,
-        "parallel_submit_steady_bytes": _steady(b),
-        # cross-worker duplicate evals: states priced by 2+ workers in the
-        # same round (master-side key-overlap count, deterministic).  All
-        # of them must land in round 0 (cold cache) — a steady-round dup
-        # means the shm fold stopped deduplicating the frontier.
-        "parallel_dup_evals": stats.get("dup_evals", 0),
-        "parallel_dup_evals_steady": sum(dup_rounds[1:]),
-        "parallel_dup_evals_rounds": dup_rounds,
-        # per-worker serving split: hits/misses/dedup plus how many cache
-        # entries arrived via the shm fold vs pickled exports
-        "parallel_worker_stats": stats.get("workers", []),
-        # consecutive steady-state rounds: the constant-factor claim
-        "parallel_submit_round_ratio": (
-            max(steady) / max(min(steady), 1) if len(steady) == 2 else 1.0
-        ),
-        # worst round's forward delta vs the init snapshot — the
-        # pre-pinning pool shipped the snapshot (or more) EVERY round
-        "parallel_max_round_vs_snapshot": (
-            max(b) / max(res_p.snapshot_bytes, 1) if b else 0.0
-        ),
-        "parallel_restarts": res_p.n_worker_restarts,
-        "parallel_same_result": _same_result(res_s, res_p),
-        # the export-transport baseline, same run, same seeds
-        "parallel_export_wall_s": wall_e,
-        "parallel_export_submit_bytes": res_e.submit_bytes,
-        "parallel_export_submit_steady_bytes": _steady(be),
-        "parallel_export_restarts": res_e.n_worker_restarts,
-        "parallel_export_same_result": _same_result(res_s, res_e),
-    }
-    return out
-
-
 LEGS = [
     # leg key -> run_ensemble overrides; bench_cell round-robins the legs
     # ACROSS reps (leg order rotates within each rep) so slow temporal
@@ -369,12 +218,8 @@ LEGS = [
 
 
 def bench_cell(cell, *, iters: int, n_standard: int, n_greedy: int,
-               reps: int = 3, pool_reps=None) -> dict:
-    """One cell's full leg matrix.  ``pool_reps`` sizes the pinned-pool
-    comparison independently of the engine-leg reps — quick/CI runs pass
-    ``pool_reps=1`` so the pool path (all three parity legs and their
-    deterministic gates) is exercised on every push at a fraction of the
-    wall cost, instead of being skipped to fit the budget."""
+               reps: int = 3) -> dict:
+    """One cell's full leg matrix."""
     out = {"cell": "x".join(cell), "iters_per_decision": iters,
            "n_trees": n_standard + n_greedy,
            # the engine that produced the headline (array_*) columns — the
@@ -426,9 +271,6 @@ def bench_cell(cell, *, iters: int, n_standard: int, n_greedy: int,
         == [d["action"] for d in res_arr.decisions])
     out.update(bench_kernel(cell))
     out.update(bench_kernel_jit(cell))
-    out.update(bench_parallel(
-        cell, iters=iters, n_standard=n_standard, n_greedy=n_greedy,
-        reps=pool_reps if pool_reps is not None else max(reps - 1, 2)))
 
     name = out["cell"]
     csv_line(f"engine_throughput[{name}][reference]", wall_ref * 1e6,
@@ -439,24 +281,6 @@ def bench_cell(cell, *, iters: int, n_standard: int, n_greedy: int,
              f"{out['array_batched_iters_per_sec']:.0f} it/s")
     csv_line(f"engine_throughput[{name}][array+columnar]", wall_arr * 1e6,
              f"{out['array_iters_per_sec']:.0f} it/s")
-    csv_line(f"engine_throughput[{name}][array+parallel]",
-             out["parallel_wall_s"] * 1e6,
-             f"{out['parallel_iters_per_sec']:.0f} it/s; "
-             f"{out['speedup_parallel_vs_sequential']:.2f}x vs sequential "
-             f"at {out['parallel_workers_n']} workers; "
-             f"shm={out['parallel_shm']}; "
-             f"worker_batch={out['parallel_worker_batch']}; "
-             f"submit steady {out['parallel_submit_steady_bytes']}B/round "
-             f"(export transport: "
-             f"{out['parallel_export_submit_steady_bytes']}B), total "
-             f"{out['parallel_submit_bytes']}B vs "
-             f"{out['parallel_export_submit_bytes']}B; snapshot "
-             f"{out['parallel_snapshot_bytes']}B shipped once "
-             f"(was: every round); dup evals "
-             f"{out['parallel_dup_evals']} (steady rounds: "
-             f"{out['parallel_dup_evals_steady']}); "
-             f"restarts={out['parallel_restarts']}; "
-             f"same={out['parallel_same_result']}")
     csv_line(f"engine_throughput_kernel[{name}]",
              out["kernel_columnar_us_per_plan"],
              f"{out['kernel_speedup']:.2f}x columnar-vs-scalar on "
@@ -482,85 +306,10 @@ def bench_cell(cell, *, iters: int, n_standard: int, n_greedy: int,
     return out
 
 
-def check_parallel(row, *, cpus=None) -> tuple:
-    """The pinned-pool parity gates on one benchmarked row.  Returns
-    ``(hard, soft)``: hard gates are the deterministic counters —
-    identical results for BOTH transports, zero restarts, round-sized
-    submit payloads, zero steady-round cross-worker duplicate evals under
-    shm, and the shm submit payload at-or-below the export baseline's
-    steady rounds and strictly below its total.  The soft (wall-clock,
-    retry-once) gate depends on ``cpus`` — the CPUs this process can
-    schedule on: with ``PARITY_WORKERS`` or more, the pool must match or
-    beat the batched-sequential leg; with fewer it cannot win by
-    construction, so only the catastrophic floor applies."""
-    hard, soft = [], []
-    cell = row["cell"]
-    if not row["parallel_same_result"]:
-        hard.append(f"{cell}: parallel diverged from sequential")
-    if not row["parallel_export_same_result"]:
-        hard.append(
-            f"{cell}: export-transport pool diverged from sequential")
-    if row["parallel_restarts"] or row["parallel_export_restarts"]:
-        hard.append(
-            f"{cell}: {row['parallel_restarts']}+"
-            f"{row['parallel_export_restarts']} unexpected worker restarts")
-    if row["parallel_submit_round_ratio"] > PARALLEL_ROUND_RATIO:
-        hard.append(
-            f"{cell}: steady-state submit rounds diverged "
-            f"({row['parallel_submit_round_ratio']:.2f}x > "
-            f"{PARALLEL_ROUND_RATIO}) — submit payload no longer "
-            f"round-sized")
-    if row["parallel_max_round_vs_snapshot"] >= 1.0:
-        hard.append(
-            f"{cell}: a forward delta reached snapshot size "
-            f"({row['parallel_max_round_vs_snapshot']:.2f}x) — the "
-            f"submit side is re-shipping whole state")
-    if HAVE_SHM and not row["parallel_shm"]:
-        hard.append(
-            f"{cell}: shm cache transport did not engage on a "
-            f"pure-analytic run despite POSIX shared memory")
-    if row["parallel_shm"]:
-        if row["parallel_dup_evals_steady"]:
-            hard.append(
-                f"{cell}: {row['parallel_dup_evals_steady']} cross-worker "
-                f"duplicate evals in steady rounds "
-                f"({row['parallel_dup_evals_rounds']}) — the shm fold "
-                f"stopped deduplicating sibling frontiers")
-        if (row["parallel_submit_steady_bytes"]
-                > row["parallel_export_submit_steady_bytes"]):
-            hard.append(
-                f"{cell}: shm steady submit "
-                f"({row['parallel_submit_steady_bytes']}B/round) above the "
-                f"export baseline "
-                f"({row['parallel_export_submit_steady_bytes']}B/round)")
-        if row["parallel_submit_bytes"] >= row["parallel_export_submit_bytes"]:
-            hard.append(
-                f"{cell}: shm total submit ({row['parallel_submit_bytes']}B)"
-                f" not below the export baseline "
-                f"({row['parallel_export_submit_bytes']}B)")
-    # --- wall-clock (retry-once) ---
-    cpus = _n_cpus() if cpus is None else cpus
-    speedup = row["speedup_parallel_vs_sequential"]
-    if cpus >= PARITY_WORKERS:
-        if speedup < 1.0:
-            soft.append(
-                f"{cell}: pool slower than batched sequential at "
-                f"{row['parallel_workers_n']} workers on a {cpus}-CPU box "
-                f"({speedup:.2f}x)")
-    elif (speedup < 1.0 / PARALLEL_WALL_RATIO
-            and row["parallel_wall_s"] > PARALLEL_WALL_FLOOR_S):
-        soft.append(
-            f"{cell}: parallel leg catastrophically slow "
-            f"({speedup:.2f}x of sequential over "
-            f"{row['parallel_wall_s']:.2f}s)")
-    return hard, soft
-
-
 def check_rows(rows) -> tuple:
     """Evaluate the CI gates on benchmarked rows.  Returns
     ``(hard, soft)`` failure-message lists: ``hard`` gates are
-    DETERMINISTIC (identical plans/costs/decisions across legs, payload
-    byte counters, eval/dup counters, restart counts — exactly
+    DETERMINISTIC (identical plans/costs/decisions across legs — exactly
     reproducible for fixed seeds, never retried), ``soft`` gates are
     wall-clock ratios (retried once by the ``--check`` driver before
     failing; see the module docstring)."""
@@ -569,10 +318,6 @@ def check_rows(rows) -> tuple:
         if not row["same_result"]:
             hard.append(f"{row['cell']}: engines diverged")
     r0 = rows[0]
-    # --- pinned-pool parity gates (headline cell) ---
-    ph, ps = check_parallel(r0)
-    hard += ph
-    soft += ps
     # --- wall-clock ratio gates (retry-once) ---
     if r0["speedup"] < 1.0:
         soft.append(
@@ -597,77 +342,26 @@ def check_rows(rows) -> tuple:
 
 
 def main(iters: int = 384, n_standard: int = 15, n_greedy: int = 1,
-         publish: bool = True, reps: int = 3, pool_reps=None) -> list:
+         publish: bool = True, reps: int = 3) -> list:
     rows = [bench_cell(c, iters=iters, n_standard=n_standard,
-                       n_greedy=n_greedy, reps=reps, pool_reps=pool_reps)
+                       n_greedy=n_greedy, reps=reps)
             for c in CELLS]
     if publish:  # scaled-down (--quick / CI-gate) runs must not overwrite
         emit(rows, "engine_throughput")  # the published Table-1 artifact
     return rows
 
 
-def parity_main(iters: int = 96, n_standard: int = 7, n_greedy: int = 1,
-                reps: int = 2) -> dict:
-    """The ``--parity`` row: ONLY the 2-worker pool comparison on the
-    warm-cache decode headline cell (the CI few-core step runs this under
-    ``taskset -c 0,1``)."""
-    cell = CELLS[0]
-    row = {"cell": "x".join(cell)}
-    row.update(bench_parallel(cell, iters=iters, n_standard=n_standard,
-                              n_greedy=n_greedy, reps=reps))
-    print(f"# parity {row['cell']}: "
-          f"{row['speedup_parallel_vs_sequential']:.2f}x pool-vs-sequential "
-          f"at {row['parallel_workers_n']} workers ({_n_cpus()} CPUs); "
-          f"shm={row['parallel_shm']}; "
-          f"worker_batch={row['parallel_worker_batch']}; "
-          f"submit steady {row['parallel_submit_steady_bytes']}B/round vs "
-          f"export {row['parallel_export_submit_steady_bytes']}B, total "
-          f"{row['parallel_submit_bytes']}B vs "
-          f"{row['parallel_export_submit_bytes']}B; dup evals per round "
-          f"{row['parallel_dup_evals_rounds']}; "
-          f"same={row['parallel_same_result']}/"
-          f"{row['parallel_export_same_result']}")
-    return row
-
-
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
-                    help="scaled-down budgets (96 iters, 7+1 trees, "
-                         "single-rep pool legs)")
+                    help="scaled-down budgets (96 iters, 7+1 trees)")
     ap.add_argument("--check", action="store_true",
                     help="exit 1 unless, on the decode cell: the array "
                          "engine beats reference, the columnar kernel "
                          "holds the hot path (leg parity + microbench "
-                         "win), all legs agree, and the pinned pool holds "
-                         "its deterministic counter gates (CI gate)")
-    ap.add_argument("--parity", action="store_true",
-                    help="run ONLY the 2-worker pool parity comparison on "
-                         "the decode cell and gate it: deterministic "
-                         "counters hard, pool>=sequential soft (engages "
-                         "when 2+ CPUs are schedulable; run under "
-                         "'taskset -c 0,1' for the few-core CI gate)")
+                         "win), and all legs agree (CI gate)")
     args = ap.parse_args()
-    if args.parity:
-        row = parity_main()
-        hard, soft = check_parallel(row)
-        if not hard and soft:
-            print("# wall-clock gate miss, retrying once: " + "; ".join(soft))
-            row = parity_main()
-            hard, soft = check_parallel(row)
-        bad = hard + soft
-        if bad:
-            print("# PARITY CHECK FAILED: " + "; ".join(bad))
-            sys.exit(1)
-        print("# parity check passed: both pool transports bit-identical "
-              "to sequential, zero steady-round duplicate evals, shm "
-              "submit payload below the export baseline"
-              + (", pool >= batched sequential at "
-                 f"{PARITY_WORKERS} workers"
-                 if _n_cpus() >= PARITY_WORKERS else
-                 f" (wall gate idle: {_n_cpus()} CPU(s) schedulable)"))
-        sys.exit(0)
-    kw = (dict(iters=96, n_standard=7, publish=False, reps=2, pool_reps=1)
+    kw = (dict(iters=96, n_standard=7, publish=False, reps=2)
           if args.quick else {})
     rows = main(**kw)
     r = rows[0]
@@ -696,7 +390,4 @@ if __name__ == "__main__":
         print("# check passed: array >= reference, columnar kernel >= "
               "scalar replay, jit kernel >= columnar at batch "
               f"{max(KERNEL_JIT_BATCHES)}, columnar leg holds the batched "
-              "leg, all legs identical on the decode cell, and the pinned "
-              "pool held its parity gates (bit-identical on both "
-              "transports, zero steady-round dup evals, shm submit below "
-              "the export baseline, round-sized payloads)")
+              "leg, all legs identical on the decode cell")

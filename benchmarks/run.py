@@ -21,13 +21,13 @@ def main(argv=None) -> None:
                     help="include compile-in-the-loop cost+real runs")
     ap.add_argument("--only", default=None,
                     help="comma list: roofline,fig7,fig8,fig9,fig45,table1,"
-                         "search,fig12,noise,engine,serving")
+                         "search,fig12,noise,engine")
     args = ap.parse_args(argv)
 
     from benchmarks import (engine_throughput, fig7_cost, fig8_exec,
                             fig9_budget, fig12_partial_cost, fig45_ensemble,
-                            learned_serving, noise_robustness, roofline,
-                            search_time, table1_configs)
+                            noise_robustness, roofline, search_time,
+                            table1_configs)
     from benchmarks.common import SUITE
 
     cells = SUITE[:4] if args.quick else None
@@ -69,12 +69,6 @@ def main(argv=None) -> None:
                                    reps=2)
         else:
             engine_throughput.main()
-    if want("serving"):
-        print("# --- engine: learned-cost serving (hybrid vs analytic) ---")
-        if args.quick:
-            learned_serving.main(iters=96, n_standard=7)
-        else:
-            learned_serving.main()
     if want("fig12"):
         print("# --- Fig 1/2 (§3): cost models on partial schedules ---")
         fig12_partial_cost.main()

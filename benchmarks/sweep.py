@@ -55,7 +55,6 @@ ROW_DEFAULTS = {
     "noise_sigma": 0.0,
     "noise_seed": 0,
     "engine": "array",
-    "cost": "analytic",
     "budget_s": None,
     "n_standard": 15,
     "n_greedy": 1,
@@ -162,7 +161,7 @@ def run_sweep(
             s["arch"], s["shape"], s["algo"], seed=s["seed"],
             noise_sigma=s["noise_sigma"], noise_seed=s["noise_seed"],
             time_budget_s=s["budget_s"], n_standard=s["n_standard"],
-            n_greedy=s["n_greedy"], engine=s["engine"], cost=s["cost"],
+            n_greedy=s["n_greedy"], engine=s["engine"],
         )
         wall = time.perf_counter() - t0
         results.append((key, s, res, wall))

@@ -263,36 +263,6 @@ def main():
               f"from batched leaves alone{extra}), cache hit rate "
               f"{r['cache_hit_rate']:.3f}, identical results: "
               f"{r['same_result']}.")
-            if "parallel_submit_bytes_rounds" in r:
-                w(f"* pinned-pool transport, {r['cell']}: steady-state "
-                  f"submit delta "
-                  f"{r['parallel_submit_bytes_rounds'][-2:]} B/round "
-                  f"against a {r['parallel_snapshot_bytes']:,} B snapshot "
-                  f"shipped once (the stateless pool re-pickled it every "
-                  f"round); parallel results identical: "
-                  f"{r['parallel_same_result']}.")
-            if "parallel_shm" in r:
-                ws = r.get("parallel_worker_stats", [])
-                shm_n = sum(s.get("shm_entries", 0) for s in ws)
-                exp_n = sum(s.get("export_entries", 0) for s in ws)
-                hits = sum(s.get("hits", 0) for s in ws)
-                dedup = sum(s.get("dedup", 0) for s in ws)
-                w(f"* shm cache transport, {r['cell']}: "
-                  f"{'on' if r['parallel_shm'] else 'OFF'} at "
-                  f"{r.get('parallel_workers_n', '?')} workers, in-worker "
-                  f"lockstep batching "
-                  f"{'on' if r.get('parallel_worker_batch') else 'off'} — "
-                  f"submit {r['parallel_submit_bytes']:,} B total vs "
-                  f"{r.get('parallel_export_submit_bytes', 0):,} B over "
-                  f"the export transport (steady "
-                  f"{r.get('parallel_submit_steady_bytes', 0)} vs "
-                  f"{r.get('parallel_export_submit_steady_bytes', 0)} "
-                  f"B/round); worker cache entries served {shm_n:,} via "
-                  f"shm fold / {exp_n:,} via pickled export; worker hits "
-                  f"{hits:,} (+{dedup:,} in-batch dedup); cross-worker "
-                  f"duplicate evals {r.get('parallel_dup_evals', 0)} "
-                  f"total, {r.get('parallel_dup_evals_steady', 0)} in "
-                  f"steady rounds.")
     for name, desc in [
         ("fig7_cost", "Fig. 7 — min cost found, normalized (geomean)"),
         ("fig8_exec", "Fig. 8 — exec time of chosen schedules (geomean)"),
@@ -300,8 +270,6 @@ def main():
         ("table1_configs", "Table 1 — MCTS config sweep"),
         ("fig45_ensemble", "Fig. 4/5 — ensemble composition"),
         ("fig12_partial_cost", "Fig. 1/2 — cost model on partial schedules"),
-        ("learned_serving", "beyond-paper — learned-cost serving "
-                            "(hybrid/learned vs exact-analytic)"),
         ("noise_robustness", "beyond-paper — noise robustness"),
         ("search_time", "§5.3 — search-time breakdown"),
     ]:

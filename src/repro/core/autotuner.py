@@ -10,8 +10,12 @@ Algorithms (paper §5 protocol, plus the complete-plan portfolio):
   portfolio — race evolve/mcts/beam/random on one shared transposition
               cache and eval budget (core/evolve.py)
 
-``measure=True`` adds real measurement (subprocess XLA compile) at every
-root synchronization — the ``mcts_cost+real_*`` configurations.
+Every search runs in the calling process on the exact analytic cost
+(``core/cost_model.py``); the MCTS ensemble's default engine is the
+flat-array ``ArrayMCTS`` with lockstep batched rounds and a shared
+transposition cache.  A ``measure_fn`` or ``measure_backend`` adds real
+measurement at every root synchronization — the ``mcts_cost+real_*``
+configurations.
 """
 from __future__ import annotations
 
@@ -116,14 +120,8 @@ def autotune(
     noise_sigma: float = 0.0,
     mdp: Optional[ScheduleMDP] = None,
     engine: str = "array",
-    parallel: bool = False,
     cache: Optional[bool] = None,
     batch: Optional[bool] = None,
-    cost: str = "analytic",
-    n_workers: Optional[int] = None,
-    worker_pool=None,
-    shm: Optional[bool] = None,
-    worker_batch: Optional[bool] = None,
     plan_store=None,
     pricing: Optional[str] = None,
     controller=None,
@@ -135,27 +133,11 @@ def autotune(
     vectorized ``"array"`` engine with batched leaf evaluation and the
     shared transposition cache, certified bit-identical to the paper-
     faithful ``"reference"`` engine by ``tests/test_differential.py``;
-    ``parallel`` runs ensemble trees across persistent pinned worker
-    processes (``repro.core.engine.workers``; per-round deltas in both
-    directions, payload bytes surfaced on ``TuneResult``, ``n_workers``
-    caps the pool — default one worker per core up to the tree count);
     ``cache`` forces the shared transposition cache on/off (default: on
     for the array engine); ``batch`` forces lockstep batched leaf
-    evaluation on/off (default: on for the array engine); ``shm`` forces
-    the pool's shared-memory cache transport on/off (default: auto — on
-    for pure-analytic parallel runs where POSIX shared memory exists);
-    ``worker_batch`` forces in-worker lockstep batching of each pinned
-    subset on/off (default: follow ``batch``).  All algorithms dispatch
-    through the ``SearchBackend`` protocol
+    evaluation on/off (default: on for the array engine).  All algorithms
+    dispatch through the ``SearchBackend`` protocol
     (``repro.core.engine.backend``).
-
-    ``cost`` selects the serving layer of the cost stack for MCTS runs:
-    ``"analytic"`` (default — exact, bit-identical to the certified PR-2
-    path), ``"learned"`` (serve the online-trained §3 MLP once it exists),
-    or ``"hybrid"`` (serve it only while its holdout Spearman clears the
-    confidence gate; exact-analytic fallback otherwise).  A pre-configured
-    ``HybridCostBackend`` is also accepted.  See
-    ``repro.core.engine.serving`` and ``docs/architecture.md``.
 
     ``measure_backend`` threads a fleet-bound measurement adapter
     (``MeasurementFleet.bind(...)``, see ``repro.core.measure_fleet``)
@@ -188,8 +170,7 @@ def autotune(
         store_req = canonical_request(
             arch, shape_name, mesh=mesh, algo=algo, seed=seed,
             time_budget_s=time_budget_s, n_standard=n_standard,
-            n_greedy=n_greedy, noise_sigma=noise_sigma, cost=cost,
-            pricing=pricing,
+            n_greedy=n_greedy, noise_sigma=noise_sigma, pricing=pricing,
         )
         hit = plan_store.lookup(store_req)
         if hit is not None:
@@ -213,14 +194,8 @@ def autotune(
         measure_backend=measure_backend,
         n_standard=n_standard,
         n_greedy=n_greedy,
-        parallel=parallel,
         cache=cache,
         batch=batch,
-        cost=cost,
-        n_workers=n_workers,
-        worker_pool=worker_pool,
-        shm=shm,
-        worker_batch=worker_batch,
         seed_plans=seed_plans,
         controller=controller,
         resume=resume,

@@ -2,11 +2,10 @@
 layer of the three-layer cost stack (see docs/architecture.md):
 
 1. **analytic** (this module) — deterministic roofline arithmetic,
-   ≈100 µs/plan, the search's default signal and the online trainer's
-   ground truth;
-2. **learned** (core/learned_cost.py + core/engine/serving.py) — the §3
-   MLP, refit online on transposition-cache contents and served on
-   cache-miss batches in one jitted forward pass;
+   ≈100 µs/plan, the search's signal and the learned model's ground
+   truth;
+2. **learned** (core/learned_cost.py) — the §3 MLP, trained offline on
+   complete schedules (the Fig. 1/2 reproduction; not a search signal);
 3. **real measurement** (core/measure.py) — subprocess XLA compiles,
    re-ranking candidates at root synchronizations (``mcts_cost+real_*``).
 
@@ -134,9 +133,10 @@ _JAX_MODS = None
 
 
 def _jax_mods():
-    """Lazy jax import: the forkserver preload chain (repro.core.ensemble →
-    this module) must stay jax-free (asserted by tests/test_engine.py), so
-    jax loads only when a pricing="jit" model actually prices a batch."""
+    """Lazy jax import: the measurement fleet's forkserver preload chain
+    (repro.core.measure_fleet → measure → this module) must stay jax-free
+    (asserted by tests/test_engine.py), so jax loads only when a
+    pricing="jit" model actually prices a batch."""
     global _JAX_MODS
     if _JAX_MODS is None:
         import jax
@@ -174,8 +174,7 @@ class PlanColumns:
     booleans, knobs as integers/floats).  This is the ONE encode a pricing
     batch pays: the analytic kernel (``_terms_columnar``) and the learned
     MLP featurizer (``learned_cost.featurize_columns``) both read these
-    columns, so a miss batch handed to ``HybridCostBackend`` is encoded
-    once whichever backend ends up pricing it.
+    columns.
 
     ``plans`` keeps the original objects (ordered) so non-columnar
     consumers — the scalar oracle path, test doubles — can fall back
@@ -496,7 +495,7 @@ class AnalyticCostModel:
 
     def __getstate__(self):
         # the batch context holds derived caches only — drop it so pickled
-        # models (process-pool workers) stay lean; it lazily rebuilds.
+        # models (round-boundary checkpoints) stay lean; it lazily rebuilds.
         # the jitted kernel closure is unpicklable and rebuilds the same way
         d = self.__dict__.copy()
         d["_batch_ctx"] = None

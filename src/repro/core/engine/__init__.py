@@ -11,10 +11,11 @@ Two interchangeable MCTS engines behind one interface:
   the full (UCB variant × simulation policy × reward mode × seed) grid by
   the differential harness in ``tests/test_differential.py``.
 
-Batched leaf evaluation (``engine/batch.py``): ``run_decision_batch`` runs
-an ensemble round's K trees in lockstep, queueing each step's K pending
-leaves (and the greedy rollouts' per-depth candidate sweeps) into single
-batched pricing calls.  The pricing seam it rides on:
+Both run in the calling process.  Batched leaf evaluation
+(``engine/batch.py``): ``run_decision_batch`` runs an ensemble round's K
+trees in lockstep, queueing each step's K pending leaves (and the greedy
+rollouts' per-depth candidate sweeps) into single batched pricing calls.
+The pricing seam it rides on:
 
 * ``AnalyticCostModel.cost_batch(plans)`` — contract:
   ``cost_batch(plans) == [cost(p) for p in plans]`` element-for-element and
@@ -34,36 +35,13 @@ batched pricing calls.  The pricing seam it rides on:
 Plus the shared ``TranspositionCache`` / ``CachedMDP`` that memoizes
 ``terminal_cost`` / ``partial_cost`` across all ensemble trees and all
 decision rounds, and the ``SearchBackend`` protocol (see ``backend.py``)
-that ``autotune`` routes every algorithm through.
-
-Parallel execution (``workers.py``): ``parallel=True`` runs ensemble
-rounds on PERSISTENT PINNED worker processes — each worker holds its
-trees and a serve-only ``CachedMDP`` for the whole run, and per-round
-traffic is a delta in both directions (root-advance + incremental cache
-export + generation-keyed model params forward; the ``ArrayMCTS`` round
-delta back), with payload bytes counted at the pickle boundary and
-worker-death resync from the master's canonical trees.
-
-Learned-cost serving (``serving.py``): ``cost="analytic"|"learned"|"hybrid"``
-on ``autotune`` / ``ProTuner`` / ``resolve_backend`` mounts a
-``HybridCostBackend`` inside ``CachedMDP`` — an ``OnlineCostTrainer``
-refits the §3 MLP on the cache's analytic terminal entries, and trained
-(confident) models price each deduplicated miss batch in ONE jitted
-forward pass, with exact-analytic fallback.  ``cost="analytic"`` (the
-default) mounts nothing, so the differential-certified PR-2 path is
-untouched.  See ``docs/architecture.md`` for the full seam contracts.
+that ``autotune`` routes every algorithm through.  See
+``docs/architecture.md`` for the seam contracts.
 """
 from __future__ import annotations
 
 from repro.core.engine.array_mcts import ArrayMCTS
 from repro.core.engine.cache import CachedMDP, TranspositionCache
-from repro.core.engine.serving import (
-    COST_MODES,
-    HybridCostBackend,
-    OnlineCostTrainer,
-    make_cost_backend,
-)
-from repro.core.engine.workers import PinnedWorkerPool
 
 ENGINES = ("reference", "array")
 
@@ -82,12 +60,7 @@ def make_tree(mdp, config, engine: str = "reference"):
 __all__ = [
     "ArrayMCTS",
     "CachedMDP",
-    "PinnedWorkerPool",
     "TranspositionCache",
-    "COST_MODES",
-    "HybridCostBackend",
-    "OnlineCostTrainer",
-    "make_cost_backend",
     "ENGINES",
     "make_tree",
 ]

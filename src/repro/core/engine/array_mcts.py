@@ -89,12 +89,6 @@ class ArrayMCTS:
             self._depth_actions = [
                 space.n_actions(d) for d in range(space.n_stages)
             ]
-        # per-round delta recording (process-pool workers; see
-        # begin_delta/collect_delta/apply_delta)
-        self._delta_base: Optional[int] = None
-        self._delta_parents: List[int] = []
-        self._delta_best: List[int] = []
-        self._delta_touched: List[int] = []
         self.root = self._new_node(-1, self.root_state)
 
     # -- storage management ------------------------------------------------
@@ -224,8 +218,6 @@ class ArrayMCTS:
         self.children[nid, slot] = child
         self.n_children[nid] = slot + 1
         self._childlist[nid].append(child)
-        if self._delta_base is not None:
-            self._delta_parents.append(nid)
         return child, child_state, child
 
     # -- default policy ------------------------------------------------------
@@ -281,12 +273,6 @@ class ArrayMCTS:
             r = 1.0 if beat_best else 0.0
         else:
             r = (self.baseline / cost) if cost > 0 else 0.0
-        rec = self._delta_best if self._delta_base is not None else None
-        if rec is not None:
-            # pre-round nodes whose visit/sum stats this backprop touches:
-            # exactly what collect_delta must ship besides the new slices
-            base = self._delta_base
-            self._delta_touched.extend(n for n in path if n < base)
         if len(path) < 16:
             vc, sc, sr, bc = (
                 self.visit_counts, self.sum_cost, self.sum_reward, self.best_cost,
@@ -298,8 +284,6 @@ class ArrayMCTS:
                 if cost < bc[nid]:
                     bc[nid] = cost
                     self.best_state[nid] = terminal
-                    if rec is not None:
-                        rec.append(nid)
         else:
             ids = np.asarray(path, dtype=np.int64)
             self.visit_counts[ids] += 1
@@ -309,8 +293,6 @@ class ArrayMCTS:
             self.best_cost[improved] = cost
             for nid in improved:
                 self.best_state[int(nid)] = terminal
-                if rec is not None:
-                    rec.append(int(nid))
 
     def iterate_once(self):
         nid, state, path = self._select()
@@ -355,129 +337,6 @@ class ArrayMCTS:
             iterations=iters,
         )
 
-    # -- per-round tree deltas (process-pool transport) ----------------------
-    # A worker runs one decision round and ships back ONLY what the round
-    # changed, instead of pickling the whole tree: the round's NEW node
-    # slices (``[base:size]`` stat/structure buffers), the stat rows of the
-    # round's TOUCHED pre-round nodes (the backprop paths — recorded during
-    # the round, so the numeric payload scales with the round, not with the
-    # total tree), and the point mutations to pre-round nodes (untried
-    # pools / child table rows of expanded parents, improved best-states).
-    # The master applies the delta to the tree object it kept, which
-    # reproduces the worker's post-round tree exactly — asserted by
-    # tests/test_engine.py::test_parallel_delta_merge_equals_whole_tree.
-    # This is the REVERSE direction of the pinned-worker protocol
-    # (engine/workers.py); the forward direction needs no tree payload at
-    # all — the master's root-synchronization action is replayed through
-    # ``advance_root``, which both sides apply to identical trees.
-
-    def begin_delta(self):
-        """Start recording a round's mutations (worker side)."""
-        self._delta_base = self.size
-        self._delta_parents = []
-        self._delta_best = []
-        self._delta_touched = []
-
-    def collect_delta(self) -> dict:
-        """Package the recorded round as a picklable delta and stop
-        recording.  Payload is a TRUE delta: ``[base:size]`` slices for
-        the round's new nodes plus the touched pre-round stat rows —
-        nothing proportional to the pre-round tree ships."""
-        base = self._delta_base
-        size = self.size
-        parents = sorted({n for n in self._delta_parents if n < base})
-        improved = {n for n in self._delta_best if n < base}
-        # every pre-round node whose numeric stats changed this round:
-        # backprop paths (visit/sum/best writes); expanded parents' stat
-        # changes are also backprop writes, so ``touched`` covers them
-        touched = np.fromiter(
-            sorted(set(self._delta_touched)), dtype=np.int64,
-        )
-        delta = {
-            "base": base,
-            "size": size,
-            "width": self.children.shape[1],
-            "visit_counts": self.visit_counts[base:size].copy(),
-            "sum_cost": self.sum_cost[base:size].copy(),
-            "sum_reward": self.sum_reward[base:size].copy(),
-            "best_cost": self.best_cost[base:size].copy(),
-            "node_action": self.node_action[base:size].copy(),
-            "n_children": self.n_children[base:size].copy(),
-            "children": self.children[base:size].copy(),
-            "touched": touched,
-            "touched_visit": self.visit_counts[touched],
-            "touched_sum_cost": self.sum_cost[touched],
-            "touched_sum_reward": self.sum_reward[touched],
-            "touched_best_cost": self.best_cost[touched],
-            # expanded pre-round parents: their children-table rows gained
-            # slots this round (n_children rides along per parent)
-            "children_mut": {n: self.children[n].copy() for n in parents},
-            "n_children_mut": {n: int(self.n_children[n]) for n in parents},
-            "untried_new": self.untried[base:],
-            "childlist_new": self._childlist[base:],
-            "best_state_new": self.best_state[base:],
-            "untried_mut": {n: self.untried[n] for n in parents},
-            "childlist_mut": {n: self._childlist[n] for n in parents},
-            "best_state_mut": {n: self.best_state[n] for n in improved},
-            "rng": self.rng.getstate(),
-            "baseline": self.baseline,
-            "global_best": self.global_best,
-            "global_best_state": self.global_best_state,
-            "sim_time": self.sim_time,
-            "eval_time": self.eval_time,
-        }
-        self._delta_base = None
-        self._delta_parents = []
-        self._delta_best = []
-        self._delta_touched = []
-        return delta
-
-    def apply_delta(self, delta: dict):
-        """Apply a worker's round delta to this (pre-round) tree, making it
-        equal to the worker's post-round tree."""
-        base, size = delta["base"], delta["size"]
-        if base != len(self.untried):
-            raise ValueError(
-                f"delta base {base} does not match tree size {len(self.untried)}"
-            )
-        while self.visit_counts.shape[0] < size:
-            self._grow_nodes()
-        width = delta["width"]
-        if self.children.shape[1] < width:
-            self._grow_width(width)
-        self.size = size
-        self.visit_counts[base:size] = delta["visit_counts"]
-        self.sum_cost[base:size] = delta["sum_cost"]
-        self.sum_reward[base:size] = delta["sum_reward"]
-        self.best_cost[base:size] = delta["best_cost"]
-        self.node_action[base:size] = delta["node_action"]
-        self.n_children[base:size] = delta["n_children"]
-        self.children[base:size, :width] = delta["children"]
-        t = delta["touched"]
-        self.visit_counts[t] = delta["touched_visit"]
-        self.sum_cost[t] = delta["touched_sum_cost"]
-        self.sum_reward[t] = delta["touched_sum_reward"]
-        self.best_cost[t] = delta["touched_best_cost"]
-        for n, row in delta["children_mut"].items():
-            self.children[n, : row.shape[0]] = row
-        for n, v in delta["n_children_mut"].items():
-            self.n_children[n] = v
-        self.untried.extend(delta["untried_new"])
-        self._childlist.extend(delta["childlist_new"])
-        self.best_state.extend(delta["best_state_new"])
-        for n, pool in delta["untried_mut"].items():
-            self.untried[n] = pool
-        for n, kids in delta["childlist_mut"].items():
-            self._childlist[n] = kids
-        for n, st in delta["best_state_mut"].items():
-            self.best_state[n] = st
-        self.rng.setstate(delta["rng"])
-        self.baseline = delta["baseline"]
-        self.global_best = delta["global_best"]
-        self.global_best_state = delta["global_best_state"]
-        self.sim_time = delta["sim_time"]
-        self.eval_time = delta["eval_time"]
-
     def advance_root(self, action: int):
         self.root_state = self.mdp.step(self.root_state, action)
         nxt = -1
@@ -493,17 +352,3 @@ class ArrayMCTS:
     def done(self) -> bool:
         return self.mdp.is_terminal(self.root_state)
 
-
-def delta_nbytes(delta: dict) -> int:
-    """Numeric payload of a collected round delta, in bytes — the array
-    buffers that dominate the wire size (new-node slices, touched stat
-    rows, expanded parents' child-table rows).  Payload accounting for the
-    O(new nodes + touched rows) transport claim: this number scales with
-    the ROUND, while ``pickle.dumps(tree)`` scales with the whole tree."""
-    n = 0
-    for v in delta.values():
-        if isinstance(v, np.ndarray):
-            n += v.nbytes
-    for row in delta["children_mut"].values():
-        n += row.nbytes
-    return n

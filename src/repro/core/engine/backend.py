@@ -8,24 +8,17 @@ A backend is anything with a ``name`` and
     run(mdp, *, seed=0, time_budget_s=None, measure_fn=None, **opts)
         -> TuneResult
 
-``resolve_backend(algo, engine=..., cost=...)`` maps the paper's Table-1
-algorithm names to configured backend instances; ``engine`` selects the
-MCTS tree representation — ``"array"`` flat numpy with batched leaf
-evaluation (the default, differential-tested against the reference) or
-``"reference"`` Node objects — and ``cost`` selects the serving layer of
-the cost stack (``"analytic"`` exact, ``"learned"``/``"hybrid"`` online
-learned-cost serving behind the transposition cache; see
-``repro.core.engine.serving``).  Whichever backend runs, batch pricing
-below the seam is the columnar roofline kernel
-(``cost_model.PlanColumns`` + ``_terms_columnar``; docs/architecture.md
-§4) — bit-identical to the retained scalar oracle, so backend selection
-never changes search values.
+``resolve_backend(algo, engine=...)`` maps the paper's Table-1 algorithm
+names to configured backend instances; ``engine`` selects the MCTS tree
+representation — ``"array"`` flat numpy with batched leaf evaluation (the
+default, differential-tested against the reference) or ``"reference"``
+Node objects.  Whichever backend runs, batch pricing below the seam is the
+columnar roofline kernel (``cost_model.PlanColumns`` +
+``_terms_columnar``; docs/architecture.md §4) — bit-identical to the
+retained scalar oracle, so backend selection never changes search values.
 
-Execution options flow through ``**opts`` untouched: ``parallel=True``
-runs MCTS ensembles on the persistent pinned worker pool
-(``repro.core.engine.workers`` — per-round deltas in both directions,
-payload bytes surfaced on ``TuneResult``), ``n_workers`` caps that pool,
-and non-MCTS backends simply ignore both.
+Execution options (``cache``, ``batch``, ``controller``, ...) flow
+through ``**opts`` untouched; backends ignore the ones they do not use.
 """
 from __future__ import annotations
 
@@ -67,14 +60,8 @@ TABLE1 = {
 }
 
 
-def resolve_backend(
-    algo: str, engine: str = "array", cost: str = "analytic"
-) -> SearchBackend:
-    """Map an algorithm name (paper §5 protocol) to a configured backend.
-
-    ``cost`` configures MCTS backends' learned-cost serving mode; the
-    non-model-based baselines (beam/greedy/random) ignore it — they price
-    straight through the analytic model, as in the paper."""
+def resolve_backend(algo: str, engine: str = "array") -> SearchBackend:
+    """Map an algorithm name (paper §5 protocol) to a configured backend."""
     # imported here: beam/random/evolve/ensemble all define backends and
     # import TuneResult from ensemble, which imports this package
     from repro.core.beam import BeamBackend, GreedyBackend
@@ -91,15 +78,14 @@ def resolve_backend(
     if algo == "evolve":
         return EvolutionarySearchBackend()
     if algo == "portfolio":
-        # member mcts/beam runs inherit the engine/cost selection through
-        # the portfolio's run() opts
+        # member mcts/beam runs inherit the engine selection through the
+        # portfolio's run() opts
         return PortfolioBackend()
     if algo in TABLE1 or algo == "mcts":
         return MCTSEnsembleBackend(
             algo=algo,
             config=TABLE1.get(algo, TABLE1["mcts_30s"]),
             engine=engine,
-            cost=cost,
             name="mcts",
         )
     raise ValueError(f"unknown algo {algo!r}")

@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import math
 import time
-from typing import List, Optional, Tuple
+from typing import List, Tuple
 
 from repro.core.engine.array_mcts import INF, ArrayMCTS
 from repro.core.mcts import DecisionResult
@@ -66,7 +66,6 @@ class _TreeCursor:
         "t", "mdp", "rng", "untried", "childlist", "best_state",
         "vc", "sc", "sr", "bc", "act",
         "da", "n_stages", "paper", "cp", "greedy", "binary",
-        "delta_base", "dparents", "dbest", "dtouched",
     )
 
     def __init__(self, t: ArrayMCTS):
@@ -76,14 +75,6 @@ class _TreeCursor:
         self.untried = t.untried
         self.childlist = t._childlist
         self.best_state = t.best_state
-        # per-round delta recording (pinned-worker reverse transport): the
-        # cursor's inline expand/backprop mirror ArrayMCTS's hooks, feeding
-        # the same record lists ``collect_delta`` packages; recording into
-        # them unfiltered is fine — collect_delta filters by ``base``
-        self.delta_base = t._delta_base
-        self.dparents = t._delta_parents
-        self.dbest = t._delta_best
-        self.dtouched = t._delta_touched
         size = t.size
         self.vc: List[int] = t.visit_counts[:size].tolist()
         self.sc: List[float] = t.sum_cost[:size].tolist()
@@ -157,8 +148,6 @@ class _TreeCursor:
             t.children[nid, slot] = child
             t.n_children[nid] = slot + 1
             childlist[nid].append(child)
-            if self.delta_base is not None:
-                self.dparents.append(nid)
             path.append(child)
             self.vc.append(0)
             self.sc.append(0.0)
@@ -244,9 +233,6 @@ class _TreeCursor:
             r = (t.baseline / cost) if cost > 0 else 0.0
         vc, sc, sr, bc = self.vc, self.sc, self.sr, self.bc
         best_state = self.best_state
-        base = self.delta_base
-        if base is not None:
-            self.dtouched.extend(n for n in path if n < base)
         for nid in path:
             vc[nid] += 1
             sc[nid] += cost
@@ -254,8 +240,6 @@ class _TreeCursor:
             if cost < bc[nid]:
                 bc[nid] = cost
                 best_state[nid] = terminal
-                if base is not None:
-                    self.dbest.append(nid)
 
     def flush(self):
         """Write the stat mirrors back into the canonical flat arrays (one
@@ -317,10 +301,4 @@ def run_decision_batch(
             extra = 1
         c.flush()
         out.append(c.t._root_decision(iters + extra))
-    # learned-cost serving (engine/serving.py): a decision-round boundary
-    # is the online trainer's deterministic refit point — the next round's
-    # miss batches are then priced by the refreshed model
-    round_end = getattr(mdp, "on_round_end", None)
-    if round_end is not None:
-        round_end()
     return out

@@ -335,7 +335,6 @@ class PortfolioBackend:
         max_evals: Optional[int] = None,
         seed_plans: Optional[Sequence[SchedulePlan]] = None,
         engine: str = "array",
-        cost: str = "analytic",
         n_standard: int = 4,
         n_greedy: int = 1,
         **_,
@@ -371,7 +370,7 @@ class PortfolioBackend:
                 n = 256 if remaining is None else min(256, remaining)
                 backend = RandomBackend(n_samples=n)
             else:
-                backend = resolve_backend(algo, engine=engine, cost=cost)
+                backend = resolve_backend(algo, engine=engine)
                 opts.update(n_standard=n_standard, n_greedy=n_greedy)
             res = backend.run(
                 mdp, seed=seed, time_budget_s=member_budget_s, **opts

@@ -1,16 +1,10 @@
 """Learned cost model (paper §3): a small MLP trained on COMPLETE
 schedules, in pure JAX.
 
-Two roles in this repo:
-
-* **Reproduction** (Fig. 1/2): a model trained on complete schedules ranks
-  complete schedules well but mis-ranks partial ones (their
-  default-completion features are off-distribution), which is what poisons
-  beam search at every depth — see ``benchmarks/fig12_partial_cost.py``.
-* **Serving** (engine layer): the same MLP is refit online on
-  transposition-cache contents and prices cache-miss batches in one
-  batched forward pass — see ``repro.core.engine.serving`` and
-  ``docs/architecture.md`` for the serving seam.
+Its role here is the Fig. 1/2 reproduction: a model trained on complete
+schedules ranks complete schedules well but mis-ranks partial ones (their
+default-completion features are off-distribution), which is what poisons
+beam search at every depth — see ``benchmarks/fig12_partial_cost.py``.
 
 The forward pass is jitted ONCE at module level (``_mlp_apply_jit``) and
 reused by both the scalar and batched entry points; batches are padded to
@@ -62,11 +56,8 @@ def featurize_batch(
 def featurize_columns(cols: PlanColumns, space: ScheduleSpace) -> np.ndarray:
     """``featurize_batch`` from a ``PlanColumns`` encoding — element-for-
     element equal to featurizing the plan objects (tested), built entirely
-    from the same structure-of-arrays the analytic columnar kernel prices.
-    This is what lets the serving layer encode a miss batch ONCE and hand
-    the encoding to whichever cost backend wins: the MLP featurizes the
-    columns, the analytic kernel prices them, no per-plan re-walk either
-    way."""
+    from the same structure-of-arrays the analytic columnar kernel prices,
+    so one encode feeds both without a per-plan re-walk."""
     blocks: List[np.ndarray] = []
     for stage in space.stages:
         for onehot in cols.stage_onehots(stage):
@@ -91,7 +82,6 @@ class LearnedCostModel:
     mean: float
     std: float
     n_evals: int = 0
-    version: int = 1  # fit generation (bumped by the online trainer)
     n_forward: int = 0  # jitted MLP invocations; a whole batch counts ONCE
 
     def cost(self, plan: SchedulePlan) -> float:
@@ -107,15 +97,6 @@ class LearnedCostModel:
         if len(plans) == 0:
             return []
         return self._predict(featurize_batch(plans, self.space))
-
-    def cost_columns(self, cols: PlanColumns) -> List[float]:
-        """``cost_batch`` from a shared ``PlanColumns`` encoding (the
-        serving seam: one encode per miss batch, whichever backend
-        prices it).  Same values as ``cost_batch(cols.plans)`` — the
-        feature matrix is element-identical (``featurize_columns``)."""
-        if cols.n == 0:
-            return []
-        return self._predict(featurize_columns(cols, self.space))
 
     def _predict(self, X: np.ndarray) -> List[float]:
         """One jitted forward pass over a feature matrix, padded to the
@@ -190,8 +171,8 @@ def fit_learned_cost(
 ) -> LearnedCostModel:
     """Fit (or warm-start refit, via ``params``) the MLP on explicit
     ``(plan, cost)`` pairs.  Normalization (log-cost mean/std) is recomputed
-    from THIS dataset — the per-fit renormalization the online trainer
-    requires as the cache's cost distribution shifts during search."""
+    from THIS dataset, so a warm-started refit follows a shifted cost
+    distribution."""
     X = featurize_batch(plans, space)
     logy = np.log(np.maximum(np.asarray(costs, np.float32), 1e-9))
     mean, std = float(logy.mean()), float(logy.std() + 1e-6)
@@ -237,7 +218,7 @@ def ranking_correlation(
 
     Both legs price through the batch seam (``cost_batch`` — one MLP
     forward pass / one columnar kernel pass for all ``n`` samples), the
-    same path the fig-12 artifact and the serving layer exercise; models
+    same path the fig-12 artifact exercises; models
     without a batch entry point fall back to a scalar sweep."""
     rng = _random.Random(seed)
     pred_plans, gold_plans = [], []

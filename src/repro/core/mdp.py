@@ -46,9 +46,8 @@ class ScheduleMDP:
 
     def completed_plans(self, states: Sequence[State]) -> list:
         """Default-complete each prefix into a full ``SchedulePlan`` — the
-        features every partial-schedule consumer scores (the analytic
-        batch path here and the learned-cost server in
-        ``engine/serving.py``); defaults resolved once per batch."""
+        features the analytic batch path scores); defaults resolved once
+        per batch."""
         defaults = self.space.default_actions()
         return [
             self.space.plan_from_actions(list(s) + defaults[len(s):])

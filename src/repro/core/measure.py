@@ -154,7 +154,7 @@ def combine_terms(
 # ---------------------------------------------------------------------------
 # Subprocess measurement client (with on-disk cache)
 # ---------------------------------------------------------------------------
-# Cache-key contract (docs/architecture.md §8): the key is a content hash
+# Cache-key contract (docs/architecture.md §6): the key is a content hash
 # of EVERY input that can change the record — key version, arch, shape,
 # mesh, DEVICE COUNT, and the full plan dict.  ``devices`` was missing
 # before v2: measuring the same (arch, shape, mesh) at a different forced

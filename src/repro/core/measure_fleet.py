@@ -10,7 +10,7 @@ fleet fans the cache misses out over persistent workers, and the search
 only ever blocks at root synchronizations — exactly where the paper's
 ``mcts_cost+real_*`` configurations re-rank candidates.
 
-Request lifecycle (docs/architecture.md §8):
+Request lifecycle (docs/architecture.md §6):
 
 1. **cache** — each request is keyed by ``measure.request_key`` (content
    hash of version, arch, shape, mesh, devices, plan); a valid on-disk
@@ -18,8 +18,8 @@ Request lifecycle (docs/architecture.md §8):
 2. **single-flight** — concurrent misses for the same key are grouped
    into one in-flight job; the plan compiles once and every requester
    shares the record.
-3. **dispatch** — jobs go to idle workers over the same pipe protocol as
-   ``PinnedWorkerPool`` (spawn via ``pick_mp_context``'s forkserver).
+3. **dispatch** — jobs go to idle workers over a pickled pipe protocol
+   (spawn via ``pick_mp_context``'s forkserver).
 4. **watchdog** — every in-flight job has a master-side deadline
    (request timeout + ``grace_s``); a worker that blows it is SIGKILLed
    and respawned, and the job re-queues.
@@ -40,6 +40,7 @@ threads through ``measure_backend=``.
 from __future__ import annotations
 
 import heapq
+import multiprocessing
 import os
 import pickle
 import time
@@ -48,7 +49,6 @@ from dataclasses import dataclass, field
 from multiprocessing.connection import wait as _conn_wait
 from typing import Dict, List, Optional
 
-from repro.core.engine.workers import _PROTO, pick_mp_context
 from repro.core.measure import (
     CACHE_DIR,
     load_record,
@@ -57,6 +57,27 @@ from repro.core.measure import (
     request_key,
     write_record,
 )
+
+_PROTO = pickle.HIGHEST_PROTOCOL
+
+
+def pick_mp_context():
+    """forkserver where available (workers start from a clean process —
+    forking a jax-threaded parent can deadlock), fork otherwise.
+
+    The forkserver preloads this module's import chain (numpy, the
+    measurement and cost-model modules — everything a worker needs to
+    unpickle a request, none of it jax): children then FORK with the
+    imports already done, so after the first fleet of a process, worker
+    spawn cost drops from an import chain to a fork."""
+    methods = multiprocessing.get_all_start_methods()
+    method = next((m for m in ("forkserver", "fork") if m in methods), None)
+    ctx = multiprocessing.get_context(method)
+    if method == "forkserver":
+        # a no-op once the server is running; effective when called (as
+        # here) before the first worker process ever starts
+        ctx.set_forkserver_preload(["repro.core.measure_fleet"])
+    return ctx
 
 
 # ---------------------------------------------------------------------------
@@ -196,9 +217,8 @@ class MeasurementFleet:
         return _FleetWorker(proc, parent)
 
     def _respawn(self, w: _FleetWorker) -> None:
-        """SIGKILL-survivable replacement (same recovery shape as
-        ``PinnedWorkerPool._resync``): the dead worker's job re-queues
-        through the normal retry budget."""
+        """SIGKILL-survivable replacement: the dead worker's job
+        re-queues through the normal retry budget."""
         self.n_worker_restarts += 1
         try:
             w.conn.close()

@@ -28,11 +28,6 @@ def main(argv=None) -> int:
                     choices=["reference", "array"],
                     help="MCTS tree engine (array = vectorized + shared "
                          "transposition cache; identical results)")
-    ap.add_argument("--cost", default="analytic",
-                    choices=["analytic", "learned", "hybrid"],
-                    help="cost serving mode: analytic (exact), learned "
-                         "(online-trained MLP prices cache misses), hybrid "
-                         "(learned only while confident; analytic fallback)")
     ap.add_argument("--pricing", default=None,
                     choices=["scalar", "columnar", "jit"],
                     help="analytic pricing kernel: columnar (exact, "
@@ -43,13 +38,6 @@ def main(argv=None) -> int:
                     help="PlanStore root directory: answer repeats from "
                          "disk, record this run, and (evolve/portfolio) "
                          "seed the population from stored plans")
-    ap.add_argument("--parallel", action="store_true",
-                    help="run ensemble trees on persistent pinned worker "
-                         "processes (per-round deltas both directions; "
-                         "identical results)")
-    ap.add_argument("--workers", type=int, default=None,
-                    help="cap the pinned worker pool (default: one per "
-                         "core, up to the tree count)")
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args(argv)
 
@@ -80,9 +68,6 @@ def main(argv=None) -> int:
             measure_backend=measure_backend,
             time_budget_s=args.budget_s,
             engine=args.engine,
-            parallel=args.parallel,
-            cost=args.cost,
-            n_workers=args.workers,
             pricing=args.pricing,
             plan_store=plan_store,
         )
@@ -92,16 +77,6 @@ def main(argv=None) -> int:
     mdp = make_mdp(args.arch, args.shape, args.mesh)
     terms = mdp.cost_model.terms(res.plan)
     print(f"[autotune] {args.arch}×{args.shape} algo={res.algo}")
-    if res.cost_mode != "analytic":
-        print(f"[autotune] cost serving: {res.cost_mode} "
-              f"(model v{res.model_version}, {res.n_fits} fits, "
-              f"{res.learned_evals} learned-priced plans)")
-    if res.submit_bytes:
-        print(f"[autotune] pinned pool: {res.submit_bytes:,}B submitted / "
-              f"{res.return_bytes:,}B returned over "
-              f"{len(res.submit_bytes_rounds)} rounds, "
-              f"{res.snapshot_bytes:,}B snapshot, "
-              f"{res.n_worker_restarts} worker restarts")
     if fleet is not None:
         print(f"[autotune] measurement fleet: {fleet.stats()}")
     if res.n_measure_failures:

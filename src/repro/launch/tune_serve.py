@@ -1,10 +1,10 @@
 """Tuner-as-a-service CLI: daemon and client ends of one socket.
 
-Serve (long-lived; one worker pool + one fleet + one plan store for every
-request it ever answers):
+Serve (long-lived; one fleet + one plan store for every request it ever
+answers):
 
     python -m repro.launch.tune_serve serve --store /var/tune-store \
-        --socket /tmp/tuner.sock --parallel
+        --socket /tmp/tuner.sock
 
 Client (per request; returns the tuned plan as JSON on stdout):
 
@@ -61,9 +61,6 @@ def main(argv=None) -> int:
     sv = sub.add_parser("serve", help="run the daemon")
     sv.add_argument("--store", required=True, help="plan-store root dir")
     sv.add_argument("--socket", required=True, help="unix socket path")
-    sv.add_argument("--parallel", action="store_true",
-                    help="share one pinned worker pool across runs")
-    sv.add_argument("--workers", type=int, default=None)
     sv.add_argument("--measure", default="none",
                     choices=["none", "stub", "real"],
                     help="shared measurement fleet for *real* algos "
@@ -82,9 +79,6 @@ def main(argv=None) -> int:
     sv.add_argument("--deadline-s", type=float, default=None,
                     help="default per-request search deadline; requests "
                          "override with their own deadline_s")
-    sv.add_argument("--degrade-after", type=int, default=5,
-                    help="cumulative pool worker restarts before the "
-                         "watchdog degrades to the sequential engine")
     sv.add_argument("--round-delay", type=float, default=0.0,
                     help=argparse.SUPPRESS)  # fault-injection: slow rounds
     sv.add_argument("--no-recover", action="store_true",
@@ -101,8 +95,6 @@ def main(argv=None) -> int:
         p.add_argument("--n-standard", type=int, default=15)
         p.add_argument("--n-greedy", type=int, default=1)
         p.add_argument("--noise-sigma", type=float, default=0.0)
-        p.add_argument("--cost", default="analytic",
-                       choices=["analytic", "learned", "hybrid"])
         p.add_argument("--deadline-s", type=float, default=None,
                        help="interrupt the search at the next round "
                             "boundary after this many seconds; the "
@@ -121,19 +113,17 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.cmd == "serve":
-        # the daemon's JAX users (learned-cost MLP, jit pricing) price plans
-        # on the host; they and the workers it spawns stay off the chip,
-        # which belongs to the one process that runs the tuned program
+        # the daemon's JAX user (jit pricing) prices plans on the host; it
+        # and the fleet workers it spawns stay off the chip, which belongs
+        # to the one process that runs the tuned program
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         from repro.service.daemon import TunerService, serve_forever
 
         service = TunerService(
-            args.store, parallel=args.parallel, n_workers=args.workers,
-            measure=args.measure,
+            args.store, measure=args.measure,
             checkpoint_every=args.checkpoint_every,
             deadline_s=args.deadline_s,
             round_delay_s=args.round_delay,
-            degrade_after=args.degrade_after,
         )
         served = serve_forever(service, args.socket,
                                max_requests=args.max_requests,
@@ -153,7 +143,7 @@ def main(argv=None) -> int:
             algo=args.algo, mesh=args.mesh,
             seed=args.seed, time_budget_s=args.budget_s,
             n_standard=args.n_standard, n_greedy=args.n_greedy,
-            noise_sigma=args.noise_sigma, cost=args.cost,
+            noise_sigma=args.noise_sigma,
         )
         if args.deadline_s is not None:
             settings["deadline_s"] = args.deadline_s

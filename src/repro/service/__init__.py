@@ -3,7 +3,7 @@
 ``PlanStore`` (store.py) is the on-disk tier — tuned plans and per-cell
 transposition-cache snapshots, atomic-published and quarantine-validated.
 ``TunerService``/``serve_forever`` (daemon.py) is the long-lived loop
-sharing one pinned worker pool and one measurement fleet across runs.
+sharing per-cell caches and one measurement fleet across runs.
 CLI: ``python -m repro.launch.tune_serve``.
 """
 from repro.service.daemon import TunerService, serve_forever

@@ -2,16 +2,13 @@
 
 ``TunerService`` is the in-process core (directly usable from tests and
 benchmarks, no socket): requests arrive as plain dicts, the plan store
-answers repeats instantly, and cold requests run the normal search — but
-against *persistent* shared machinery instead of one-shot copies:
+answers repeats instantly, and cold requests run the normal in-process
+search — but against *persistent* shared state instead of one-shot copies:
 
-* one ``PinnedWorkerPool`` across ALL runs — worker processes spawn once
-  per daemon, each run rebinds them to its trees
-  (``PinnedWorkerPool.rebind``) and ships per-round deltas as usual;
 * one ``MeasurementFleet`` across all measuring runs;
 * one in-memory ``TranspositionCache`` per cell, warm-started from the
-  store's cell tier and synced back after every run (exact-wins both
-  ways, see ``service/store.py``).
+  store's cell tier and synced back after every run (see
+  ``service/store.py``).
 
 Cold-path results are bit-identical to one-shot ``autotune()`` — the
 warm cache is a pure memo of exact values, so plan/cost/decisions match
@@ -34,10 +31,7 @@ Crash safety and deadlines (PR 10) ride on the round-boundary
   recorded as the stored plan;
 * a failed search syncs the warm cell cache (the progress it DID make),
   releases its journal/checkpoint state, and returns structured error
-  provenance (``error_info``) instead of a bare ``{"ok": false}``;
-* a health watchdog **degrades** a repeatedly-restarting pinned pool
-  (``degrade_after`` cumulative worker restarts) to the bit-identical
-  sequential engine, counted on ``stats()``.
+  provenance (``error_info``) instead of a bare ``{"ok": false}``.
 
 ``serve_forever`` wraps the service in a Unix-domain-socket JSON-lines
 protocol (one request object per line, one response object per line):
@@ -48,7 +42,7 @@ protocol (one request object per line, one response object per line):
 The front end is concurrent and supervised: a threaded accept loop, a
 read timeout per accepted connection (a silent client is closed, never
 blocking the daemon), and a bounded request queue drained by ONE search
-worker (the pool/cells/fleet are single-run state) — a full queue
+worker (the cells/fleet are single-run state) — a full queue
 answers ``{"ok": false, "error": "overloaded", "retry_after_s": ...}``
 immediately.  Shutdown cancels the in-flight search (it checkpoints and
 returns best-so-far to its waiting client) and answers queued requests
@@ -68,7 +62,6 @@ from typing import Dict, List, Optional
 
 from repro.core.autotuner import autotune, make_mdp
 from repro.core.engine.cache import CachedMDP, TranspositionCache
-from repro.core.engine.workers import PinnedWorkerPool
 from repro.core.run_control import RunController
 from repro.service.store import (
     PlanStore,
@@ -77,8 +70,10 @@ from repro.service.store import (
     request_key,
 )
 
-_EXEC_KEYS = ("engine", "parallel", "n_workers", "shm", "worker_batch",
-              "deadline_s")
+# per-request execution knobs: never part of the store key.  Keys older
+# clients still send (``parallel``, ``n_workers``) are accepted and dropped
+# by ``canonical_request``: the search always runs in this process.
+_EXEC_KEYS = ("engine", "deadline_s")
 
 
 class _CellState:
@@ -141,21 +136,16 @@ class TunerService:
         self,
         store_dir: str,
         *,
-        parallel: bool = False,
-        n_workers: Optional[int] = None,
         measure: str = "none",
         fleet_kwargs: Optional[dict] = None,
         log=print,
         checkpoint_every: int = 4,
         deadline_s: Optional[float] = None,
         round_delay_s: float = 0.0,
-        degrade_after: int = 5,
         latency_window: int = 256,
     ):
         assert measure in ("none", "stub", "real"), measure
         self.store = PlanStore(store_dir)
-        self.parallel = parallel
-        self.n_workers = n_workers
         self.measure = measure
         self.fleet_kwargs = dict(fleet_kwargs or {})
         self.log = log
@@ -163,35 +153,21 @@ class TunerService:
         # rounds (0 disables checkpoints AND journal resume), the default
         # per-request deadline (None = unbounded; requests override with
         # the ``deadline_s`` exec knob), the deterministic per-round
-        # fault-injection delay (tests/benchmarks only), and the watchdog
-        # threshold on cumulative pool worker restarts
+        # fault-injection delay (tests/benchmarks only)
         self.checkpoint_every = checkpoint_every
         self.deadline_s = deadline_s
         self.round_delay_s = round_delay_s
-        self.degrade_after = degrade_after
         self.cells: Dict[str, _CellState] = {}
-        self.pool: Optional[PinnedWorkerPool] = None
         self.fleet = None
         self.n_requests = 0
         self.n_searches = 0
         self.n_errors = 0
         self.n_interrupted = 0
         self.n_recovered = 0
-        self.degraded = False  # watchdog tripped: sequential engine only
-        self.n_pool_restarts = 0  # last observed cumulative restart count
         self.time_to_plan = _LatencyRing(latency_window)
         self._active_controller: Optional[RunController] = None
 
     # -- shared machinery (lazy, daemon-lifetime) ----------------------
-    def _shared_pool(self, mdp) -> Optional[PinnedWorkerPool]:
-        if not self.parallel or self.degraded:
-            return None
-        if self.pool is None:
-            # pre-spawn at the requested width with no trees; every run
-            # rebinds (workers.py keeps the width for empty trees)
-            self.pool = PinnedWorkerPool([], mdp, n_workers=self.n_workers)
-        return self.pool
-
     def _shared_fleet(self):
         if self.measure == "none":
             return None
@@ -212,10 +188,9 @@ class TunerService:
     def handle(self, request: dict) -> dict:
         """One tuning request → one response dict.  ``request`` carries
         the ``canonical_request`` settings plus optional execution knobs
-        (engine/parallel/n_workers/shm/worker_batch/deadline_s), which
-        never enter the store key.  Never raises: a failed request
-        returns ``ok=False`` with the legacy ``error`` string plus
-        structured ``error_info`` provenance."""
+        (``_EXEC_KEYS``), which never enter the store key.  Never raises:
+        a failed request returns ``ok=False`` with the legacy ``error``
+        string plus structured ``error_info`` provenance."""
         t0 = time.perf_counter()
         self.n_requests += 1
         req = None
@@ -257,8 +232,7 @@ class TunerService:
         ckey = cell_key(req)
         cell = self.cells.setdefault(ckey, _CellState())
         if not cell.cache.n_entries:
-            n = self.store.warm_cell(
-                ckey, cell.cache, include_learned=req["cost"] != "analytic")
+            n = self.store.warm_cell(ckey, cell.cache)
             if n:
                 self.log(f"[tuner-service] cell {ckey[:8]}: warmed "
                          f"{n} entries from store")
@@ -274,12 +248,6 @@ class TunerService:
             fleet.bind(req["arch"], req["shape"], req["mesh"])
             if fleet is not None and "real" in req["algo"] else None
         )
-        parallel = exec_knobs.get("parallel", self.parallel)
-        if self.degraded:
-            # watchdog tripped: the sequential engine is certified
-            # bit-identical to the pool, so degrading changes nothing but
-            # wall clock
-            parallel = False
         controller = RunController(
             deadline_s=exec_knobs.get("deadline_s", self.deadline_s),
             checkpoint_every=self.checkpoint_every,
@@ -304,14 +272,9 @@ class TunerService:
                 algo=req["algo"], mesh=req["mesh"], seed=req["seed"],
                 n_standard=req["n_standard"], n_greedy=req["n_greedy"],
                 time_budget_s=req["time_budget_s"],
-                noise_sigma=req["noise_sigma"], cost=req["cost"],
+                noise_sigma=req["noise_sigma"],
                 mdp=mdp,
                 engine=exec_knobs.get("engine", "array"),
-                parallel=parallel,
-                n_workers=exec_knobs.get("n_workers", self.n_workers),
-                worker_pool=self._shared_pool(mdp) if parallel else None,
-                shm=exec_knobs.get("shm"),
-                worker_batch=exec_knobs.get("worker_batch"),
                 measure_backend=measure_backend,
                 controller=controller,
                 resume=resume,
@@ -329,7 +292,6 @@ class TunerService:
             raise
         finally:
             self._active_controller = None
-            self._watchdog()
         if (res.stats or {}).get("interrupted"):
             # deadline/cancel best-so-far: answer the caller, KEEP the
             # checkpoint (a retry resumes and completes), never record the
@@ -386,27 +348,6 @@ class TunerService:
         if controller is not None:
             controller.cancel()
 
-    def _watchdog(self) -> None:
-        """Health check after every search: a pool whose workers keep
-        dying gets shut down and the daemon degrades to the sequential
-        engine (certified bit-identical — same plans, no worker
-        processes to babysit)."""
-        if self.pool is None or self.degraded:
-            return
-        restarts = self.pool.n_worker_restarts
-        self.n_pool_restarts = restarts
-        if restarts >= self.degrade_after:
-            self.log(
-                f"[tuner-service] pool hit {restarts} worker restarts "
-                f"(threshold {self.degrade_after}); degrading to the "
-                f"sequential engine")
-            pool, self.pool = self.pool, None
-            self.degraded = True
-            try:
-                pool.shutdown()
-            except Exception:  # noqa: BLE001 - a dying pool must not block degrade
-                pass
-
     def stats(self) -> dict:
         out = {
             "n_requests": self.n_requests,
@@ -414,30 +355,15 @@ class TunerService:
             "n_errors": self.n_errors,
             "n_interrupted": self.n_interrupted,
             "n_recovered": self.n_recovered,
-            "degraded": self.degraded,
-            "pool_restarts": self.n_pool_restarts,
             "time_to_plan": self.time_to_plan.summary(),
             "store": self.store.stats(),
             "cells": {k: v.cache.stats() for k, v in self.cells.items()},
         }
         if self.fleet is not None:
             out["fleet"] = self.fleet.stats()
-        if self.pool is not None:
-            out["pool"] = {
-                "submit_bytes": self.pool.submit_bytes,
-                "return_bytes": self.pool.return_bytes,
-                "snapshot_bytes": self.pool.snapshot_bytes,
-                # last run's serving split + cross-worker duplicate evals
-                # (per-worker hit/miss/dedup counters) + restart counts,
-                # cumulative and since the last rebind
-                **self.pool.stats(),
-            }
         return out
 
     def shutdown(self) -> None:
-        if self.pool is not None:
-            self.pool.shutdown()
-            self.pool = None
         if self.fleet is not None:
             self.fleet.shutdown()
             self.fleet = None
@@ -465,8 +391,8 @@ class _Job:
 class _Server:
     """Threaded front end state: the accept loop spawns one reader
     thread per connection; tune requests flow through a bounded queue
-    into ONE search-worker thread (the pool/cells/fleet are single-run
-    state, so searches serialize); ping/stats/shutdown answer inline on
+    into ONE search-worker thread (the cells/fleet are single-run state,
+    so searches serialize); ping/stats/shutdown answer inline on
     the connection thread, so they work while a search is running."""
 
     def __init__(self, service: TunerService, *, max_requests: Optional[int],

@@ -6,24 +6,25 @@ the measurement-cache discipline (``core/measure.py``): tmp-sibling +
 
 ``plans/<request-key>.json`` — complete tuned results.  The key hashes
 every *value-affecting* request setting (arch, shape, mesh, algo, seed,
-budget, ensemble size, noise, cost mode) and deliberately EXCLUDES
-execution knobs (``engine``, ``parallel``, ``n_workers``) — the engines
-are certified bit-identical (``tests/test_differential.py``), so a plan
-tuned by any of them answers the same request.  A hit reproduces the full
+budget, ensemble size, noise, plus a constant ``"cost": "analytic"`` that
+keeps every key written by earlier versions valid) and deliberately
+EXCLUDES execution knobs (``engine``; older clients' ``parallel`` and
+``n_workers`` are accepted and dropped) — the engines are certified
+bit-identical (``tests/test_differential.py``), so a plan tuned by any of
+them answers the same request.  A hit reproduces the full
 ``TuneResult`` (plan, exact cost, decision trace) with ``from_store=True``
 and zero search evals.
 
 ``cells/<cell-key>.json`` — per-cell ``TranspositionCache`` snapshots.
 The cell key hashes only what cache *values* depend on (arch, shape,
 mesh, noise), so every algo/seed/budget tuning the same cell shares one
-warm-start file.  Sync reuses the pinned-worker delta protocol
-(``TranspositionCache.watermark``/``export_since``/``apply_export``):
-each sync exports the in-memory cache's new entries since the last sync,
-merges them into the on-disk state under the exact-wins rule, and
-publishes atomically.  Writers are lock-free — concurrent daemons race on
-the ``os.replace`` and the loser's delta simply lands on its next sync
-(its in-memory cache still holds everything); exact-wins makes the merge
-order-independent for exact entries, so the store converges.
+warm-start file.  Each sync exports the in-memory cache's new entries
+since the last sync (``TranspositionCache.watermark``/``export_since``),
+merges them into the on-disk state, and publishes atomically.  Writers
+are lock-free — concurrent daemons race on the ``os.replace`` and the
+loser's delta simply lands on its next sync (its in-memory cache still
+holds everything); every entry is the exact analytic value of its state,
+so the merge is order-independent and the store converges.
 
 Two further tiers back the daemon's crash safety (PR 10):
 ``journal/<request-key>.json`` — the write-ahead request log (journaled
@@ -33,12 +34,12 @@ before search, released after the result lands; pending entries are what
 ``ProTuner.snapshot()`` states, published with the same tmp-sibling +
 ``os.replace`` discipline and quarantined on unreadable load.
 
-Warm starts load only EXACT (untagged) entries by default: a memo of
-exact analytic costs changes hit counts but never values, so a warmed
-search's plan/cost/decisions stay bit-identical to a cold one.  Learned-
-tagged entries (model predictions) are persisted — exact-wins applies
-across restarts too — but are only loaded into runs that themselves serve
-a learned model (``include_learned=True``).
+A memo of exact analytic costs changes hit counts but never values, so a
+warmed search's plan/cost/decisions stay bit-identical to a cold one.
+Cell files written by earlier versions may also hold learned-model
+predictions, tagged in their ``terminal_version``/``partial_version``
+tables; those entries are dropped on read and never written back (the
+tables stay in the format, empty).
 """
 from __future__ import annotations
 
@@ -73,7 +74,6 @@ def canonical_request(
     n_greedy: int = 1,
     noise_sigma: float = 0.0,
     noise_seed: Optional[int] = None,
-    cost: str = "analytic",
     pricing: Optional[str] = None,
     **_ignored,
 ) -> dict:
@@ -87,8 +87,12 @@ def canonical_request(
     OMITTED from the dict so every pre-existing request key is unchanged
     — while "jit" records ``cost_model.JIT_PRICING_TAG`` (a tag bump on
     any kernel revision re-keys stored plans and cells, so ULP-level
-    value drift never answers a stale request).  Execution knobs
+    value drift never answers a stale request).  ``cost`` is always
+    "analytic" — the key keeps the field so earlier stored plans still hit
+    — and a request for any other cost mode is refused.  Execution knobs
     (engine/parallel/n_workers) are accepted and dropped."""
+    if _ignored.get("cost", "analytic") != "analytic":
+        raise ValueError(f"unknown cost {_ignored['cost']!r}")
     req = {
         "arch": arch,
         "shape": shape,
@@ -102,7 +106,7 @@ def canonical_request(
         "noise_seed": (
             (seed if noise_seed is None else noise_seed) if noise_sigma else 0
         ),
-        "cost": cost,
+        "cost": "analytic",
     }
     if pricing == "jit":
         from repro.core.cost_model import JIT_PRICING_TAG
@@ -173,6 +177,10 @@ def _load_json(path: str, validate) -> Optional[dict]:
 # ---------------------------------------------------------------------------
 # Cache table codec (state tuples <-> JSON lists)
 # ---------------------------------------------------------------------------
+# a cell file's tables; the two ``*_version`` tables list the states an
+# earlier version priced with a learned model (absent or empty otherwise)
+_CELL_TABLES = ("terminal", "partial", "terminal_version", "partial_version")
+
 def _encode_tbl(tbl: dict) -> list:
     return [[list(k), v] for k, v in tbl.items()]
 
@@ -290,58 +298,46 @@ class PlanStore:
         return os.path.join(self.cells_dir, ckey + ".json")
 
     def _load_cell_tables(self, ckey: str):
+        """The stored ``(terminal, partial)`` tables, without the entries
+        an earlier version tagged as learned-model predictions."""
         obj = _load_json(
             self._cell_path(ckey),
-            lambda o: all(isinstance(o[k], list) for k in
-                          ("terminal", "partial",
-                           "terminal_version", "partial_version")),
+            lambda o: all(isinstance(o.get(k, []), list) for k in _CELL_TABLES),
         )
         if obj is None:
             return None
-        return (
-            _decode_tbl(obj["terminal"]),
-            _decode_tbl(obj["partial"]),
-            _decode_tbl(obj["terminal_version"]),
-            _decode_tbl(obj["partial_version"]),
-        )
+        t, p, tv, pv = (_decode_tbl(obj.get(k, [])) for k in _CELL_TABLES)
+        return ({k: v for k, v in t.items() if k not in tv},
+                {k: v for k, v in p.items() if k not in pv})
 
-    def warm_cell(self, ckey: str, cache: TranspositionCache,
-                  include_learned: bool = False) -> int:
+    def warm_cell(self, ckey: str, cache: TranspositionCache) -> int:
         """Load the stored cell state into ``cache``; returns the number
-        of entries applied.  Exact-only by default (see module doc)."""
+        of entries applied."""
         tables = self._load_cell_tables(ckey)
         if tables is None:
             return 0
-        t, p, tv, pv = tables
-        if not include_learned:
-            t = {k: v for k, v in t.items() if k not in tv}
-            p = {k: v for k, v in p.items() if k not in pv}
-            tv, pv = {}, {}
-        cache.apply_export((t, p, tv, pv))
-        return len(t) + len(p)
+        cache.apply_export(tables)
+        return len(tables[0]) + len(tables[1])
 
     def sync_cell(self, ckey: str, cache: TranspositionCache,
                   wm: Optional[Watermark]) -> Watermark:
         """Merge ``cache``'s entries since ``wm`` into the stored cell
         state and publish atomically; returns the new watermark.  Merge-
         on-write: the CURRENT disk state is re-read and the delta folded
-        into it under exact-wins, so two daemons writing the same cell
-        converge (the ``os.replace`` race loser's delta rides its next
-        sync)."""
+        into it, so two daemons writing the same cell converge (the
+        ``os.replace`` race loser's delta rides its next sync)."""
         new_wm = cache.watermark()
-        entries, _full = cache.export_since(wm)
         scratch = TranspositionCache()
         tables = self._load_cell_tables(ckey)
         if tables is not None:
-            t, p, tv, pv = tables
-            scratch.apply_export((t, p, tv, pv))
-        scratch.apply_export(entries)
+            scratch.apply_export(tables)
+        scratch.apply_export(cache.export_since(wm))
         _write_json(self._cell_path(ckey), {
             "version": STORE_VERSION,
             "terminal": _encode_tbl(scratch.terminal),
             "partial": _encode_tbl(scratch.partial),
-            "terminal_version": _encode_tbl(scratch.terminal_version),
-            "partial_version": _encode_tbl(scratch.partial_version),
+            "terminal_version": [],
+            "partial_version": [],
         })
         return new_wm
 

@@ -34,6 +34,9 @@ never mask a divergence in the other).  The cache is a pure memo
 the grid's hundreds of trajectories, keeping the harness inside the
 tier-1 budget.
 """
+import json
+import os
+
 import pytest
 from conftest import TABLE1_CELLS as CELLS
 from conftest import make_cell_mdp
@@ -112,16 +115,26 @@ def test_engines_identical_across_grid(ucb, simulation, reward, seed, cell):
 
 # ---------------------------------------------------------------------------
 # Ensemble level: the full ProTuner loop (root synchronization, winner
-# selection, tree reuse) across all three engine modes
+# selection, tree reuse) across all three engine modes, over the same
+# UCB × rollout × reward × cell grid as the single-tree legs above.  A
+# ProTuner ensemble always mixes random- and greedy-rollout trees, so the
+# rollout axis picks the majority: "random" is 2 standard + 1 greedy tree,
+# "greedy" is 1 standard + 2 greedy trees.
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("cell", list(CELLS))
-def test_ensemble_identical_across_engines(cell):
+@pytest.mark.parametrize("reward", ["cost", "binary"])
+@pytest.mark.parametrize("rollout", ["random", "greedy"])
+@pytest.mark.parametrize("ucb", ["paper", "cp10", "sqrt2"])
+def test_ensemble_identical_across_engines(ucb, rollout, reward, cell):
+    n_standard, n_greedy = (2, 1) if rollout == "random" else (1, 2)
+
     def run(**kw):
         res = ProTuner(
             _mdp(cell),
-            n_standard=2,
-            n_greedy=1,
-            mcts_config=MCTSConfig(iters_per_decision=10),
+            n_standard=n_standard,
+            n_greedy=n_greedy,
+            mcts_config=MCTSConfig(ucb=ucb, reward_mode=reward,
+                                   iters_per_decision=10),
             seed=3,
             **kw,
         ).run()
@@ -136,19 +149,6 @@ def test_ensemble_identical_across_engines(cell):
     ref = run(engine="reference", cache=False)
     arr = run(engine="array", batch=False)
     bat = run(engine="array", batch=True)
-    # pinned process-pool workers, pool defaults (shm transport +
-    # in-worker lockstep batching auto-on for this pure-analytic run)
-    par = run(engine="array", parallel=True)
-    # the transport/batching matrix at 2 workers (trees split across
-    # workers, so the shm fold and the export echo both carry real
-    # cross-worker traffic): export baseline, shm without in-worker
-    # batching, shm with it — all bit-identical
-    exp = run(engine="array", parallel=True, n_workers=2,
-              shm=False, worker_batch=False)
-    shm = run(engine="array", parallel=True, n_workers=2,
-              shm=True, worker_batch=False)
-    lock = run(engine="array", parallel=True, n_workers=2,
-               shm=True, worker_batch=True)
     # run-controller leg (core/run_control.py): an UNINTERRUPTED run with
     # the controller mounted — checkpoint cadence firing into a sink —
     # must stay bit-identical to a controller-free run (the controller
@@ -161,55 +161,34 @@ def test_ensemble_identical_across_engines(cell):
                                        checkpoint_fn=sink.append))
     assert arr == ref
     assert bat == ref
-    assert par == ref
-    assert exp == ref
-    assert shm == ref
-    assert lock == ref
     assert con == ref
     assert sink, "checkpoint cadence never fired"
 
 
 # ---------------------------------------------------------------------------
-# Parallel legs over the grid dimensions: the pinned process pool
-# (engine/workers.py) must reproduce the sequential ensemble bit-for-bit
-# for every UCB variant / simulation policy / reward mode, across both
-# cache transports (shared-memory log vs pickled exports) and both
-# in-worker evaluation modes (lockstep-batched vs per-tree).  One
-# representative config per UCB keeps the pool spawns inside the tier-1
-# budget; the full sequential grid above already certifies the engines,
-# and the pool's transport is value-blind (pure-memo cache entries +
-# per-round tree deltas), so any divergence here is a protocol bug.
+# Benchmark tune cells: ``autotune(arch, shape, algo="mcts_1s", seed=0)`` is
+# the one call the chip benchmark makes to get each cell's plan.  Plan,
+# exact cost, eval count and decision trace are pinned to the values the
+# search produced before the worker pool and learned-cost serving were
+# removed (tests/data/benchmark_tune_cells.json), so any change to what the
+# benchmark runs shows here first.
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize(
-    "ucb,simulation,reward,shm,worker_batch",
-    [
-        ("paper", "random", "cost", None, None),    # pool defaults
-        ("cp10", "greedy", "binary", False, False),  # export transport
-        ("sqrt2", "greedy", "cost", True, True),     # shm + lockstep
-    ],
-)
-def test_parallel_identical_across_grid(ucb, simulation, reward, shm,
-                                        worker_batch):
-    cfg = MCTSConfig(
-        ucb=ucb, simulation=simulation, reward_mode=reward,
-        iters_per_decision=8,
-    )
+_PINNED = os.path.join(os.path.dirname(__file__), "data",
+                       "benchmark_tune_cells.json")
 
-    def run(parallel):
-        res = ProTuner(
-            _mdp("moe_train"), n_standard=2, n_greedy=1, mcts_config=cfg,
-            seed=1, parallel=parallel, n_workers=2, shm=shm,
-            worker_batch=worker_batch,
-        ).run()
-        return (
-            res.plan,
-            res.cost,
-            [d["action"] for d in res.decisions],
-            [d["best_cost"] for d in res.decisions],
-            [d["winner_tree"] for d in res.decisions],
-        )
 
-    assert run(True) == run(False)
+@pytest.mark.parametrize("cell", ["granite-moe-1b-a400m/train_4k",
+                                  "granite-3-2b/train_4k",
+                                  "jamba2-3b/prefill_32k"])
+def test_benchmark_tune_cells_plan_pinned(cell):
+    with open(_PINNED) as f:
+        want = json.load(f)[cell]
+    res = autotune(*cell.split("/"), algo="mcts_1s", seed=0)
+    # through JSON, as the pinned file holds it (tuples become lists)
+    assert json.loads(json.dumps(res.plan.to_dict())) == want["plan"]
+    assert repr(res.cost) == want["cost"]
+    assert res.n_evals == want["n_evals"]
+    assert res.decisions == want["decisions"]
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Engine-layer tests: ArrayMCTS ↔ reference MCTS parity, transposition
-cache exactness, process-pool reproducibility, and SearchBackend routing.
+"""Engine-layer tests: ArrayMCTS ↔ reference MCTS parity, lockstep
+batched rounds, transposition cache exactness, and SearchBackend routing.
 
 Parity is asserted EXACTLY (same action, same best_cost, same best_state
 for a fixed seed): the array engine replicates the reference's RNG call
@@ -17,6 +17,7 @@ from repro.core.ensemble import ProTuner
 from repro.core.mcts import MCTS, MCTSConfig
 
 from conftest import MOE_TRAIN_CELL as CELL
+from conftest import TABLE1_CELLS, make_cell_mdp
 
 
 def _mdp():
@@ -122,145 +123,28 @@ def test_run_decision_counters_survive_batched_ensemble():
     )
 
 
-# ---------------------------------------------------------------------------
-# Per-round tree deltas (process-pool transport)
-# ---------------------------------------------------------------------------
-def test_parallel_delta_merge_equals_whole_tree():
-    """The master tree with a worker's round delta applied must equal the
-    worker's post-round tree — the whole-tree-pickle result — field for
-    field, and continue identically afterwards."""
-    import pickle
+@pytest.mark.parametrize("cell", ["moe_train", "decode"])
+@pytest.mark.parametrize("reward", ["cost", "binary"])
+@pytest.mark.parametrize("ucb", ["paper", "cp10", "sqrt2"])
+def test_lockstep_batch_identical_to_per_tree_loop(ucb, reward, cell):
+    """A whole ensemble run with lockstep batched rounds (``batch=True``)
+    equals the per-tree ``run_decision`` loop (``batch=False``): same
+    plan, cost and decision trace, and — with the shared cache on — the
+    same eval, hit and miss counters (first lookup of a state is a miss
+    whatever the order, and the batch dedups what the loop would hit)."""
+    cfg = MCTSConfig(ucb=ucb, reward_mode=reward, iters_per_decision=8)
 
-    import numpy as np
+    def run(batch):
+        res = ProTuner(make_cell_mdp(*TABLE1_CELLS[cell]), n_standard=2,
+                       n_greedy=1, mcts_config=cfg, seed=4, engine="array",
+                       cache=True, batch=batch).run()
+        return ((res.plan, res.cost, res.decisions),
+                (res.n_evals, res.cache_hits, res.cache_misses))
 
-    mdp = CachedMDP(_mdp())
-    master = ArrayMCTS(mdp, MCTSConfig(iters_per_decision=24, seed=6))
-    for _ in range(2):  # grow a real subtree before the measured round
-        r = master.run_decision()
-        master.advance_root(r.action)
-    worker = pickle.loads(pickle.dumps(master))  # ship to the worker
-    worker.begin_delta()
-    res_w = worker.run_decision()
-    delta = worker.collect_delta()
-    # TRUE delta: the numeric payload is the round's new-node slices plus
-    # the round's touched pre-round stat rows — never the whole [:size]
-    # arrays (that O(total tree) copy was the ROADMAP item this replaces)
-    base, size = delta["base"], delta["size"]
-    assert base > 16  # the warm-up rounds grew a real pre-round tree
-    for name in ("visit_counts", "sum_cost", "sum_reward", "best_cost",
-                 "node_action", "n_children"):
-        assert len(delta[name]) == size - base, name
-    assert delta["children"].shape[0] == size - base
-    assert 0 < len(delta["touched"]) < base  # paths only, not every node
-    assert (delta["touched"] < base).all()
-    wire = pickle.dumps(delta)
-    master.apply_delta(pickle.loads(wire))  # return trip
-
-    assert master.size == worker.size
-    n = master.size
-    for name in ("visit_counts", "sum_cost", "sum_reward", "best_cost",
-                 "node_action", "n_children"):
-        np.testing.assert_array_equal(
-            getattr(master, name)[:n], getattr(worker, name)[:n], err_msg=name
-        )
-    w = worker.children.shape[1]
-    np.testing.assert_array_equal(master.children[:n, :w], worker.children[:n, :w])
-    assert master.untried == worker.untried
-    assert master._childlist == worker._childlist
-    assert master.best_state == worker.best_state
-    assert master.rng.getstate() == worker.rng.getstate()
-    assert (master.baseline, master.global_best, master.global_best_state) == (
-        worker.baseline, worker.global_best, worker.global_best_state
-    )
-    # the delta payload is what crosses the pool boundary — it must be
-    # smaller than the whole-tree pickle it replaces
-    assert len(wire) < len(pickle.dumps(worker))
-    # payload accounting helper: the numeric delta payload is positive and
-    # bounded by the wire size
-    from repro.core.engine.array_mcts import delta_nbytes
-
-    assert 0 < delta_nbytes(delta) <= len(wire)
-    # merged tree and whole-tree result keep evolving identically
-    r_m, r_w = master.run_decision(), worker.run_decision()
-    assert (r_m.action, r_m.best_cost, r_m.best_state) == (
-        r_w.action, r_w.best_cost, r_w.best_state
-    )
-
-
-def test_delta_rejects_mismatched_base():
-    mdp = CachedMDP(_mdp())
-    a = ArrayMCTS(mdp, MCTSConfig(iters_per_decision=8, seed=1))
-    b = ArrayMCTS(mdp, MCTSConfig(iters_per_decision=8, seed=1))
-    a.run_decision()  # a grew past b's size
-    b.begin_delta()
-    b.run_decision()
-    delta = b.collect_delta()
-    with pytest.raises(ValueError):
-        a.apply_delta(delta)
-
-
-def test_batched_round_under_delta_matches_per_tree():
-    """In-worker lockstep batching composes with delta recording (the
-    shm-pool configuration: a pinned worker batches its subset's rounds
-    while recording per-tree deltas).  A ``run_decision_batch`` round with
-    delta recording active must return the same results as per-tree
-    ``run_decision`` rounds, and the collected deltas, applied to
-    pre-round master copies, must rebuild each worker tree field for
-    field."""
-    import pickle
-
-    import numpy as np
-
-    from repro.core.engine.batch import run_decision_batch
-
-    def grow(mdp, seeds):
-        trees = []
-        for s in seeds:
-            t = ArrayMCTS(mdp, MCTSConfig(iters_per_decision=16, seed=s))
-            r = t.run_decision()  # a real pre-round tree, not a stub root
-            t.advance_root(r.action)
-            trees.append(t)
-        return trees
-
-    m_bat, m_seq = CachedMDP(_mdp()), CachedMDP(_mdp())
-    bat = grow(m_bat, (6, 7))
-    seq = grow(m_seq, (6, 7))
-    masters = [pickle.loads(pickle.dumps(t)) for t in bat]  # pre-round
-
-    for t in bat:
-        t.begin_delta()
-    res_bat = run_decision_batch(bat, m_bat)
-    deltas = [t.collect_delta() for t in bat]
-
-    for t in seq:
-        t.begin_delta()
-    res_seq = [t.run_decision() for t in seq]
-    for t in seq:
-        t.collect_delta()
-
-    key = lambda r: (r.action, r.best_cost, r.best_state, r.iterations)
-    assert [key(r) for r in res_bat] == [key(r) for r in res_seq]
-    # batching never double-prices a shared leaf, delta recording or not
-    assert m_bat.mdp.cost_model.n_evals == m_seq.mdp.cost_model.n_evals
-    assert (m_bat.cache.hits, m_bat.cache.misses) == (
-        m_seq.cache.hits, m_seq.cache.misses)
-
-    for master, delta, worker in zip(masters, deltas, bat):
-        master.apply_delta(delta)
-        assert master.size == worker.size
-        n = master.size
-        for name in ("visit_counts", "sum_cost", "sum_reward", "best_cost",
-                     "node_action", "n_children"):
-            np.testing.assert_array_equal(
-                getattr(master, name)[:n], getattr(worker, name)[:n],
-                err_msg=name)
-        w = worker.children.shape[1]
-        np.testing.assert_array_equal(
-            master.children[:n, :w], worker.children[:n, :w])
-        assert master.untried == worker.untried
-        assert master._childlist == worker._childlist
-        assert master.best_state == worker.best_state
-        assert master.rng.getstate() == worker.rng.getstate()
+    bat, loop = run(True), run(False)
+    assert bat[0] == loop[0]
+    assert bat[1] == loop[1]
+    assert bat[1][0] == bat[1][2]  # each unique schedule priced once
 
 
 # ---------------------------------------------------------------------------
@@ -298,12 +182,12 @@ def test_cache_shared_across_trees_saves_evals():
 
 
 def test_pinned_worker_preload_chain_is_jax_free():
-    """``pick_mp_context`` preloads ``repro.core.ensemble`` into the
-    forkserver on the promise that the chain is jax-free (forking a
-    jax-threaded process can deadlock; jax lives behind lazy imports in
-    ``learned_cost``/``serving.fit``).  A top-level jax import sneaking
-    into that chain would silently poison every pinned worker — keep it
-    out."""
+    """The measurement fleet's ``pick_mp_context`` preloads
+    ``repro.core.measure_fleet`` into the forkserver on the promise that
+    the chain is jax-free (forking a jax-threaded process can deadlock;
+    jax lives behind lazy imports in ``cost_model``).  A top-level jax
+    import sneaking into that chain would silently poison every fleet
+    worker — keep it out."""
     import os
     import subprocess
     import sys
@@ -314,88 +198,31 @@ def test_pinned_worker_preload_chain_is_jax_free():
     ) + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
         [sys.executable, "-c",
-         "import sys, repro.core.ensemble; print('jax' in sys.modules)"],
+         "import sys, repro.core.measure_fleet; "
+         "print('jax' in sys.modules)"],
         capture_output=True, text=True, env=env, check=True,
     )
     assert out.stdout.strip() == "False"
 
 
 def test_cache_watermark_incremental_export():
-    """The pinned-worker forward-delta seam: ``export_since(watermark)``
+    """The plan store's cell-sync seam: ``export_since(watermark)``
     returns exactly the entries added since the cursor — O(new entries),
-    never a whole-table diff — and degrades to a full resync exactly when
-    the tables stopped being append-only (an eviction)."""
+    never a whole-table diff — and everything when there is no cursor."""
     c = TranspositionCache()
     c.terminal[(1,)] = 1.0
     wm = c.watermark()
     c.terminal[(2,)] = 2.0
     c.partial[(0,)] = 0.5
-    entries, full = c.export_since(wm)
-    assert not full
-    t, p, tv, pv = entries
-    assert t == {(2,): 2.0} and p == {(0,): 0.5} and not tv and not pv
+    entries = c.export_since(wm)
+    assert entries == ({(2,): 2.0}, {(0,): 0.5})
     d = TranspositionCache()
     d.apply_export(entries)
     assert d.terminal == {(2,): 2.0} and d.partial == {(0,): 0.5}
     # nothing new since the current watermark -> empty incremental export
-    entries, full = c.export_since(c.watermark())
-    assert not full and not entries[0] and not entries[1]
-    # no cursor at all -> full snapshot
-    entries, full = c.export_since(None)
-    assert full and entries[0] == c.terminal
-    # an eviction bumps the mutation epoch: length-based cursors are stale
-    # and the next export is a full resync (exactly once per epoch)
-    wm2 = c.watermark()
-    c.terminal[(9, 9)] = 9.0
-    c.terminal_version[(9, 9)] = 1
-    assert c.evict_learned() == 1
-    assert (9, 9) not in c.terminal and not c.terminal_version
-    _, full = c.export_since(wm2)
-    assert full
-    assert not c.export_since(c.watermark())[1]  # new cursor: incremental
-
-
-def test_pinned_submit_payload_stays_round_sized():
-    """The tentpole's O(round) SUBMIT claim, measured on the Table-1
-    decode cell: with persistent pinned workers, consecutive mid-run
-    rounds ship submit payloads within a constant factor of each other,
-    and no round's forward delta ever reaches the one-time init snapshot
-    — which is what the stateless pool re-pickled EVERY round, at the
-    run's smallest point (the tree then keeps growing every round, so the
-    old path's per-round bytes only go up from there)."""
-    import pickle
-
-    tuner = ProTuner(
-        make_mdp("granite-3-2b", "decode_32k"), n_standard=2, n_greedy=1,
-        mcts_config=MCTSConfig(iters_per_decision=16), seed=1,
-        engine="array", parallel=True,
-    )
-    res = tuner.run()
-    b = res.submit_bytes_rounds
-    assert res.n_worker_restarts == 0 and len(b) >= 4
-    # consecutive steady-state rounds (cache warm, constant per-round
-    # activity) ship submit payloads within a constant factor of each
-    # other — and once the hit rate saturates the forward delta collapses
-    # to little more than the root-advance message, even though the trees
-    # have grown every single round
-    assert b[-2] <= 4 * b[-3] and b[-3] <= 4 * b[-2]
-    assert b[-2] < 4096
-    # the return side is per-round work too: consecutive rounds stay
-    # within a constant factor (no tree-sized growth)
-    r = res.return_bytes_rounds
-    assert r[-2] <= 4 * r[-3] and r[-3] <= 4 * r[-2]
-    # no forward delta approaches the full-state snapshot
-    assert max(b) < res.snapshot_bytes
-    # and the old path's submit side only grows: at run END the whole
-    # state (trees + cache) dwarfs every round delta we actually shipped
-    end_state = len(
-        pickle.dumps((tuner.mdp, tuner.trees), pickle.HIGHEST_PROTOCOL)
-    )
-    assert max(b) * 2 < end_state
-    # totals are consistent with the per-round counters
-    assert res.submit_bytes == sum(b)
-    assert res.return_bytes == sum(res.return_bytes_rounds)
-    assert res.snapshot_bytes > 0
+    assert c.export_since(c.watermark()) == ({}, {})
+    # no cursor at all -> everything
+    assert c.export_since(None) == (c.terminal, c.partial)
 
 
 def test_cache_stats_and_merge():
@@ -411,46 +238,12 @@ def test_cache_stats_and_merge():
     assert (c1.hits, c1.misses) == (5, 3)
     assert c1.n_entries == 3
     assert 0 < c1.hit_rate < 1
-    # pickling keeps mappings, resets counters (multiprocess protocol)
+    # pickling keeps mappings, resets counters (checkpoint protocol)
     import pickle
 
     c3 = pickle.loads(pickle.dumps(c1))
     assert c3.terminal == c1.terminal and c3.partial == c1.partial
     assert (c3.hits, c3.misses) == (0, 0)
-
-
-# ---------------------------------------------------------------------------
-# Process-pool path
-# ---------------------------------------------------------------------------
-def test_protuner_reproducible_parallel_on_and_off():
-    """Fixed seed => identical plan/cost/decisions, sequential or in the
-    process pool, and across repeats."""
-    cfg = MCTSConfig(iters_per_decision=12)
-
-    def run(parallel):
-        return ProTuner(_mdp(), n_standard=2, n_greedy=1, mcts_config=cfg,
-                        seed=7, engine="array", parallel=parallel).run()
-
-    seq1, seq2 = run(False), run(False)
-    assert seq1.plan == seq2.plan and seq1.cost == seq2.cost
-    par = run(True)
-    assert par.plan == seq1.plan
-    assert par.cost == seq1.cost
-    assert [d["action"] for d in par.decisions] == [
-        d["action"] for d in seq1.decisions
-    ]
-
-
-def test_parallel_reference_engine_also_reproducible():
-    cfg = MCTSConfig(iters_per_decision=8)
-    seq = ProTuner(_mdp(), n_standard=2, n_greedy=0, mcts_config=cfg,
-                   seed=3, engine="reference").run()
-    par = ProTuner(_mdp(), n_standard=2, n_greedy=0, mcts_config=cfg,
-                   seed=3, engine="reference", parallel=True).run()
-    assert par.plan == seq.plan and par.cost == seq.cost
-    # uncached trees keep private cost-model copies across rounds; each
-    # eval must be counted exactly once (regression: was quadratic)
-    assert par.n_evals == seq.n_evals
 
 
 # ---------------------------------------------------------------------------

@@ -1,12 +1,10 @@
 """Fault tolerance control-plane tests: heartbeats, rendezvous re-balance,
-straggler eviction, elastic restart plans — plus the search engine's
-pinned-worker death/resync protocol (``repro.core.engine.workers``) and
-the measurement fleet's retry/quarantine/watchdog machinery
+straggler eviction, elastic restart plans — plus the measurement fleet's
+retry/quarantine/watchdog machinery
 (``repro.core.measure_fleet``; all via the XLA-free stub target)."""
 import itertools
 import json
 import os
-import signal
 
 import pytest
 
@@ -107,74 +105,6 @@ def test_rebalanced_pipeline_is_exact():
     import numpy as np
 
     np.testing.assert_array_equal(recomputed["inputs"], orig[2]["inputs"])
-
-
-def _shm_segments():
-    """Live repro shm cache segments of pools this process created (Linux:
-    files in /dev/shm).  Segment names carry the creating pid; other test
-    processes running at the same time create and unlink their own."""
-    try:
-        return {f for f in os.listdir("/dev/shm")
-                if f.startswith(f"repro-cache-{os.getpid()}-")}
-    except FileNotFoundError:  # pragma: no cover - non-Linux shm
-        return set()
-
-
-def test_pinned_worker_death_resync_identical_to_sequential(monkeypatch):
-    """Kill a pinned search worker mid-run — twice, in different rounds.
-    The master must respawn it and reseed it from its CANONICAL tree
-    snapshot plus the merged cache (``PinnedWorkerPool._resync``); the
-    replacement re-runs the lost round from the identical pre-round state
-    (same pickled RNG), so the tuning result — plan, cost, decision
-    sequence — is bit-identical to the sequential path regardless of the
-    deaths.  Each resync also swaps the shm cache segment to a fresh
-    generation (the dead worker's mapping is unknowable); every
-    generation must be unlinked by the end of the run — no /dev/shm
-    leak."""
-    from repro.core.autotuner import make_mdp
-    from repro.core.engine.shm_cache import HAVE_SHM
-    from repro.core.ensemble import ProTuner
-    from repro.core.mcts import MCTSConfig
-
-    cfg = MCTSConfig(iters_per_decision=10)
-
-    def make(parallel):
-        return ProTuner(
-            make_mdp("granite-moe-1b-a400m", "train_4k"), n_standard=2,
-            n_greedy=1, mcts_config=cfg, seed=11, engine="array",
-            parallel=parallel,
-        )
-
-    seq = make(False).run()
-
-    rounds = {"n": 0}
-    orig = ProTuner._round_pinned
-
-    def killing(self):
-        rounds["n"] += 1
-        if rounds["n"] in (2, 4):  # before the round's submit: the dead
-            w = self._pool._workers[0]  # pipe surfaces on send or collect
-            os.kill(w.proc.pid, signal.SIGKILL)
-            w.proc.join(timeout=10)
-        return orig(self)
-
-    monkeypatch.setattr(ProTuner, "_round_pinned", killing)
-    pre = _shm_segments()
-    tuner = make(True)
-    par = tuner.run()
-    assert par.n_worker_restarts == 2
-    # each resync re-shipped a snapshot (beyond the two initial inits)
-    assert par.snapshot_bytes > 0
-    assert par.plan == seq.plan and par.cost == seq.cost
-    assert [d["action"] for d in par.decisions] == [
-        d["action"] for d in seq.decisions
-    ]
-    # the shm transport survived both deaths (pure-analytic run) and every
-    # generation — the two retired by resync swaps included — is unlinked
-    # once the run's pool shuts down
-    if HAVE_SHM:
-        assert par.stats.get("shm") is True
-    assert not (_shm_segments() - pre)
 
 
 # ---------------------------------------------------------------------------

@@ -125,7 +125,7 @@ def test_columnar_kernel_equals_scalar_oracle(batch):
 def test_featurize_columns_matches_featurize_batch(batch):
     """The shared-encoding seam: featurizing a ``PlanColumns`` batch for
     the learned model produces the SAME float32 matrix as featurizing the
-    plan objects — the serving layer's one-encode-per-batch guarantee."""
+    plan objects, so one encode feeds the kernel and the MLP alike."""
     from repro.core.cost_model import PlanColumns
     from repro.core.learned_cost import featurize_batch, featurize_columns
 

@@ -8,12 +8,12 @@ layers:
 * engine level, on the dense train cell — an interrupted ensemble run
   checkpoints at a completed round boundary and a resumed run replays
   the exact tail, bit-identical (plan, cost, decisions) to the
-  uninterrupted reference, for both the sequential and the pinned-pool
-  parallel round paths, plus the evolutionary backend's
+  uninterrupted reference, for each engine and round mode, plus the
+  evolutionary backend's
   generation-boundary interrupt (best-so-far prefix, no checkpoints).
 
-The daemon-level legs (SIGKILL resume, journal replay, watchdog
-degradation) live in ``tests/test_tuner_service.py``.
+The daemon-level legs (SIGKILL resume, journal replay) live in
+``tests/test_tuner_service.py``.
 """
 import pickle
 
@@ -118,8 +118,11 @@ def _cancelling_sink(after: int):
     return snaps, box, fn
 
 
-@pytest.mark.parametrize("resume_parallel", [False, True])
-def test_interrupt_then_resume_bit_identical(resume_parallel):
+# every engine × round mode a snapshot can carry; the reference engine
+# has no lockstep rounds (``batch`` only batches array trees)
+@pytest.mark.parametrize("engine,batch", [("array", True), ("array", False),
+                                          ("reference", False)])
+def test_interrupt_then_resume_bit_identical(engine, batch):
     ref = _ref()
     rounds_total = len(ref.decisions)
     assert rounds_total > 6
@@ -128,7 +131,8 @@ def test_interrupt_then_resume_bit_identical(resume_parallel):
     con = RunController(checkpoint_every=1, checkpoint_fn=fn)
     box["con"] = con
     cut = autotune(CELL[0], CELL[1], algo="mcts_1s", seed=0,
-                   n_standard=2, n_greedy=1, controller=con)
+                   n_standard=2, n_greedy=1, engine=engine, batch=batch,
+                   controller=con)
     info = cut.stats["interrupted"]
     assert info["reason"] == "cancelled"
     assert info["rounds_done"] == 5 and info["rounds_total"] == rounds_total
@@ -136,13 +140,12 @@ def test_interrupt_then_resume_bit_identical(resume_parallel):
     assert info["round_truncated"] is False and info["checkpointed"] is True
     assert cut.decisions == ref.decisions[:5]  # best-so-far is a true prefix
 
-    # resume from the frozen checkpoint: the tail replays bit-identically,
-    # through the sequential rounds or the pinned-pool parallel rounds
+    # resume from the frozen checkpoint: the tail replays bit-identically
     snap = pickle.loads(snaps[-1])
+    assert snap["engine"] == engine
     res = autotune(CELL[0], CELL[1], algo="mcts_1s", seed=0,
-                   n_standard=2, n_greedy=1, resume=snap,
-                   parallel=resume_parallel,
-                   n_workers=2 if resume_parallel else None)
+                   n_standard=2, n_greedy=1, engine=engine, batch=batch,
+                   resume=snap)
     assert res.plan == ref.plan and res.cost == ref.cost
     assert res.decisions == ref.decisions
     assert "interrupted" not in (res.stats or {})

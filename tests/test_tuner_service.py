@@ -1,11 +1,13 @@
 """Tuner-as-a-service: PlanStore durability + daemon behaviour (XLA-free).
 
-Covers the ISSUE-7 store contract: atomic-published entries quarantined
-when corrupt, exact-wins convergence across writers, warm starts that
-never change results, plan-tier hits with zero search, and the socket
-protocol end to end (in-process server thread)."""
+Covers the store contract: atomic-published entries quarantined when
+corrupt, convergence across writers, warm starts that never change
+results, plan-tier hits with zero search, stores written by earlier
+versions, and the socket protocol end to end (in-process server
+thread)."""
 import json
 import os
+import shutil
 import threading
 
 import pytest
@@ -62,6 +64,19 @@ def test_request_key_excludes_execution_knobs():
     assert request_key(a) != request_key(c)
 
 
+def test_request_rejects_removed_cost_modes():
+    # the search prices on the exact analytic cost only: an explicit
+    # "analytic" keys like no cost at all, any other mode is refused
+    # rather than answered with a plan it did not ask for
+    a = canonical_request(**REQ)
+    assert a["cost"] == "analytic"
+    assert request_key(canonical_request(**REQ, cost="analytic")) == (
+        request_key(a))
+    for mode in ("learned", "hybrid"):
+        with pytest.raises(ValueError, match="cost"):
+            canonical_request(**REQ, cost=mode)
+
+
 def test_corrupt_plan_entry_quarantined(tmp_path):
     store = PlanStore(str(tmp_path / "store"))
     req = canonical_request(**REQ)
@@ -97,44 +112,37 @@ def test_corrupt_cell_entry_quarantined(tmp_path):
 
 
 def test_two_writers_converge_exact_wins(tmp_path):
-    """Two daemons race on one cell: whatever the sync order, a learned
-    prediction never shadows an exact analytic entry on disk."""
+    """Two daemons race on one cell: whatever the sync order, the stored
+    cell ends up holding both writers' entries, and an entry that a record
+    from an earlier version tagged as a learned-model prediction never
+    reaches a warm start nor survives the next write."""
     store = PlanStore(str(tmp_path / "store"))
     ckey = "cafecafecafecafecafe"
-    exact = TranspositionCache()
-    exact.terminal[(0, 1)] = 0.5
-    learned = TranspositionCache()
-    learned.terminal[(0, 1)] = 0.9
-    learned.terminal_version[(0, 1)] = 3
-    learned.terminal[(0, 2)] = 0.7  # untagged entry unique to this writer
+    a = TranspositionCache()
+    a.terminal[(0, 1)] = 0.5
+    a.partial[(0,)] = 0.4
+    b = TranspositionCache()
+    b.terminal[(0, 1)] = 0.5  # the same exact value, priced by both
+    b.terminal[(0, 2)] = 0.7
 
-    for first, second in ((exact, learned), (learned, exact)):
+    for first, second in ((a, b), (b, a)):
         for f in os.listdir(store.cells_dir):
             os.remove(os.path.join(store.cells_dir, f))
+        # the cell file as an earlier version left it: one learned entry
+        with open(store._cell_path(ckey), "w") as f:
+            json.dump({"version": 1, "terminal": [[[0, 3], 0.9]],
+                       "partial": [], "terminal_version": [[[0, 3], 2]],
+                       "partial_version": []}, f)
         store.sync_cell(ckey, first, None)
         store.sync_cell(ckey, second, None)
         merged = TranspositionCache()
-        store.warm_cell(ckey, merged, include_learned=True)
-        assert merged.terminal[(0, 1)] == 0.5, "learned shadowed exact"
-        assert (0, 1) not in merged.terminal_version
-        assert merged.terminal[(0, 2)] == 0.7  # both writers' entries kept
-
-
-def test_warm_start_excludes_learned_by_default(tmp_path):
-    store = PlanStore(str(tmp_path / "store"))
-    cache = TranspositionCache()
-    cache.terminal[(0, 1)] = 0.5
-    cache.terminal[(0, 2)] = 0.9
-    cache.terminal_version[(0, 2)] = 4  # a model prediction
-    store.sync_cell("k" * 20, cache, None)
-    fresh = TranspositionCache()
-    # an analytic run must only see exact entries (values change nothing,
-    # so plan/cost/decisions stay bit-identical to a cold run)
-    assert store.warm_cell("k" * 20, fresh) == 1
-    assert fresh.terminal == {(0, 1): 0.5}
-    both = TranspositionCache()
-    assert store.warm_cell("k" * 20, both, include_learned=True) == 2
-    assert both.terminal_version == {(0, 2): 4}
+        assert store.warm_cell(ckey, merged) == 3
+        assert merged.terminal == {(0, 1): 0.5, (0, 2): 0.7}
+        assert merged.partial == {(0,): 0.4}
+        with open(store._cell_path(ckey)) as f:
+            on_disk = json.load(f)
+        assert [0, 3] not in [k for k, _ in on_disk["terminal"]]
+        assert on_disk["terminal_version"] == []
 
 
 def test_sync_cell_is_incremental(tmp_path):
@@ -242,55 +250,46 @@ def test_bad_request_never_kills_daemon(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Shared pinned pool across runs
+# A store written by an earlier version (tests/data/store_at_parent: one
+# daemon run of REQ, plus a learned-tagged entry in its cell file, written
+# before the worker pool and learned-cost serving were removed)
 # ---------------------------------------------------------------------------
-def test_shared_pool_reused_across_runs(tmp_path):
-    svc = _service(tmp_path, parallel=True, n_workers=2)
-    out1 = svc.handle(dict(REQ))
-    pids = {w.proc.pid for w in svc.pool._workers}
-    out2 = svc.handle(dict(REQ, seed=1))
-    assert {w.proc.pid for w in svc.pool._workers} == pids
-    assert svc.pool.n_worker_restarts == 0
-    # parallel shared-pool results == sequential one-shot results
-    for out, seed in ((out1, 0), (out2, 1)):
-        ref = autotune(CELL[0], CELL[1], algo="mcts_1s", seed=seed,
-                       n_standard=2, n_greedy=1)
-        assert out["result"]["plan"] == ref.plan.to_dict()
-        assert out["result"]["cost"] == ref.cost
-        assert out["result"]["decisions"] == ref.decisions
+_PARENT_STORE = os.path.join(os.path.dirname(__file__), "data",
+                             "store_at_parent")
+
+
+def test_store_written_at_parent_still_hits(tmp_path):
+    root = str(tmp_path / "store")
+    shutil.copytree(_PARENT_STORE, root)
+    req = canonical_request(**REQ)
+    # the analytic request key is unchanged: the stored file answers it
+    assert os.path.exists(PlanStore(root)._plan_path(req))
+    ref = autotune(CELL[0], CELL[1], algo="mcts_1s", seed=0,
+                   n_standard=2, n_greedy=1)
+    hit = autotune(CELL[0], CELL[1], algo="mcts_1s", seed=0,
+                   n_standard=2, n_greedy=1, plan_store=PlanStore(root))
+    assert hit.from_store and hit.n_evals == ref.n_evals
+    assert hit.plan == ref.plan and hit.cost == ref.cost
+    assert hit.decisions == ref.decisions
+    # the cell file loads without its learned-tagged entries
+    ckey = cell_key(req)
+    warm = TranspositionCache()
+    n = PlanStore(root).warm_cell(ckey, warm)
+    assert n == warm.n_entries > 0
+    assert (99, 99) not in warm.terminal and (99,) not in warm.partial
+    # a daemon on that store: the repeat is a store hit, and a new seed
+    # searches from the warmed cell with the cold run's exact result
+    svc = TunerService(root, log=lambda *a: None)
+    assert svc.handle(dict(REQ))["served"] == "store"
+    out = svc.handle(dict(REQ, seed=1))
+    assert out["served"] == "search"
+    assert svc.cells[ckey].cache.hits > 0
+    ref1 = autotune(CELL[0], CELL[1], algo="mcts_1s", seed=1,
+                    n_standard=2, n_greedy=1)
+    assert out["result"]["plan"] == ref1.plan.to_dict()
+    assert out["result"]["cost"] == ref1.cost
+    assert out["result"]["decisions"] == ref1.decisions
     svc.shutdown()
-    assert svc.pool is None
-
-
-def test_pool_rebind_direct():
-    """PinnedWorkerPool.rebind repoints live workers at a new run's trees:
-    same processes, same results as a fresh pool."""
-    from repro.core.autotuner import make_mdp
-    from repro.core.engine.cache import CachedMDP
-    from repro.core.ensemble import ProTuner
-    from repro.core.engine.workers import PinnedWorkerPool
-    from repro.core.mcts import MCTSConfig
-
-    mc = MCTSConfig(iters_per_decision=4)
-    pool = PinnedWorkerPool([], CachedMDP(make_mdp(*CELL)), n_workers=2)
-    assert len(pool._workers) == 2  # empty trees keep the requested width
-    try:
-        pids = {w.proc.pid for w in pool._workers}
-        results = []
-        for seed in (0, 1):
-            tuner = ProTuner(CachedMDP(make_mdp(*CELL)), n_standard=2,
-                             n_greedy=1, mcts_config=mc, seed=seed,
-                             worker_pool=pool)
-            results.append(tuner.run())
-        assert {w.proc.pid for w in pool._workers} == pids
-        for seed, res in zip((0, 1), results):
-            ref = ProTuner(CachedMDP(make_mdp(*CELL)), n_standard=2,
-                           n_greedy=1, mcts_config=mc, seed=seed).run()
-            assert res.plan == ref.plan and res.cost == ref.cost
-            assert [d["action"] for d in res.decisions] == [
-                d["action"] for d in ref.decisions]
-    finally:
-        pool.shutdown()
 
 
 # ---------------------------------------------------------------------------
@@ -309,7 +308,7 @@ def test_autotune_plan_store_kwarg(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# PR 10: crash safety, deadlines, backpressure, degradation
+# Crash safety, deadlines, backpressure
 # ---------------------------------------------------------------------------
 def _ref(seed=0):
     return autotune(CELL[0], CELL[1], algo="mcts_1s", seed=seed,
@@ -450,28 +449,6 @@ def test_recover_replays_pending_journal(tmp_path):
     assert svc2.recover() == 0
     assert svc2.store.pending_requests() == []
     svc2.shutdown()
-
-
-def test_watchdog_degrades_repeatedly_restarting_pool(tmp_path):
-    """Past the restart threshold the pool is shut down and later runs go
-    sequential — same results (the engines are certified bit-identical),
-    no more worker processes to babysit."""
-    svc = _service(tmp_path, parallel=True, n_workers=2, degrade_after=3)
-    out1 = svc.handle(dict(REQ))
-    assert svc.pool is not None and not svc.degraded
-    svc.pool.n_worker_restarts = 3  # the pool has been dying repeatedly
-    out2 = svc.handle(dict(REQ, seed=1))  # this run's watchdog trips
-    assert svc.degraded and svc.pool is None
-    st = svc.stats()
-    assert st["degraded"] and st["pool_restarts"] == 3
-    out3 = svc.handle(dict(REQ, seed=2))  # served by the sequential engine
-    assert out3["ok"] and out3["served"] == "search"
-    for out, seed in ((out1, 0), (out2, 1), (out3, 2)):
-        ref = _ref(seed)
-        assert out["result"]["plan"] == ref.plan.to_dict()
-        assert out["result"]["cost"] == ref.cost
-        assert out["result"]["decisions"] == ref.decisions
-    svc.shutdown()
 
 
 def _start_server(svc, sock, **kw):

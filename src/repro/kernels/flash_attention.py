@@ -7,10 +7,14 @@ sees (block_q × head_dim) @ (head_dim × block_kv) and
 (block_q × block_kv) @ (block_kv × head_dim) matmuls — block sizes are
 schedule-space knobs (multiples of 128 keep the MXU fully fed).
 
-Fully-masked kv blocks above the causal diagonal are skipped via
-``pl.when`` — with block_q == block_kv this halves the compute, and is the
-structural analogue of the paper's "don't evaluate children you will not
-use" observation (§5.3).
+The matmuls take q, k and v in their own dtype (bf16 on the chip) and
+accumulate in float32; the softmax statistics and accumulator stay float32.
+Causal kv blocks fall into three kinds (``geometry.flash_block_split``):
+blocks above the diagonal are skipped via ``pl.when`` and, through the
+clamped k/v index_maps, fetch nothing — with block_q == block_kv this
+halves the work, the structural analogue of the paper's "don't evaluate
+children you will not use" observation (§5.3); blocks below it run without
+a mask; only blocks that cross it build the causal mask.
 
 GQA is handled in the k/v index_maps (q-head h reads kv-head h // group).
 """
@@ -23,6 +27,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.geometry import (
+    flash_block_full,
+    flash_block_runs,
+    flash_last_kv_block,
+)
 
 NEG_INF = -1e30
 
@@ -40,8 +50,7 @@ def _fwd_kernel(
     causal: bool,
     block_q: int,
     block_kv: int,
-    seq_q: int,
-    seq_kv: int,
+    q_off: int,
 ):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
@@ -53,22 +62,16 @@ def _fwd_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # causal block skip: first kv index of this block vs last q position of
-    # this q block (queries occupy the LAST seq_q positions of seq_kv).
-    q_off = seq_kv - seq_q
-    run = True
-    if causal:
-        run = kj * block_kv <= q_off + (qi + 1) * block_q - 1
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bkv, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+    def body(masked: bool):
+        # MXU operands stay in the inputs' dtype; products accumulate in f32.
+        q = q_ref[0, 0]
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)  # (bq, D)
+        k = k_ref[0, 0]  # (bkv, D)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (bq, bkv)
-        if causal:
+        )  # (bq, bkv)
+        if masked:
             qpos = q_off + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0
             )
@@ -83,9 +86,22 @@ def _fwd_kernel(
         p = jnp.exp(s - m_new)  # (bq, bkv)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         m_ref[...] = m_new
+
+    if causal:
+        # Blocks wholly below the diagonal run unmasked, blocks that cross it
+        # run masked, blocks above it run nothing (geometry.flash_block_split).
+        full = flash_block_full(qi, kj, q_off, block_q, block_kv)
+        runs = flash_block_runs(qi, kj, q_off, block_q, block_kv)
+        pl.when(full)(lambda: body(masked=False))
+        pl.when(jnp.logical_and(runs, jnp.logical_not(full)))(
+            lambda: body(masked=True)
+        )
+    else:
+        body(masked=False)
 
     @pl.when(kj == nkv - 1)
     def _finalize():
@@ -118,28 +134,30 @@ def flash_attention(
     scale = D ** -0.5
     grid = (B, Hq, Sq // block_q, Skv // block_kv)
 
+    q_off = Skv - Sq
     kernel = functools.partial(
         _fwd_kernel,
         scale=scale,
         causal=causal,
         block_q=block_q,
         block_kv=block_kv,
-        seq_q=Sq,
-        seq_kv=Skv,
+        q_off=q_off,
     )
+
+    def kv_index(b, h, qi, kj):
+        # Past the last block q block qi needs, keep that block's index: the
+        # pipeline fetches nothing for the skipped steps.
+        if causal:
+            kj = jnp.minimum(kj, flash_last_kv_block(qi, q_off, block_q, block_kv))
+        return (b, h // group, kj, 0)
+
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, kj: (b, h, qi, 0)),
-            pl.BlockSpec(
-                (1, 1, block_kv, D),
-                lambda b, h, qi, kj, g=group: (b, h // g, kj, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, D),
-                lambda b, h, qi, kj, g=group: (b, h // g, kj, 0),
-            ),
+            pl.BlockSpec((1, 1, block_kv, D), kv_index),
+            pl.BlockSpec((1, 1, block_kv, D), kv_index),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, block_q, D), lambda b, h, qi, kj: (b, h, qi, 0)

@@ -19,6 +19,47 @@ def flash_vmem_bytes(
     return io + scratch
 
 
+def flash_block_runs(qi, kj, q_off: int, block_q: int, block_kv: int):
+    """Causal flash attention: whether kv block ``kj`` holds any key that a
+    query of q block ``qi`` may see.  Queries sit at positions
+    ``q_off + qi*block_q + r`` (the last ``seq_q`` of ``seq_kv``).  Plain
+    integer arithmetic, so it takes Python ints and traced program ids."""
+    return kj * block_kv <= q_off + (qi + 1) * block_q - 1
+
+
+def flash_block_full(qi, kj, q_off: int, block_q: int, block_kv: int):
+    """Causal flash attention: whether every key of kv block ``kj`` is
+    visible to every query of q block ``qi`` (no mask needed)."""
+    return kj * block_kv + block_kv - 1 <= q_off + qi * block_q
+
+
+def flash_last_kv_block(qi, q_off: int, block_q: int, block_kv: int):
+    """The last kv block that q block ``qi`` needs under the causal mask."""
+    return (q_off + (qi + 1) * block_q - 1) // block_kv
+
+
+def flash_block_split(
+    seq_q: int, seq_kv: int, block_q: int, block_kv: int, causal: bool = True
+) -> tuple[int, int, int]:
+    """``(full, diagonal, skipped)`` grid steps per (batch, head) of the
+    flash-attention forward: full blocks run unmasked, diagonal blocks run
+    with the causal mask, skipped blocks run nothing.  Blocks are clipped
+    to the sequence as the kernel clips them."""
+    block_q, block_kv = min(block_q, seq_q), min(block_kv, seq_kv)
+    nq, nkv = seq_q // block_q, seq_kv // block_kv
+    if not causal:
+        return nq * nkv, 0, 0
+    q_off = seq_kv - seq_q
+    full = diagonal = 0
+    for qi in range(nq):
+        for kj in range(nkv):
+            if flash_block_full(qi, kj, q_off, block_q, block_kv):
+                full += 1
+            elif flash_block_runs(qi, kj, q_off, block_q, block_kv):
+                diagonal += 1
+    return full, diagonal, nq * nkv - full - diagonal
+
+
 def scan_vmem_bytes(
     chunk: int, d_block: int, n_state: int, dtype_bytes: int = 2
 ) -> int:

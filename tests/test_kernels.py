@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import geometry, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gemm import moe_gemm
 from repro.kernels.quantize import dequantize_int8, quantize_int8
@@ -20,6 +20,17 @@ def _tol(dtype):
 
 
 # ---------------------------------------------------------------------------
+def _qkv(B, Hq, Hkv, S, D, dtype):
+    """Random q, k, v; ``S`` is one length or ``(Sq, Skv)``, the queries
+    then being the last Sq positions of the Skv keys."""
+    Sq, Skv = S if isinstance(S, tuple) else (S, S)
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (B, Hq, Sq, D), dtype)
+    k = jax.random.normal(ks[1], (B, Hkv, Skv, D), dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, Skv, D), dtype)
+    return q, k, v
+
+
 @pytest.mark.parametrize(
     "B,Hq,Hkv,S,D,bq,bkv,causal",
     [
@@ -29,28 +40,81 @@ def _tol(dtype):
         (1, 4, 2, 256, 128, 256, 128, True),  # block_q == S
         (2, 4, 2, 128, 64, 128, 128, False),  # non-causal
         (1, 2, 2, 512, 64, 128, 256, True),   # bkv > bq
+        pytest.param(1, 8, 2, 512, 64, 128, 128, True, id="full-blocks-group4"),
+        pytest.param(1, 4, 2, (128, 384), 64, 64, 128, True, id="sq<skv-bq<bkv"),
+        pytest.param(1, 4, 1, (256, 512), 64, 128, 64, True, id="sq<skv-bq>bkv"),
+        pytest.param(1, 2, 1, (192, 256), 64, 64, 128, True, id="sq<skv-offset-mid-block"),
+        pytest.param(1, 2, 2, (128, 256), 64, 128, 128, False, id="sq<skv-non-causal"),
     ],
 )
 def test_flash_attention_matches_ref(B, Hq, Hkv, S, D, bq, bkv, causal):
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, Hq, S, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.float32)
+    q, k, v = _qkv(B, Hq, Hkv, S, D, jnp.float32)
     out = flash_attention(q, k, v, causal=causal, block_q=bq, block_kv=bkv, interpret=True)
     exp = ref.attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5, rtol=2e-5)
 
 
-def test_flash_attention_bf16():
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (1, 4, 128, 64), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (1, 2, 128, 64), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (1, 2, 128, 64), jnp.bfloat16)
-    out = flash_attention(q, k, v, block_q=64, block_kv=64, interpret=True)
+@pytest.mark.parametrize(
+    "B,Hq,Hkv,S,D,bq,bkv",
+    [
+        pytest.param(1, 4, 2, 128, 64, 64, 64, id="base"),
+        # the benchmark cells' head geometries, S scaled down
+        pytest.param(1, 8, 2, 512, 64, 128, 128, id="granite-3-2b-d64-group4"),
+        pytest.param(1, 4, 2, 512, 64, 128, 128, id="granite-moe-d64-group2"),
+        pytest.param(1, 20, 1, 256, 128, 128, 128, id="jamba2-3b-d128-group20"),
+        pytest.param(1, 4, 2, 512, 64, 256, 128, id="bq>bkv"),
+        pytest.param(1, 4, 2, 512, 64, 128, 256, id="bq<bkv"),
+        pytest.param(1, 4, 2, (192, 256), 64, 64, 128, id="sq<skv"),
+    ],
+)
+def test_flash_attention_bf16(B, Hq, Hkv, S, D, bq, bkv):
+    q, k, v = _qkv(B, Hq, Hkv, S, D, jnp.bfloat16)
+    out = flash_attention(q, k, v, block_q=bq, block_kv=bkv, interpret=True)
     exp = ref.attention(q, k, v)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(exp, np.float32), **_tol(jnp.bfloat16)
     )
+
+
+def _brute_split(Sq, Skv, bq, bkv, causal):
+    """(full, diagonal, skipped) and each q block's last needed kv block,
+    read off an explicit (Sq, Skv) mask."""
+    qpos = np.arange(Sq)[:, None] + (Skv - Sq)
+    mask = qpos >= np.arange(Skv)[None, :] if causal else np.ones((Sq, Skv), bool)
+    blocks = mask.reshape(Sq // bq, bq, Skv // bkv, bkv).transpose(0, 2, 1, 3)
+    full = int(blocks.all(axis=(2, 3)).sum())
+    runs = blocks.any(axis=(2, 3))
+    last = [int(np.nonzero(r)[0].max()) for r in runs]
+    return (full, int(runs.sum()) - full, int((~runs).sum())), last
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_block_split_matches_mask(causal):
+    for Sq, Skv in [(128, 128), (256, 256), (512, 512), (128, 512), (192, 256), (64, 320)]:
+        for bq in (32, 64, 128):
+            for bkv in (32, 64, 128):
+                if Sq % bq or Skv % bkv:
+                    continue
+                split = geometry.flash_block_split(Sq, Skv, bq, bkv, causal)
+                expect, last = _brute_split(Sq, Skv, bq, bkv, causal)
+                assert split == expect, (Sq, Skv, bq, bkv)
+                assert sum(split) == (Sq // bq) * (Skv // bkv)
+                if causal:
+                    got = [geometry.flash_last_kv_block(qi, Skv - Sq, bq, bkv)
+                           for qi in range(Sq // bq)]
+                    assert got == last, (Sq, Skv, bq, bkv)
+
+
+@pytest.mark.parametrize(
+    "S,block,split",
+    [
+        pytest.param(4096, 512, (28, 8, 28), id="dense_prefill_4k"),
+        pytest.param(4096, 512, (28, 8, 28), id="moe_train_4k"),
+        pytest.param(8192, 512, (120, 16, 120), id="hybrid_prefill_8k"),
+    ],
+)
+def test_flash_block_split_cells(S, block, split):
+    assert geometry.flash_block_split(S, S, block, block) == split
 
 
 # ---------------------------------------------------------------------------
